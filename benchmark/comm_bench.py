@@ -1,7 +1,7 @@
 """Gradient-communication policy measurement harness.
 
 The ONE implementation shared by tools/comm_smoke.py (CI gate) and any
-bench.py comm phase, so the parity checks, the dispatch accounting, and
+benchmark comm cell, so the parity checks, the dispatch accounting, and
 the loss-closeness criterion cannot drift between the evidence record
 and the gate.
 

@@ -1,7 +1,7 @@
 """Continuous-batching generation measurement harness.
 
 The ONE implementation shared by tools/gen_smoke.py (CI gate) and any
-bench.py generation phase, so the parity check, the trace-count
+benchmark generation cell, so the parity check, the trace-count
 assertion, and the throughput criterion cannot drift between the
 evidence record and the gate.
 

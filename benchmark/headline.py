@@ -1,10 +1,10 @@
 """The bench headline configuration, shared by the perf harnesses.
 
-Single source of truth for the autotuned conv-lowering picks the r4
-headline run settled on (benchmark/results/bench_r4_v5e.json), so the
-decomposition/sweep harnesses measure the same lowering the headline
-reports. If the autotuner's winners change on a new device generation,
-this is the one place to update.
+Single source of truth for the conv-lowering picks the decomposition
+and sweep harnesses (step_phases, xla_flags_sweep) run under, so they
+measure one lowering. The picks are the round-4 autotune winners
+(builder-banked, not reproduced; source file removed in PR 21); on the
+chip they are not measured.
 """
 
 HEADLINE_ENV = {"PADDLE_TPU_CONV_IMPL": "conv",

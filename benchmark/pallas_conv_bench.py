@@ -14,9 +14,9 @@ mfu_ladder.py use, so rows are comparable across harnesses.
 
 NOTE (r4 lesson, benchmark/results/mfu_levers_*.json): an isolated 3x3
 microbench CANNOT justify adoption — impl=matmul won this exact probe
-2.6x and regressed the end-to-end step 3x. Adoption lives in bench.py's
-pallas_trial phase and the tune winner cache (timed per shape, stock XLA
-always in the race). This file exists for the per-shape evidence table.
+2.6x and regressed the end-to-end step 3x. Adoption is decided on full
+training steps and through the tune winner cache (timed per shape, stock
+XLA always in the race). This file exists for the per-shape evidence table.
 """
 from __future__ import annotations
 
@@ -76,8 +76,8 @@ def bench(batch=None, dtype="bfloat16", iters=8):
         "pallas_conv", rows,
         meta={"dtype": dtype,
               "note": "interpret-mode (meaningless) if platform != tpu; "
-                      "adoption decided end-to-end in bench.py "
-                      "pallas_trial + the tune winner cache"})
+                      "adoption decided on full training steps + "
+                      "the tune winner cache"})
     path = write_result(rec)
     print("wrote", path)
     return rec

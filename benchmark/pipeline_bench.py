@@ -1,7 +1,7 @@
 """Sync-vs-pipelined Trainer measurement harness.
 
-The ONE implementation shared by bench.py's pipeline phase and
-tools/perf_smoke.py (gate), so the overlap formula, timed windows, and
+The ONE implementation behind tools/perf_smoke.py (gate) and any
+benchmark cell, so the overlap formula, timed windows, and
 parity check cannot drift between the evidence record and the CI gate.
 
 Workload: a small MLP trained through the public Trainer surface over a
@@ -17,7 +17,7 @@ from __future__ import annotations
 
 def bench(steps=30, batch=64, dim=64, hidden=128, read_ms=3.0,
           timed_passes=1, lr=0.01):
-    """Returns the fields that ride bench.py's headline record: both
+    """Returns the pipeline evidence fields: both
     modes' steps/s, the speedup, bit-exact parity, and the pipeline
     counters proving (or refuting) the overlap."""
     import time

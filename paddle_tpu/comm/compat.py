@@ -1,42 +1,19 @@
-"""shard_map across jax versions.
+"""The one ``shard_map`` spelling call sites use.
 
-``shard_map`` moved twice upstream: ``jax.experimental.shard_map``
-(<= 0.4.x, replication check kwarg ``check_rep``) -> ``jax.shard_map``
-(>= 0.6, kwarg ``check_vma``). Callers here always want the check OFF —
+Callers here always want the replication (vma) check OFF —
 collective-heavy bodies (pallas out_shapes, masked psum broadcasts) trip
-the replication checker — so this wrapper normalises both the import path
-and the kwarg name once, instead of every call site guessing.
+the checker — so the wrapper fixes that default once instead of every
+call site repeating it.
 """
 from __future__ import annotations
 
 import jax
 
-try:                                    # jax >= 0.6
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:                     # jax <= 0.4.x / 0.5.x
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        _CHECK_KW = "check_rep"
-    except ImportError:                 # ancient jax: no shard_map at all
-        _shard_map = None
-        _CHECK_KW = None
-
-
-def has_shard_map():
-    """True when this jax provides shard_map in either spelling (tests
-    skip their shard_map suites with a named reason when it doesn't)."""
-    return _shard_map is not None
-
 
 def shard_map(f, mesh, in_specs, out_specs, check=False):
-    """Version-portable ``shard_map`` (replication/vma check defaults off)."""
-    if _shard_map is None:
-        raise ImportError(
-            "this jax (%s) provides neither jax.shard_map nor "
-            "jax.experimental.shard_map" % jax.__version__)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check})
+    """``jax.shard_map`` with the vma check defaulting off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def axis_size(axis_name):

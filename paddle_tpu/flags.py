@@ -196,18 +196,17 @@ DEFINE_bool("benchmark", False,
             "(reference: FLAGS_benchmark, executor.cc:29)")
 DEFINE_string("conv_impl", "conv",
               "dense conv2d lowering: 'conv' (lax.conv) or 'matmul' "
-              "(shifted einsums); bench.py autotunes this on device")
+              "(shifted einsums)")
 DEFINE_string("conv_layout", "nchw",
               "internal conv execution layout: 'nchw' (the API contract "
               "layout, passed through) or 'nhwc' (transpose to NHWC/HWIO "
               "around the conv — TPU vector lanes ride the channel dim; "
-              "XLA cancels the transpose pairs between adjacent convs); "
-              "bench.py autotunes this on device")
+              "XLA cancels the transpose pairs between adjacent convs)")
 DEFINE_bool("conv_first_s2d", False,
             "rewrite the ImageNet stem conv (7x7/s2/p3, C_in<=4) as "
             "space-to-depth + 4x4/s1 conv: 4x better MXU lane utilization "
             "on the 3-channel input (the public MLPerf ResNet trick); "
-            "numerically exact, autotuned by bench.py")
+            "numerically exact")
 DEFINE_bool("debug_shapes", False,
             "raise (instead of recording) on shape-inference failures")
 DEFINE_bool("verify", False,
@@ -271,14 +270,18 @@ DEFINE_int32("pipeline_depth", 2,
              "async pipeline keeps in flight (2 = classic double "
              "buffering; <1 disables pipelining)")
 DEFINE_bool("compile_cache", True,
-            "persist XLA compilations to compile_cache_dir via jax's "
-            "on-disk compilation cache so repeat runs skip the cold "
-            "compile (~29 s/step-class for big programs); set to 0 to "
-            "opt out. Never overrides an explicitly configured "
-            "JAX_COMPILATION_CACHE_DIR")
-DEFINE_string("compile_cache_dir", "~/.cache/paddle_tpu/xla",
-              "directory for the persistent XLA compilation cache "
-              "(used when FLAGS.compile_cache is on)")
+            "persist XLA compilations via jax's on-disk compilation "
+            "cache so repeat runs skip the cold compile; set to 0 to "
+            "opt out")
+DEFINE_string("compile_cache_dir",
+              os.path.join(os.path.dirname(os.path.dirname(
+                  os.path.abspath(__file__))), ".jax_cache"),
+              "directory for the persistent XLA compilation cache when "
+              "JAX_COMPILATION_CACHE_DIR is unset (default "
+              "<checkout>/.jax_cache, resolved from the package's own "
+              "location: the path is part of the cache key, so it must "
+              "not move between runs). Where the environment variable is "
+              "set, jax reads it itself and this flag is ignored")
 DEFINE_int32("serve_max_batch", 8,
              "online serving (paddle_tpu.serving): most requests the "
              "micro-batcher coalesces into one run_many device dispatch. "

@@ -21,9 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-
-def _interpret_default():
-    return jax.devices()[0].platform == "cpu"
+from ..place import on_tpu
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -54,7 +52,7 @@ def _kernel(x_ref, w_ref, h0_ref, m_ref, h_out, h_scr):
     cand = jnp.tanh(x[:, 2 * D:] + jnp.dot(
         r * h_prev, w[:, 2 * D:], preferred_element_type=jnp.float32))
     h_new = (1.0 - u) * h_prev + u * cand
-    m = m_ref[0].astype(jnp.float32)[:, None]
+    m = m_ref[0].astype(jnp.float32)               # [N, 1]
     h = h_new * m + h_prev * (1.0 - m)
     h_scr[...] = h
     h_out[0] = h.astype(h_out.dtype)
@@ -65,7 +63,7 @@ def _forward(xs, w, h0, mask, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not on_tpu()
     T, N, D3 = xs.shape
     D = D3 // 3
     hs = pl.pallas_call(
@@ -75,13 +73,14 @@ def _forward(xs, w, h0, mask, interpret):
             pl.BlockSpec((1, N, D3), lambda t: (t, 0, 0)),
             pl.BlockSpec((D, D3), lambda t: (0, 0)),
             pl.BlockSpec((N, D), lambda t: (0, 0)),
-            pl.BlockSpec((1, N), lambda t: (t, 0)),
+            # mask as [T, N, 1] columns (see fused_lstm._forward)
+            pl.BlockSpec((1, N, 1), lambda t: (t, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, N, D), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((T, N, D), xs.dtype),
         scratch_shapes=[pltpu.VMEM((N, D), jnp.float32)],
         interpret=interpret,
-    )(xs, w, h0, mask)
+    )(xs, w, h0, mask[:, :, None])
     return hs, (xs, w, h0, mask, hs)
 
 

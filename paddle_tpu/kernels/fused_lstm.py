@@ -24,9 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-
-def _interpret_default():
-    return jax.devices()[0].platform == "cpu"
+from ..place import on_tpu
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -61,7 +59,7 @@ def _kernel(x_ref, w_ref, h0_ref, c0_ref, m_ref, h_out, c_out, h_scr,
     o = jax.nn.sigmoid(g[:, 3 * D:4 * D])
     c_new = f * c_prev + i * c_t
     h_new = o * jnp.tanh(c_new)
-    m = m_ref[0].astype(jnp.float32)[:, None]
+    m = m_ref[0].astype(jnp.float32)               # [N, 1]
     h = h_new * m + h_prev * (1.0 - m)
     c = c_new * m + c_prev * (1.0 - m)
     h_scr[...] = h
@@ -75,7 +73,7 @@ def _forward(xs, w, h0, c0, mask, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not on_tpu()
     T, N, D4 = xs.shape
     D = D4 // 4
     hs, cs = pl.pallas_call(
@@ -86,7 +84,9 @@ def _forward(xs, w, h0, c0, mask, interpret):
             pl.BlockSpec((D, D4), lambda t: (0, 0)),         # w (resident)
             pl.BlockSpec((N, D), lambda t: (0, 0)),          # h0
             pl.BlockSpec((N, D), lambda t: (0, 0)),          # c0
-            pl.BlockSpec((1, N), lambda t: (t, 0)),          # mask_t
+            # mask rides as [T, N, 1]: a (1, N) block over [T, N] is not a
+            # legal Mosaic tile, (N, 1) equal to the array's last two dims is
+            pl.BlockSpec((1, N, 1), lambda t: (t, 0, 0)),    # mask_t
         ],
         out_specs=[
             pl.BlockSpec((1, N, D), lambda t: (t, 0, 0)),
@@ -101,7 +101,7 @@ def _forward(xs, w, h0, c0, mask, interpret):
             pltpu.VMEM((N, D), jnp.float32),
         ],
         interpret=interpret,
-    )(xs, w, h0, c0, mask)
+    )(xs, w, h0, c0, mask[:, :, None])
     return hs, cs, (xs, w, h0, c0, mask, hs, cs)
 
 

@@ -22,7 +22,7 @@ page compute attention over trash — the same garbage the gather
 reference computes — and their outputs are discarded by the engine, so
 parity holds on every row.
 
-Interpret-mode capable (``interpret=not _on_tpu()``), so the parity
+Interpret-mode capable (``interpret=not on_tpu()``), so the parity
 grid in tests/test_kernels_parity.py is tier-1-testable on CPU. The
 config contract is the conv3x3/flash contract: a stale or invalid tune
 pick DEGRADES to the gather reference (``resolve_block_config`` ->
@@ -34,6 +34,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from ..place import on_tpu
 
 NEG_INF = -1e30
 
@@ -79,11 +81,6 @@ def resolve_block_config(config, R, max_blocks):
     return br, bkv
 
 
-def _on_tpu():
-    from ..amp import _on_tpu as _amp_on_tpu
-    return _amp_on_tpu()
-
-
 def paged_attention_reference(q, k_pages, v_pages, block_tables,
                               positions):
     """The stock gather path — decode_step's attention math verbatim:
@@ -123,24 +120,34 @@ def _pa_kernel(tables_ref, pos_ref, q_ref, *refs, block_r, block_kv, T,
         num_ref[...] = jnp.zeros_like(num_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
 
+    # Single-query attention is a matrix-VECTOR product per head: every
+    # K/V element meets exactly one multiply-add, so the step is bound by
+    # the page read, not the MXU. The contraction is therefore written as
+    # a VPU broadcast-multiply + reduce with the head axis kept on
+    # sublanes throughout ("hd,thd->ht" batches over h with no free lhs
+    # dim — a dot form Mosaic has no dimension numbers for). Scores and
+    # the running stats stay [.., nh, 1] columns: no relayout between
+    # the score tile, the softmax and the p*V accumulation.
     for i in range(block_r):
         row = rb * block_r + i
         pos = pos_ref[row]
         q = q_ref[i].astype(jnp.float32)                   # [nh, dh]
         m, num, den = m_ref[i], num_ref[i], den_ref[i]
+        nh = q.shape[0]
         for j in range(block_kv):
             slot = b * block_kv + j
             k_blk = k_refs[i * block_kv + j][0].astype(jnp.float32)
             v_blk = v_refs[i * block_kv + j][0].astype(jnp.float32)
-            kvpos = slot * T + jax.lax.broadcasted_iota(jnp.int32, (T,), 0)
-            s = jnp.einsum("hd,thd->ht", q, k_blk) * scale   # [nh, T]
-            s = jnp.where((kvpos <= pos)[None, :], s, NEG_INF)
-            blk_max = jnp.max(s, axis=-1)
-            new_m = jnp.maximum(m, blk_max)
-            p = jnp.exp(s - new_m[:, None])
+            kvpos = slot * T + jax.lax.broadcasted_iota(
+                jnp.int32, (T, nh, 1), 0)
+            s = jnp.sum(k_blk * q[None], axis=-1,
+                        keepdims=True) * scale              # [T, nh, 1]
+            s = jnp.where(kvpos <= pos, s, NEG_INF)
+            new_m = jnp.maximum(m, jnp.max(s, axis=0))      # [nh, 1]
+            p = jnp.exp(s - new_m[None])                    # [T, nh, 1]
             alpha = jnp.exp(m - new_m)
-            num = num * alpha[:, None] + jnp.einsum("ht,thd->hd", p, v_blk)
-            den = den * alpha + jnp.sum(p, axis=-1)
+            num = num * alpha + jnp.sum(p * v_blk, axis=0)  # [nh, dh]
+            den = den * alpha + jnp.sum(p, axis=0)
             m = new_m
         m_ref[i], num_ref[i], den_ref[i] = m, num, den
 
@@ -148,7 +155,7 @@ def _pa_kernel(tables_ref, pos_ref, q_ref, *refs, block_r, block_kv, T,
     def _emit():
         for i in range(block_r):
             den = jnp.maximum(den_ref[i], 1e-20)
-            out_ref[i] = (num_ref[i] / den[:, None]).astype(out_ref.dtype)
+            out_ref[i] = (num_ref[i] / den).astype(out_ref.dtype)
 
 
 def _pa_pallas(q, k_pages, v_pages, block_tables, positions, block_r,
@@ -182,9 +189,9 @@ def _pa_pallas(q, k_pages, v_pages, block_tables, positions, block_r,
         out_specs=pl.BlockSpec((block_r, nh, dh),
                                lambda rb, b, tbl, ps: (rb, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_r, nh), jnp.float32),        # running max
+            pltpu.VMEM((block_r, nh, 1), jnp.float32),     # running max
             pltpu.VMEM((block_r, nh, dh), jnp.float32),    # numerator
-            pltpu.VMEM((block_r, nh), jnp.float32),        # denominator
+            pltpu.VMEM((block_r, nh, 1), jnp.float32),     # denominator
         ],
     )
     nkv = block_r * block_kv
@@ -263,6 +270,6 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions,
                                          block_tables, positions)
     br, bkv = resolved
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     return _pa_pallas(q, k_pages, v_pages, block_tables, positions,
                       br, bkv, interpret)

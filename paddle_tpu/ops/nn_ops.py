@@ -229,8 +229,8 @@ def conv_impl():
     """Which dense-conv lowering to use: 'conv' = lax.conv_general_dilated
     (XLA:TPU's native conv->MXU path, the default) or 'matmul' = KH*KW
     shifted einsums (the im2col+gemm role of reference
-    operators/math/im2col.* + conv_op.h GemmConvKernel). bench.py autotunes
-    this on the real device and pins PADDLE_TPU_CONV_IMPL."""
+    operators/math/im2col.* + conv_op.h GemmConvKernel). The
+    PADDLE_TPU_CONV_IMPL env var overrides the flag."""
     import os
     env = os.environ.get("PADDLE_TPU_CONV_IMPL")
     if env:
@@ -244,8 +244,7 @@ def conv_layout():
     transposed). The op API contract stays NCHW either way; 'nhwc' wraps
     each conv in transposes that XLA's algebraic simplifier cancels
     between adjacent convs (elementwise ops in between are layout-moved).
-    bench.py autotunes this on the real device and pins
-    PADDLE_TPU_CONV_LAYOUT."""
+    The PADDLE_TPU_CONV_LAYOUT env var overrides the flag."""
     import os
     env = os.environ.get("PADDLE_TPU_CONV_LAYOUT")
     if env:

@@ -25,8 +25,9 @@ Three stages:
   sync points (the event handler touching ``.cost``/``.metrics``, the
   log-period progress line, pass end, before checkpoints).
 - the persistent compile cache — jax's on-disk XLA compilation cache
-  (``FLAGS.compile_cache_dir``, default ``~/.cache/paddle_tpu/xla``,
-  opt-out ``FLAGS.compile_cache=0``) plus the in-process warm-start
+  (``JAX_COMPILATION_CACHE_DIR`` when set, else
+  ``FLAGS.compile_cache_dir`` = ``<checkout>/.jax_cache``; opt-out
+  ``FLAGS.compile_cache=0``) plus the in-process warm-start
   registry in core.executor keyed by (program uid, version, feed
   signature), so repeat runs skip the cold compile.
 
@@ -48,7 +49,8 @@ from .core.executor import AsyncFetch, clear_warm_cache  # noqa: F401
 from .resilience import fault_point, record_event
 
 __all__ = ["AsyncFetch", "FeedPipeline", "materialize",
-           "materialize_scalar", "enable_compile_cache",
+           "materialize_scalar", "compile_cache_dir",
+           "enable_compile_cache",
            "maybe_enable_compile_cache", "clear_warm_cache"]
 
 
@@ -76,47 +78,41 @@ def materialize_scalar(value):
 _compile_cache_state = {"configured": False}
 
 
-def enable_compile_cache(dirname=None):
-    """Point jax's persistent XLA compilation cache at ``dirname``
-    (default ``FLAGS.compile_cache_dir``). Returns the directory, or None
-    when the running jax has no persistent-cache support."""
+def compile_cache_dir():
+    """Where the persistent compile cache lives:
+    ``JAX_COMPILATION_CACHE_DIR`` places it from outside; unset, it is
+    ``FLAGS.compile_cache_dir`` (``<checkout>/.jax_cache``)."""
+    from .flags import FLAGS
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or FLAGS.compile_cache_dir)
+
+
+def enable_compile_cache():
+    """Turn on jax's persistent XLA compilation cache and return its
+    directory (:func:`compile_cache_dir`). Where the environment variable
+    is set jax reads it itself and no directory is set in code."""
     import jax
 
-    from .flags import FLAGS
-    dirname = os.path.expanduser(dirname or FLAGS.compile_cache_dir)
-    try:
+    dirname = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(dirname, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", dirname)
-    except Exception:
-        return None
-    try:
-        # default threshold (1s) would skip every small program; the cache
-        # exists exactly to kill the ~29 s/step-class cold compiles AND the
-        # long tail of small ones on repeat bench runs
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-    except Exception:
-        pass
+    # the default threshold (1 s) would skip the long tail of small
+    # programs a repeat run compiles again
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
     _compile_cache_state["configured"] = True
     return dirname
 
 
 def maybe_enable_compile_cache():
     """Idempotent lazy hook the Executor calls before its first compile:
-    honors ``FLAGS.compile_cache`` (opt-out) and never overrides a cache
-    dir already configured (bench.py / JAX_COMPILATION_CACHE_DIR)."""
+    honors ``FLAGS.compile_cache`` (opt-out)."""
     if _compile_cache_state["configured"]:
         return
     _compile_cache_state["configured"] = True
     from .flags import FLAGS
-    if not FLAGS.compile_cache:
-        return
-    try:
-        import jax
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return  # respect an explicit user/bench configuration
-    except Exception:
-        return
-    enable_compile_cache()
+    if FLAGS.compile_cache:
+        enable_compile_cache()
 
 
 # -- background feed stage ----------------------------------------------------

@@ -37,8 +37,21 @@ class CPUPlace(Place):
 CUDAPlace = TPUPlace
 
 
-def is_compiled_with_tpu() -> bool:
-    try:
-        return any(d.platform != "cpu" for d in jax.devices())
-    except RuntimeError:
+def on_tpu() -> bool:
+    """The one answer to "compiled or interpreted": True when the default
+    device is a TPU (Pallas kernels compile through Mosaic, AMP casts to
+    bf16, the tuner times on the wall clock), False on the CPU backend
+    (kernels run in interpret mode). A backend that fails to initialise
+    raises here — it is never read as "no TPU"."""
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        return True
+    if platform == "cpu":
         return False
+    raise RuntimeError(
+        "paddle_tpu runs on 'tpu' (compiled) or 'cpu' (interpreted); "
+        "default device platform is %r" % (platform,))
+
+
+def is_compiled_with_tpu() -> bool:
+    return any(d.platform == "tpu" for d in jax.devices())

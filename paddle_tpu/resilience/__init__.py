@@ -9,8 +9,8 @@ this package is that posture rebuilt as one subsystem (HiCCL, arxiv
 failure semantics, not scattered try/excepts):
 
 - :mod:`.retry` — ``RetryPolicy``: the declared budget every
-  cross-host/cross-process edge spends (device probes in bench.py,
-  dataset cache lookups, pserver RPC).
+  cross-host/cross-process edge spends (dataset cache lookups,
+  pserver RPC).
 - :mod:`.faults` — deterministic injection registry; tests and the
   ``PADDLE_TPU_FAULT_SPEC`` env var arm named sites to raise, delay, or
   corrupt at the Nth hit.
@@ -37,7 +37,7 @@ Consumers elsewhere in the package: checkpoint.py (CRC + fallback to the
 previous complete checkpoint), trainer.py (SIGTERM preemption
 checkpoint), parallel/async_sgd.py (bounded reconnect, then recorded
 degraded continuation), paddle_tpu.native.Reader (reader.next site),
-dataset/common.py, and bench.py's device-init probe.
+and dataset/common.py.
 """
 from .events import (  # noqa: F401
     record_event, record_durable_event, events, clear_events,

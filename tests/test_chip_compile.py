@@ -1,0 +1,153 @@
+"""The six Pallas kernels, compiled by the TPU's own compiler for a
+*described* v5e (no chip attached), at the real widths the chip smoke and
+the benchmark cells use. A compile that passes here is not a chip run: it
+proves Mosaic accepts the kernel (block shapes, dot forms, VMEM budget),
+nothing about results or times. ``chip_smoke.py`` is the chip run.
+
+Everything that touches the TPU library lives in the module-scoped
+fixtures below (never at import or collection time): only the xdist
+worker that is handed this file loads libtpu, and it compiles in its own
+process. Keep every described-topology compile in THIS file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on the first described chip; the persistent compile cache
+    is off around these compiles (an entry compiled for a described chip
+    is written but cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _kernel_module(name):
+    # the package re-exports some kernels under their module's own name
+    import importlib
+    return importlib.import_module("paddle_tpu.kernels." + name)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+FLASH_SHAPES = [((96, 1024, 64), jnp.bfloat16), ((96, 1024, 64), jnp.float32),
+                ((32, 2048, 128), jnp.bfloat16),
+                ((32, 2048, 128), jnp.float32)]
+
+
+@pytest.mark.parametrize("shape,dtype", FLASH_SHAPES)
+def test_flash_attention_fwd_compiles(one_chip, shape, dtype):
+    fa = _kernel_module("flash_attention")
+
+    def fwd(q, k, v):
+        return fa._fa_forward(q, k, v, True, shape[-1] ** -0.5, shape[1],
+                              interpret=False)
+
+    _compile(fwd, one_chip, *[(shape, dtype)] * 3)
+
+
+@pytest.mark.parametrize("shape,dtype", FLASH_SHAPES)
+def test_flash_attention_bwd_compiles(one_chip, shape, dtype):
+    """Both backward kernels (dK/dV and dQ) in one program."""
+    fa = _kernel_module("flash_attention")
+
+    def bwd(q, k, v, do, lse, delta):
+        return fa._fa_backward(q, k, v, do, lse, delta, True,
+                               shape[-1] ** -0.5, shape[1], interpret=False)
+
+    row = (shape[:2], jnp.float32)
+    text = _compile(bwd, one_chip, *[(shape, dtype)] * 4, row, row)
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("kernel", ["lstm", "gru"])
+def test_fused_rnn_compiles(one_chip, kernel):
+    """The stacked-LSTM cell shape: T100, N64, D512, f32."""
+    T, N, D = 100, 64, 512
+    f32 = jnp.float32
+    if kernel == "lstm":
+        from paddle_tpu.kernels.fused_lstm import _forward
+
+        def fwd(xs, w, h0, c0, mask):
+            return _forward(xs, w, h0, c0, mask, False)[:2]
+
+        shapes = [((T, N, 4 * D), f32), ((D, 4 * D), f32), ((N, D), f32),
+                  ((N, D), f32), ((T, N), f32)]
+    else:
+        from paddle_tpu.kernels.fused_gru import _forward
+
+        def fwd(xs, w, h0, mask):
+            return _forward(xs, w, h0, mask, False)[0]
+
+        shapes = [((T, N, 3 * D), f32), ((D, 3 * D), f32), ((N, D), f32),
+                  ((T, N), f32)]
+    _compile(fwd, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("R,MB,T,nh,dh,dtype", [
+    (8, 8, 16, 2, 16, jnp.float32),
+    (8, 64, 16, 12, 64, jnp.float32),
+    (8, 64, 16, 12, 64, jnp.bfloat16),
+    (32, 128, 16, 16, 128, jnp.bfloat16),
+])
+def test_paged_attention_compiles(one_chip, R, MB, T, nh, dh, dtype):
+    pa = _kernel_module("paged_attention")
+
+    pages = R * MB + 1
+    br, bkv = pa.resolve_block_config(pa.DEFAULT_CONFIG, R, MB)
+
+    def fwd(q, kp, vp, tables, pos):
+        return pa._pa_pallas(q, kp, vp, tables, pos, br, bkv, False)
+
+    _compile(fwd, one_chip, ((R, nh, dh), dtype),
+             ((pages, T, nh, dh), dtype), ((pages, T, nh, dh), dtype),
+             ((R, MB), jnp.int32), ((R,), jnp.int32))
+
+
+@pytest.mark.parametrize("H,C", [(56, 64), (28, 128), (14, 256), (7, 512)])
+def test_conv3x3_compiles(one_chip, H, C):
+    from paddle_tpu.kernels.conv3x3 import _conv3x3_fwd
+
+    def fwd(x, w):
+        return _conv3x3_fwd(x, w, interpret=False)
+
+    _compile(fwd, one_chip, ((128, H, H, C), jnp.bfloat16),
+             ((3, 3, C, C), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("n,config", [
+    (1024, None),
+    (4096, (("block_k", 512), ("block_m", 256), ("block_n", 256))),
+])
+def test_matmul_compiles(one_chip, n, config):
+    from paddle_tpu.kernels.matmul import _matmul_fwd
+
+    def fwd(x, w):
+        return _matmul_fwd(x, w, interpret=False, config=config)
+
+    _compile(fwd, one_chip, ((n, n), jnp.bfloat16), ((n, n), jnp.bfloat16))

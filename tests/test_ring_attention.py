@@ -5,18 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.comm import compat as _compat
-
-# shard_map moved across jax versions (jax.experimental.shard_map in
-# <=0.4/0.5, jax.shard_map from 0.6); paddle_tpu.comm.compat bridges
-# both, so these tests run on either. A jax with NEITHER spelling cannot
-# run shard_map at all — one named module-level skip instead of the 8
-# ImportErrors this file used to produce on such installs.
-if not _compat.has_shard_map():
-    pytest.skip("jax %s has no shard_map (neither jax.shard_map nor "
-                "jax.experimental.shard_map)" % jax.__version__,
-                allow_module_level=True)
-
 from paddle_tpu.parallel import (make_mesh, ring_attention_sharded,
                                  ulysses_attention_sharded)
 

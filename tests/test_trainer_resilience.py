@@ -245,6 +245,13 @@ def test_trainer_watchdog_wiring(monkeypatch):
 
     monkeypatch.setattr(trainer_mod, "StepWatchdog", factory)
     tr = _build_trainer()
+    # warm the step program before the deadline is armed: the 0.3 s
+    # deadline is sized for steady steps, and the first batch's XLA
+    # compile under a loaded six-worker suite can outlast it — a second,
+    # legitimate firing (every ping re-arms a fired deadline) that this
+    # test's "exactly the seeded wedge" count would misread
+    tr.train(_batches(2), num_passes=1)
+    assert not fired
     faults.arm("trainer.step", "delay", nth=3, times=1, delay=1.2)
     with flags_guard(step_timeout_s=0.3):
         tr.train(_batches(5), num_passes=1)

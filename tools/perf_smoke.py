@@ -4,9 +4,8 @@ synchronous Trainer loop, (b) not be slower, and (c) show real overlap
 (feed-wait below step time), on a small run with a realistic per-batch
 host feed cost.
 
-The measurement itself lives in benchmark/pipeline_bench.py — the SAME
-harness bench.py's pipeline phase emits evidence from, so gate and
-evidence cannot drift. Companion to tools/lint.sh (static gate); this is
+The measurement itself lives in benchmark/pipeline_bench.py, so gate
+and evidence cannot drift. Companion to tools/lint.sh (static gate); this is
 the dynamic one. Exit 0 on pass, 1 on failure; prints a one-line JSON
 summary either way.
 
