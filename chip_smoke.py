@@ -689,9 +689,9 @@ def phase_dp4(cfg, seed, rehearse):
     mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
     t4, s4, main4 = _resnet_trainer(pt, cfg, seed, dist_mesh=mesh)
     exe = t4.exe
-    # the profiler makes the Executor compile the step ahead of time and
-    # record XLA's collective census for it (profiler.record_program_
-    # analysis) — the repo's own way to see what GSPMD inserted
+    # a step run while the host profiler is on is noted, and XLA's
+    # collective census of its executable is taken on demand (profiler.
+    # get_program_analysis) — the repo's own way to see what GSPMD inserted
     pt.profiler.start_profiler()
     try:
         loss4, secs4, late4, _ = _train_steps(pt, t4, s4,

@@ -109,6 +109,7 @@ def append_backward(loss: ir.Variable, parameter_list=None, no_grad_set=None,
     """
     block = loss.block
     program = block.program
+    first_backward_op = len(block.ops)
     no_grad: Set[str] = set(no_grad_set or ())
     for v in program.list_vars():
         if v.stop_gradient:
@@ -189,6 +190,8 @@ def append_backward(loss: ir.Variable, parameter_list=None, no_grad_set=None,
                             outputs={"Out": [canon]})
             g = canon
         params_and_grads.append((p, block.var(g)))
+    for op in block.ops[first_backward_op:]:
+        op.phase = "backward"
     _check_backward_pass(program)
     return params_and_grads
 

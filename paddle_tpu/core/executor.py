@@ -22,6 +22,7 @@ import functools
 import logging
 import threading
 import time
+import zlib
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -233,8 +234,14 @@ def trace_ops(block: ir.Block, env: Dict[str, Any], rng: RngSource,
     for op in block.ops:
         opdef = registry.lookup_checked(op.type)
         t0 = time.perf_counter() if timing else 0.0
+        # the op's device scope, "<phase>/<op>", always (trace-time work
+        # only): a step traced with scopes and one without would be two
+        # executables. The one generic_grad type stands for nearly every
+        # backward op, so it goes by the forward type it differentiates.
+        scope = "%s/%s" % (op.phase, op.attrs.get("__fwd_type__") or op.type)
         try:
-            opdef.lower(LowerContext(op, env, rng, block, value_hook))
+            with jax.named_scope(scope):
+                opdef.lower(LowerContext(op, env, rng, block, value_hook))
         except Exception as e:
             _annotate_op_error(e, op)
             raise
@@ -377,6 +384,14 @@ def _feed_signature(feed: Dict[str, Any]):
         else:
             sig.append((name, tuple(v.shape), str(v.dtype)))
     return tuple(sig)
+
+
+def _host_nbytes(v):
+    """Bytes a fed value holds on the host (nothing once it is on the
+    device): what the ``upload`` span says it moved."""
+    if isinstance(v, LoDTensor):
+        v = v.numpy()
+    return v.nbytes if isinstance(v, np.ndarray) else 0
 
 
 def _to_device_value(v, device=None):
@@ -534,12 +549,14 @@ class AsyncFetch(object):
     def value(self):
         """Materialise (once) and return the host value."""
         if not self._done:
-            self._host = _fetch_to_host(self._value, self._return_numpy)
+            from .. import profiler as _prof
+            with _prof.span("fetch"):
+                self._host = _fetch_to_host(self._value,
+                                            self._return_numpy)
             self._done = True
             self._value = None  # release the device buffer reference
             if self._stats is not None:
                 self._stats["fetch_sync_count"] += 1
-            from .. import profiler as _prof
             _prof.update_pipeline_counters(fetch_sync_count=1)
         return self._host
 
@@ -634,22 +651,92 @@ def _program_trace_lock(uid):
         return lk
 
 
+def _abstract(x):
+    """Shape, dtype and (for a committed array) sharding of one argument
+    of a compiled step: what lowering it again needs, and no array."""
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                sharding=sharding,
+                                weak_type=getattr(x, "weak_type", False))
+
+
 class _TracedOnce(object):
-    """Compiled-step wrapper that serializes the tracing first call."""
+    """Compiled-step wrapper that serializes the tracing first call, and
+    keeps that call's abstract values so that the profiler can ask for
+    the facts of the executable that runs (``facts``) without a second,
+    profiled executable in its place."""
 
-    __slots__ = ("fn", "_ready")
+    __slots__ = ("fn", "_ready", "_span_args", "_mesh_devices", "_avals",
+                 "_facts")
 
-    def __init__(self, fn):
+    def __init__(self, fn, program, mesh_devices=1):
         self.fn = fn
         self._ready = threading.Event()
+        self._span_args = {"program": program._uid,
+                           "version": program._version}
+        self._mesh_devices = mesh_devices
+        self._avals = None
+        self._facts = None
 
     def __call__(self, *args):
         if self._ready.is_set():
             return self.fn(*args)
+        from .. import profiler as _prof
         with _FIRST_TRACE_LOCK:
-            out = self.fn(*args)
-        self._ready.set()
+            if self._ready.is_set():    # another thread's first call won
+                return self.fn(*args)
+            # before the call: it donates the state's buffers
+            self._avals = jax.tree_util.tree_map(_abstract, args)
+            _prof.set_phase("trace")    # per-op spans now time the lowering
+            try:
+                with _prof.span("compile", **self._span_args):
+                    out = self.fn(*args)
+            finally:
+                _prof.set_phase("eager")
+            self._ready.set()
         return out
+
+    def facts(self):
+        """``{"module", "scopes", "analysis"}`` of the compiled step (see
+        profiler.device_scopes / analyse_compiled), or None before its
+        first call. Lowers and compiles from the kept abstract values:
+        jax's own caches answer, no second executable is built."""
+        if self._facts is None and self._ready.is_set():
+            from .. import profiler as _prof
+            with _FIRST_TRACE_LOCK:
+                compiled = self.fn.lower(*self._avals).compile()
+            module, scopes = _prof.scopes_of_module(compiled.as_text())
+            self._facts = {
+                "module": module, "scopes": scopes,
+                "analysis": _prof.analyse_compiled(compiled,
+                                                   self._mesh_devices)}
+        return self._facts
+
+
+def _step_name(program, feed_template, fetch_names, repeat, dist):
+    """The jitted step's name, ``paddle_tpu_step_<8 hex>``: its module's
+    name on the trace's ``XLA Modules`` line and the key of its table in
+    ``profiler.device_scopes()``, so two steps of one process (startup and
+    main, a last smaller batch) must not share it. The hex is a checksum
+    of what the step is built from and nothing that differs between two
+    runs of one script (no uid). The name is part of the persistent
+    compile cache's key, the ops' named scopes are not (debug info is
+    stripped from it): whoever changes what the scopes say changes the
+    prefix, or a cache directory hands back an executable whose device
+    ops bear the old scopes."""
+    content = repr((
+        [(op.type, op.phase, sorted(op.inputs.items()),
+          sorted(op.outputs.items())) for op in _iter_ops(
+              program.global_block())],
+        [(v.name, v.shape, str(v.dtype)) for v in program.list_vars()],
+        _feed_signature(feed_template), fetch_names, repeat,
+        dist.cache_token() if dist is not None else None))
+    return "paddle_tpu_step_%08x" % zlib.crc32(content.encode())
+
+
+def compiled_steps():
+    """The compiled steps the process keeps (the warm registry)."""
+    return list(_WARM_JIT_CACHE.values())
 
 
 def clear_warm_cache():
@@ -831,7 +918,15 @@ class Executor(object):
         first access; paths that compute eagerly on the host
         (``check_nan_inf``, host ops) still return handles, just trivially
         ready ones."""
+        from .. import profiler as _prof
         program = program if program is not None else ir.default_main_program()
+        with _prof.span("run", program=program._uid):
+            return self._run(program, feed, fetch_list, scope, return_numpy,
+                             use_jit, dist_context, repeat, sync)
+
+    def _run(self, program, feed, fetch_list, scope, return_numpy, use_jit,
+             dist_context, repeat, sync):
+        from .. import profiler as _prof
         self._maybe_verify(program)
         scope = scope if scope is not None else global_scope()
         feed = feed or {}
@@ -842,10 +937,11 @@ class Executor(object):
 
         # under a mesh, leave feeds uncommitted: jit's in_shardings place them
         dev = None if dist is not None else self._device()
-        dev_feed = {k: _to_device_value(v, dev) for k, v in feed.items()}
+        with _prof.span("upload", bytes=sum(map(_host_nbytes,
+                                                feed.values()))):
+            dev_feed = {k: _to_device_value(v, dev) for k, v in feed.items()}
         block = program.global_block()
 
-        from .. import profiler as _prof
         timing = _prof.profiler_enabled()
         t0 = time.perf_counter() if timing else 0.0
         if (_is_host_block(block) or not use_jit or self.check_nan_inf
@@ -928,7 +1024,8 @@ class Executor(object):
             self.stats["lazy_fetches"] += len(outs)
             return [AsyncFetch(o, return_numpy=return_numpy,
                                stats=self.stats) for o in outs]
-        return [_fetch_to_host(o, return_numpy) for o in outs]
+        with _prof.span("fetch"):
+            return [_fetch_to_host(o, return_numpy) for o in outs]
 
     # -- hybrid path: jitted device segments + interpreted host ops ----------
     def _run_hybrid(self, program, feed, fetch_names, scope):
@@ -1119,6 +1216,12 @@ class Executor(object):
     # -- jit path --------------------------------------------------------------
     def _run_jit(self, program, feed, fetch_names, scope, dist=None,
                  repeat=1):
+        from .. import profiler as _prof
+        with _prof.span("dispatch"):
+            return self._dispatch(program, feed, fetch_names, scope,
+                                  dist, repeat)
+
+    def _dispatch(self, program, feed, fetch_names, scope, dist, repeat):
         per_scope = self._state_memo.setdefault(scope, {})
         # parent scopes can own persistables found via the lookup walk;
         # include their name-set versions so additions there invalidate
@@ -1182,7 +1285,7 @@ class Executor(object):
                      else jax.device_put(v, dev) for n, v in state.items()}
         from .. import profiler as _prof
         key = (program._uid, program._version, _feed_signature(feed),
-               tuple(fetch_names), repeat, _prof.profiler_enabled(),
+               tuple(fetch_names), repeat,
                dist.cache_token() if dist is not None else None,
                # the compiled step depends on the comm flags under a
                # mesh (explicit collective routing + the byte model):
@@ -1213,9 +1316,10 @@ class Executor(object):
                 self._sharding_preflight(program, dist)
             shardings = (_dist_shardings(dist, state, feed)
                          if dist is not None else None)
-            fn = _TracedOnce(self._compile(
-                program, feed, fetch_names, state_names,
-                shardings=shardings, dist=dist, repeat=repeat))
+            fn = _TracedOnce(
+                self._compile(program, feed, fetch_names, state_names,
+                              shardings=shardings, dist=dist, repeat=repeat),
+                program, dist.num_devices if dist is not None else 1)
             self.stats["compiles"] += 1
             if dist is not None:
                 self._record_comm_model(program, dist)
@@ -1223,6 +1327,8 @@ class Executor(object):
             if len(_WARM_JIT_CACHE) >= _WARM_JIT_LIMIT:
                 _WARM_JIT_CACHE.clear()
             _WARM_JIT_CACHE[key] = fn
+        if _prof.profiler_enabled():
+            _prof.note_profiled_step("program_%d" % program._uid, fn)
         rng_key = self._rng_key(program, scope)
         if dist is None:
             rng_key = jax.device_put(rng_key, dev)
@@ -1450,6 +1556,7 @@ class Executor(object):
                     (state, rng_key), fs = jax.lax.scan(
                         body, (state, rng_key), None, length=repeat - 1)
                     return [f[-1] for f in fs], state, rng_key
+            fn.__name__ = fallback.__name__     # the step's one name
             jitted = jax.jit(fn, donate_argnums=(0,),
                              in_shardings=shardings)
             # dry-run the whole build abstractly before committing: a
@@ -1520,6 +1627,8 @@ class Executor(object):
                     cell["fn"] = built
             return cell["fn"](state, feed, rng_key)
 
+        # what _TracedOnce.facts lowers: the build the first call chose
+        dispatch.lower = lambda *avals: cell["fn"].lower(*avals)
         return dispatch
 
     def _record_comm_model(self, program, dist):
@@ -1600,7 +1709,7 @@ class Executor(object):
                         value, dist.sharding_for(name, value))
                 return value
 
-        def one_step(state, feed, rng_key):
+        def paddle_tpu_step(state, feed, rng_key):
             env = dict(feed)
             env.update(state)
             rng = RngSource(rng_key)
@@ -1621,56 +1730,30 @@ class Executor(object):
             return fetches, new_state, rng.key
 
         if repeat == 1:
-            fn = one_step
+            fn = paddle_tpu_step
         else:
             def fn(state, feed, rng_key):
                 # first step outside the scan: it may add extra_out keys,
                 # after which the carry structure is stable
-                fetches, state, rng_key = one_step(state, feed, rng_key)
+                fetches, state, rng_key = paddle_tpu_step(state, feed,
+                                                          rng_key)
 
                 def body(carry, _):
                     st, key = carry
-                    f, st2, key2 = one_step(st, feed, key)
+                    f, st2, key2 = paddle_tpu_step(st, feed, key)
                     return (st2, key2), f
 
                 (state, rng_key), fs = jax.lax.scan(
                     body, (state, rng_key), None, length=repeat - 1)
                 fetches = [f[-1] for f in fs]  # last step's fetches
                 return fetches, state, rng_key
+        fn.__name__ = _step_name(program, feed_template, fetch_names, repeat,
+                                 dist)
 
         if shardings is not None:
             jitted = jax.jit(fn, donate_argnums=(0,), in_shardings=shardings)
         else:
             jitted = jax.jit(fn, donate_argnums=(0,))
-        from .. import profiler as _prof
-        if _prof.profiler_enabled():
-            # AOT-compile so the timeline artifact gets XLA's compiled cost
-            # analysis + collective census for this program
-            # (device_tracer.h role; see profiler.write_timeline)
-            label = "program_%d" % program._uid
-            mesh_devices = (dist.num_devices if dist is not None else 1)
-
-            memo = {}
-
-            def profiled(state, feed, rng_key):
-                if "c" not in memo:
-                    _prof.set_phase("trace")
-                    try:
-                        memo["c"] = jitted.lower(state, feed,
-                                                 rng_key).compile()
-                    finally:
-                        _prof.set_phase("eager")
-                    _prof.record_program_analysis(label, memo["c"],
-                                                  mesh_devices)
-                    memo["entry"] = _prof.get_program_analysis(label)
-                else:
-                    # O(1) re-insert so reset_profiler() between sessions
-                    # doesn't lose the programs section (the expensive HLO
-                    # scan ran once at compile time)
-                    _prof.put_program_analysis(label, memo["entry"])
-                return memo["c"](state, feed, rng_key)
-
-            return profiled
         if dist is not None:
             # (4) of the comm tentpole: eligible pure-DP programs route
             # their grad sync through the explicit comm collectives; the
@@ -1834,7 +1917,8 @@ class Executor(object):
         persist = self._persistable_names(program)
         for n, v in env.items():
             if n in persist:
-                # scope never holds ConcreteScalar (see one_step new_state)
+                # scope never holds ConcreteScalar (see paddle_tpu_step's
+                # new_state)
                 scope.set_var(n, raw_data(v) if isinstance(v, ConcreteScalar)
                               else v)
         scope.set_var(RNG_VAR, rng_key)

@@ -164,6 +164,12 @@ class Operator(object):
     sub-Blocks (control flow), matching attr type BLOCK.
     """
 
+    # which part of a training step appended the op: "backward"
+    # (append_backward), "update" (Optimizer.minimize's pass) or "forward"
+    # (everything else). A python attribute, not a desc attr: it names the
+    # op's device scope (executor.trace_ops) and is not serialised.
+    phase = "forward"
+
     def __init__(self, block, type, inputs=None, outputs=None, attrs=None):
         self.block = block
         self.type = type

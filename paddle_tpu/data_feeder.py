@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import profiler as _prof
 from .core.ir import Variable
 from .core.lod import LoDTensor, lengths_to_offsets
 from .core.types import convert_dtype
@@ -84,16 +85,20 @@ class DataFeeder(object):
     def feed(self, iterable):
         """Minibatch (iterable of per-sample field tuples) -> feed dict.
         Stateless per call (thread-safe; see _converter_specs)."""
-        converters = [
-            DataToLoDTensorConverter(lod_level=lod, shape=shape or (),
-                                     dtype=dtype)
-            for lod, shape, dtype in self._converter_specs]
-        for each_sample in iterable:
-            if len(each_sample) != len(converters):
-                raise ValueError(
-                    "sample has %d fields, feed_list expects %d"
-                    % (len(each_sample), len(converters)))
-            for value, conv in zip(each_sample, converters):
-                conv.feed(value)
-        return {name: conv.done()
-                for name, conv in zip(self.feed_names, converters)}
+        with _prof.span("feed") as span:
+            converters = [
+                DataToLoDTensorConverter(lod_level=lod, shape=shape or (),
+                                         dtype=dtype)
+                for lod, shape, dtype in self._converter_specs]
+            rows = 0
+            for each_sample in iterable:
+                if len(each_sample) != len(converters):
+                    raise ValueError(
+                        "sample has %d fields, feed_list expects %d"
+                        % (len(each_sample), len(converters)))
+                for value, conv in zip(each_sample, converters):
+                    conv.feed(value)
+                rows += 1
+            span.set_metadata(rows=rows)
+            return {name: conv.done()
+                    for name, conv in zip(self.feed_names, converters)}

@@ -86,11 +86,14 @@ class Optimizer(object):
                  no_grad_set=None):
         """reference: optimizer.py Optimizer.minimize."""
         params_grads = append_backward(loss, parameter_list, no_grad_set)
+        first_update_op = len(loss.block.ops)
         params_grads = append_gradient_clip_ops(params_grads)
         params_grads = append_regularization_ops(params_grads,
                                                  self.regularization)
         optimize_ops = self._create_optimization_pass(
             params_grads, loss, startup_program)
+        for op in loss.block.ops[first_update_op:]:
+            op.phase = "update"
         return optimize_ops, params_grads
 
     def _create_optimization_pass(self, parameters_and_grads, loss,

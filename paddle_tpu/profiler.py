@@ -7,15 +7,25 @@ timeline). On TPU the per-op host loop doesn't exist — one jitted program is
 one device launch — so the host profiler records per-run wall/compile times
 per program, and the device timeline comes from jax.profiler's xplane trace
 (TensorBoard-compatible), which is the CUPTI-tracer equivalent.
+
+The program's own spans (``span`` / ``step_span``) are events of that same
+trace, on its clock: they are always in the code and record only while a
+jax.profiler session is open (``xla_trace``). ``device_scopes`` gives the
+table that names each device op of a compiled step by its forward /
+backward / update scope (doc/diagnostics.md).
 """
 from __future__ import annotations
 
 import contextlib
+import re
 import time
 from collections import defaultdict
 
+import jax
+
 __all__ = ["timer", "stat_summary", "print_stats", "reset_stats",
            "BarrierStat",
+           "span", "step_span", "device_scopes",
            "start_profiler", "stop_profiler", "reset_profiler", "profiler",
            "cuda_profiler", "xla_trace", "profiler_enabled", "record_run",
            "record_op_event", "record_program_analysis", "write_timeline",
@@ -45,6 +55,7 @@ _enabled = False
 _records = defaultdict(list)  # label -> [seconds]
 _op_events = []               # chrome-trace X events (eager per-op spans)
 _program_analyses = {}        # label -> {flops, bytes, collectives, ...}
+_profiled_steps = {}          # label -> compiled step run while profiling
 _pipeline_counters = defaultdict(float)  # async-pipeline observability
 _serving_counters = defaultdict(float)   # online-serving observability
 _comm_counters = defaultdict(float)      # gradient-communication observability
@@ -95,6 +106,7 @@ def reset_profiler():
     _records.clear()
     del _op_events[:]
     _program_analyses.clear()
+    _profiled_steps.clear()
     _pipeline_counters.clear()
     _serving_counters.clear()
     _comm_counters.clear()
@@ -486,6 +498,12 @@ def record_program_analysis(label, compiled, mesh_devices=1):
     inserted — the mesh 'barrier stat': every collective is a cross-device
     sync point (reference: platform/device_tracer.h timeline +
     profiler.proto role, in compiled-program form)."""
+    _program_analyses[label] = analyse_compiled(compiled, mesh_devices)
+
+
+def analyse_compiled(compiled, mesh_devices=1):
+    """The ``programs`` entry of one compiled program (see
+    record_program_analysis)."""
     entry = {"mesh_devices": int(mesh_devices)}
     try:
         ca = compiled.cost_analysis()
@@ -519,16 +537,76 @@ def record_program_analysis(label, compiled, mesh_devices=1):
                 + getattr(mem, "output_size_in_bytes", 0))
     except Exception:
         pass
-    _program_analyses[label] = entry
+    return entry
+
+
+def note_profiled_step(label, step):
+    """Called by Executor._run_jit while profiling is on: ``step`` (the
+    executor's compiled-step wrapper) ran under ``label``. Its analysis
+    is taken on demand (``write_timeline`` / ``get_program_analysis``)
+    from the step's kept abstract values, so the executable that runs is
+    the one that runs with profiling off."""
+    _profiled_steps[label] = step
+
+
+def _resolve_profiled_steps():
+    for label, step in list(_profiled_steps.items()):
+        facts = step.facts()
+        if facts is not None:
+            _program_analyses[label] = facts["analysis"]
+        del _profiled_steps[label]
 
 
 def get_program_analysis(label):
+    _resolve_profiled_steps()
     return _program_analyses.get(label)
 
 
-def put_program_analysis(label, entry):
-    if entry is not None:
-        _program_analyses[label] = entry
+_SCOPE_RE = re.compile(r"(?:^|/)((?:forward|backward|update)/[^/\"]+)")
+_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+UNSCOPED = "unscoped"
+
+
+def scopes_of_module(text):
+    """(module name, {instruction name: scope}) from a compiled module's
+    text. The scope is the outermost ``<phase>/<op>`` that
+    ``executor.trace_ops`` opened around the op's lowering, read from the
+    instruction's own ``op_name`` (a fusion takes the fusion
+    instruction's); an instruction the compiler made with no such
+    ``op_name`` (copies, async starts) is ``unscoped``, never guessed.
+    Every computation of the module is read: a ``while`` body's
+    instructions run as device ops of their own."""
+    module, table = None, {}
+    for line in text.splitlines():
+        if module is None and line.startswith("HloModule "):
+            module = line.split()[1].rstrip(",")
+            continue
+        m = _INSTRUCTION_RE.match(line)
+        if m is None:
+            continue
+        op_name = _OP_NAME_RE.search(line)
+        scope = _SCOPE_RE.search(op_name.group(1)) if op_name else None
+        table[m.group(1)] = scope.group(1) if scope else UNSCOPED
+    return module, table
+
+
+def device_scopes():
+    """``{module name: {instruction name: scope}}`` for every step this
+    process compiled and still keeps (the executor's warm cache keeps
+    them after the Trainer is gone). A device op's event in the trace
+    (line ``XLA Ops``) carries its instruction's name and no scope; this
+    table is the join. Each step is lowered again from the abstract values
+    of its first call (jax's own caches answer: nothing is compiled a
+    second time) and its optimised module's text is read, once per step:
+    call it outside any timed window."""
+    from .core import executor as _executor
+    out = {}
+    for step in _executor.compiled_steps():
+        facts = step.facts()
+        if facts is not None:
+            out[facts["module"]] = facts["scopes"]
+    return out
 
 
 def write_timeline(path):
@@ -577,6 +655,7 @@ def write_timeline(path):
       for the elastic Trainer worker).
     """
     import json
+    _resolve_profiled_steps()
     rows = []
     for label, times in _records.items():
         n = len(times)
@@ -667,11 +746,25 @@ def cuda_profiler(output_file=None, output_mode=None, config=None):
         yield
 
 
+def span(name, **args):
+    """A host span ``paddle_tpu/<name>`` in the jax profiler's trace, on
+    the clock of the device lines. Recorded only while a jax.profiler
+    session is open (``xla_trace``); otherwise it costs one activity
+    check. It starts where it is made: make it in the ``with``."""
+    return jax.profiler.TraceAnnotation("paddle_tpu/" + name, **args)
+
+
+def step_span(n, **args):
+    """The step span ``paddle_tpu/train_step`` with ``step_num=n``: the
+    parent of one training step's spans (see ``span``)."""
+    return jax.profiler.StepTraceAnnotation("paddle_tpu/train_step",
+                                            step_num=n, **args)
+
+
 @contextlib.contextmanager
 def xla_trace(logdir):
     """jax.profiler trace — kernel timeline, HBM usage, per-fusion costs
     (device_tracer equivalent; reference: platform/device_tracer.h:30-60)."""
-    import jax
     jax.profiler.start_trace(str(logdir))
     try:
         yield

@@ -74,6 +74,29 @@ class EndIteration(object):
         self._metrics = value or {}
 
 
+def _step_spans(batches, pass_id, first_step):
+    """``enumerate(batches)`` with every iteration of the caller's loop
+    inside its own ``paddle_tpu/train_step`` span: from taking the batch
+    off the reader to the end of the loop's body (after ``EndIteration``'s
+    handler). ``step_num`` counts on from ``first_step`` (batches since
+    ``train()`` began). A span cannot be taken back, so the call that
+    finds the reader exhausted leaves one too, marked ``end_of_pass=1``
+    and with no batch in it."""
+    from . import profiler as _prof
+    batches = iter(batches)
+    batch_id = 0
+    while True:
+        with _prof.step_span(first_step + batch_id, pass_id=pass_id,
+                             batch_id=batch_id) as span:
+            try:
+                data = next(batches)
+            except StopIteration:
+                span.set_metadata(end_of_pass=1)
+                return
+            yield batch_id, data
+        batch_id += 1
+
+
 class Trainer(object):
     """Drive a built program over a reader with events.
 
@@ -357,6 +380,7 @@ class Trainer(object):
         hook_installed = False
         if self.checkpoint_dir or (worker is not None and worker.root):
             hook_installed, old_sigterm = self._install_preemption_hook()
+        steps_done = 0
         try:
             for pass_id in range(num_passes):
                 handler(BeginPass(pass_id))
@@ -368,124 +392,124 @@ class Trainer(object):
                     # too — a reader wedged before its first yield is
                     # still a hang
                     watchdog.arm("pass%d/start" % pass_id)
-                with _prof.timer("pass"):
-                    try:
-                        if use_pipe:
-                            pipe = FeedPipeline(reader, self.feeder,
-                                                self.exe, depth=depth)
-                            batches = pipe
-                        else:
-                            batches = reader()
-                        last_iter_t = None
-                        feed_wait_seen = 0.0
+                try:
+                    if use_pipe:
+                        pipe = FeedPipeline(reader, self.feeder,
+                                            self.exe, depth=depth)
+                        batches = pipe
+                    else:
+                        batches = reader()
+                    last_iter_t = None
+                    feed_wait_seen = 0.0
+                    commit_ms_last = 0.0
+                    for batch_id, data in _step_spans(batches, pass_id,
+                                                      steps_done):
+                        # the gray-failure heartbeat: the wall
+                        # delta between iteration starts (reader
+                        # wait + dispatch + any injected stall —
+                        # the async pipeline makes a batch-timer-
+                        # only number blind to these) MINUS the
+                        # commit/checkpoint span: that is
+                        # legitimate per-role overhead (only the
+                        # lease owner pays it), not gray slowness —
+                        # the step watchdog pauses around it for
+                        # the same reason
+                        now_t = time.monotonic()
+                        if worker is not None and \
+                                last_iter_t is not None:
+                            fw = None
+                            if pipe is not None:
+                                total = pipe.stats["feed_wait_ms"]
+                                fw = total - feed_wait_seen
+                                feed_wait_seen = total
+                            worker.publish_heartbeat(
+                                max((now_t - last_iter_t) * 1e3
+                                    - commit_ms_last, 0.0),
+                                feed_wait_ms=fw)
+                        last_iter_t = now_t
                         commit_ms_last = 0.0
-                        for batch_id, data in enumerate(batches):
-                            # the gray-failure heartbeat: the wall
-                            # delta between iteration starts (reader
-                            # wait + dispatch + any injected stall —
-                            # the async pipeline makes a batch-timer-
-                            # only number blind to these) MINUS the
-                            # commit/checkpoint span: that is
-                            # legitimate per-role overhead (only the
-                            # lease owner pays it), not gray slowness —
-                            # the step watchdog pauses around it for
-                            # the same reason
-                            now_t = time.monotonic()
-                            if worker is not None and \
-                                    last_iter_t is not None:
-                                fw = None
-                                if pipe is not None:
-                                    total = pipe.stats["feed_wait_ms"]
-                                    fw = total - feed_wait_seen
-                                    feed_wait_seen = total
-                                worker.publish_heartbeat(
-                                    max((now_t - last_iter_t) * 1e3
-                                        - commit_ms_last, 0.0),
-                                    feed_wait_ms=fw)
-                            last_iter_t = now_t
-                            commit_ms_last = 0.0
-                            handler(BeginIteration(pass_id, batch_id))
+                        handler(BeginIteration(pass_id, batch_id))
+                        if watchdog is not None:
+                            watchdog.ping("pass%d/batch%d"
+                                          % (pass_id, batch_id))
+                        # chaos lever: delay = a wedged step (the
+                        # watchdog's quarry), raise = a step failure
+                        # that propagates (the supervisor's
+                        # transient-restart path)
+                        fault_point("trainer.step")
+                        if use_pipe:
+                            # data is already a device-resident
+                            # feed dict from the pipeline ring
+                            outs = self.exe.run(
+                                self.main_program, feed=data,
+                                fetch_list=self.fetch_list,
+                                sync=False)
+                            cost = outs[0]  # lazy AsyncFetch
+                        else:
+                            outs = self.exe.run(
+                                self.main_program,
+                                feed=self.feeder.feed(data),
+                                fetch_list=self.fetch_list)
+                            cost = float(
+                                np.asarray(outs[0]).reshape(-1)[0])
+                        skipped = False
+                        if guard is not None:
+                            # the guardrail sync point: a wedged
+                            # device surfaces HERE under the async
+                            # pipeline, inside the armed deadline
+                            cost = materialize_scalar(cost)
+                            skipped = guard.check(
+                                cost, pass_id=pass_id,
+                                batch_id=batch_id) != "ok"
                             if watchdog is not None:
-                                watchdog.ping("pass%d/batch%d"
+                                watchdog.ping(
+                                    "pass%d/batch%d/guarded"
+                                    % (pass_id, batch_id))
+                        counted = True
+                        if worker is not None:
+                            # lease commit + (on the cadence) the
+                            # paired checkpoint — not a step, so the
+                            # step deadline pauses around it
+                            if watchdog is not None:
+                                watchdog.disarm()
+                            commit_t0 = time.monotonic()
+                            counted = worker.commit(cost=cost,
+                                                    skipped=skipped)
+                            commit_ms_last = (time.monotonic()
+                                              - commit_t0) * 1e3
+                            if watchdog is not None:
+                                watchdog.arm("pass%d/batch%d/next"
+                                             % (pass_id, batch_id))
+                        if not skipped and counted:
+                            # a lapsed lease (counted=False) is a
+                            # batch the audited timeline disowns —
+                            # a survivor re-runs it; pass metrics
+                            # must agree with the lease accounting
+                            costs.append(cost)
+                        if log_period and \
+                                (batch_id + 1) % log_period == 0:
+                            # the reference's per-log_period batch line
+                            # (reference: TrainerInternal.cpp:159-171)
+                            # — a declared materialization point
+                            window = [materialize_scalar(c)
+                                      for c in costs[-log_period:]]
+                            if window:
+                                print("pass %d batch %d: cost=%.6f "
+                                      "(avg %.6f)"
+                                      % (pass_id, batch_id, window[-1],
+                                         float(np.mean(window))))
+                            if watchdog is not None:
+                                watchdog.ping("pass%d/batch%d/log"
                                               % (pass_id, batch_id))
-                            # chaos lever: delay = a wedged step (the
-                            # watchdog's quarry), raise = a step failure
-                            # that propagates (the supervisor's
-                            # transient-restart path)
-                            fault_point("trainer.step")
-                            with _prof.timer("batch"):
-                                if use_pipe:
-                                    # data is already a device-resident
-                                    # feed dict from the pipeline ring
-                                    outs = self.exe.run(
-                                        self.main_program, feed=data,
-                                        fetch_list=self.fetch_list,
-                                        sync=False)
-                                    cost = outs[0]  # lazy AsyncFetch
-                                else:
-                                    outs = self.exe.run(
-                                        self.main_program,
-                                        feed=self.feeder.feed(data),
-                                        fetch_list=self.fetch_list)
-                                    cost = float(
-                                        np.asarray(outs[0]).reshape(-1)[0])
-                            skipped = False
-                            if guard is not None:
-                                # the guardrail sync point: a wedged
-                                # device surfaces HERE under the async
-                                # pipeline, inside the armed deadline
-                                cost = materialize_scalar(cost)
-                                skipped = guard.check(
-                                    cost, pass_id=pass_id,
-                                    batch_id=batch_id) != "ok"
-                                if watchdog is not None:
-                                    watchdog.ping(
-                                        "pass%d/batch%d/guarded"
-                                        % (pass_id, batch_id))
-                            counted = True
-                            if worker is not None:
-                                # lease commit + (on the cadence) the
-                                # paired checkpoint — not a step, so the
-                                # step deadline pauses around it
-                                if watchdog is not None:
-                                    watchdog.disarm()
-                                commit_t0 = time.monotonic()
-                                counted = worker.commit(cost=cost,
-                                                        skipped=skipped)
-                                commit_ms_last = (time.monotonic()
-                                                  - commit_t0) * 1e3
-                                if watchdog is not None:
-                                    watchdog.arm("pass%d/batch%d/next"
-                                                 % (pass_id, batch_id))
-                            if not skipped and counted:
-                                # a lapsed lease (counted=False) is a
-                                # batch the audited timeline disowns —
-                                # a survivor re-runs it; pass metrics
-                                # must agree with the lease accounting
-                                costs.append(cost)
-                            if log_period and \
-                                    (batch_id + 1) % log_period == 0:
-                                # the reference's per-log_period batch line
-                                # (reference: TrainerInternal.cpp:159-171)
-                                # — a declared materialization point
-                                window = [materialize_scalar(c)
-                                          for c in costs[-log_period:]]
-                                if window:
-                                    print("pass %d batch %d: cost=%.6f "
-                                          "(avg %.6f)"
-                                          % (pass_id, batch_id, window[-1],
-                                             float(np.mean(window))))
-                                if watchdog is not None:
-                                    watchdog.ping("pass%d/batch%d/log"
-                                                  % (pass_id, batch_id))
-                            handler(EndIteration(pass_id, batch_id, cost,
-                                                 {"fetches": outs[1:]}))
-                            if self.preempted:
-                                break
-                    finally:
-                        if pipe is not None:
-                            pipe.close()
-                            self._merge_pipeline_stats(pipe, _prof)
+                        handler(EndIteration(pass_id, batch_id, cost,
+                                             {"fetches": outs[1:]}))
+                        if self.preempted:
+                            break
+                finally:
+                    if pipe is not None:
+                        pipe.close()
+                        self._merge_pipeline_stats(pipe, _prof)
+                steps_done += batch_id + 1
                 # pass end is a materialization point (and it precedes
                 # every checkpoint below, keeping saves synchronous)
                 costs = [materialize_scalar(c) for c in costs]
