@@ -18,6 +18,7 @@ kept as the debug path.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import threading
@@ -394,6 +395,17 @@ def _host_nbytes(v):
     return v.nbytes if isinstance(v, np.ndarray) else 0
 
 
+def _upload(feed, device):
+    """``feed`` on the device, inside an ``upload`` span (arg ``bytes``)
+    where any of it was still on the host: a feed that ``prepare_feed``
+    already moved leaves no second span of 0 bytes behind."""
+    from .. import profiler as _prof
+    nbytes = sum(map(_host_nbytes, feed.values()))
+    with (_prof.span("upload", bytes=nbytes) if nbytes
+          else contextlib.nullcontext()):
+        return {k: _to_device_value(v, device) for k, v in feed.items()}
+
+
 def _to_device_value(v, device=None):
     """Normalise a fed python value into a jnp array or TracedLoD."""
     if isinstance(v, LoDTensor):
@@ -768,6 +780,11 @@ class Executor(object):
         # dispatch_depth) make the async execution pipeline observable:
         # overlap is only real when feed_wait stays below step time and
         # fetch syncs stay rare (see doc/async_pipeline.md)
+        # lookahead_steps counts the steps of Trainer's default loop whose
+        # next batch was prepared while they ran, lookahead_loss_ready
+        # those of them that had finished on the device when the host came
+        # back for the loss: a ratio near 1 says the host sets the pace,
+        # near 0 the device
         # the comm_* entries model the DP grad-sync wire traffic of the
         # compiled program under the active comm policy (paddle_tpu.comm;
         # refreshed per compile), and record quant fallbacks folded in by
@@ -790,6 +807,7 @@ class Executor(object):
                       "lazy_fetches": 0, "fetch_sync_count": 0,
                       "compiles": 0, "compile_cache_hits": 0,
                       "feed_wait_ms": 0.0,
+                      "lookahead_steps": 0, "lookahead_loss_ready": 0,
                       "dispatch_depth": 0, "comm_bytes": 0,
                       "comm_buckets": 0, "comm_quant_fallbacks": 0,
                       "comm_path": "",
@@ -856,7 +874,9 @@ class Executor(object):
         """Transfer a feed dict to the device once; the returned dict can be
         passed to run() repeatedly without re-transferring (device_put of an
         already-committed array is a no-op). The reference's analog is the
-        data-provider double buffer keeping batches device-resident.
+        data-provider double buffer keeping batches device-resident. The
+        transfer is the ``upload`` span of the step that will run on it;
+        ``run()`` opens none for a feed that is already here.
 
         ``local_shard=True`` (multi-host, needs a dist_context): each
         process passes only ITS slice of the global batch — the slices are
@@ -896,7 +916,7 @@ class Executor(object):
                 out[k] = jax.make_array_from_process_local_data(sh, arr)
             return out
         dev = None if self.dist_context is not None else self._device()
-        return {k: _to_device_value(v, dev) for k, v in feed.items()}
+        return _upload(feed, dev)
 
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, use_jit=True, feed_var_name="feed",
@@ -937,9 +957,7 @@ class Executor(object):
 
         # under a mesh, leave feeds uncommitted: jit's in_shardings place them
         dev = None if dist is not None else self._device()
-        with _prof.span("upload", bytes=sum(map(_host_nbytes,
-                                                feed.values()))):
-            dev_feed = {k: _to_device_value(v, dev) for k, v in feed.items()}
+        dev_feed = _upload(feed, dev)
         block = program.global_block()
 
         timing = _prof.profiler_enabled()

@@ -259,12 +259,14 @@ DEFINE_string("lstm_impl", "scan",
               "whole-sequence LSTM lowering: 'scan' (lax.scan) or "
               "'pallas' (fused VMEM-resident kernel, standard gate set)")
 DEFINE_bool("pipeline", False,
-            "default Trainer.train execution mode: True overlaps host feed "
-            "prep (DataFeeder.feed + device_put) of batch k+1 with the "
-            "device computing batch k and defers fetch materialization to "
-            "real sync points (paddle_tpu.pipeline; per-call override via "
-            "Trainer.train(pipeline=...)). Losses are bit-identical to the "
-            "synchronous mode; check_nan_inf forces synchronous")
+            "default Trainer.train execution mode. False: the training "
+            "thread prepares batch k+1 (DataFeeder.feed + device_put) "
+            "while the device computes step k, then reads step k's loss. "
+            "True adds a feed thread that runs pipeline_depth batches "
+            "ahead and defers fetch materialization to real sync points "
+            "(paddle_tpu.pipeline; per-call override via "
+            "Trainer.train(pipeline=...)). Losses are bit-identical in "
+            "both; check_nan_inf keeps the feed on the training thread")
 DEFINE_int32("pipeline_depth", 2,
              "bounded ring of device-resident prefetched feed buffers the "
              "async pipeline keeps in flight (2 = classic double "

@@ -1,14 +1,21 @@
 """Asynchronous execution pipeline: overlapped feed prefetch, lazy
 fetches, and the persistent compile cache.
 
-The synchronous Trainer loop serialises three resources that could run
-concurrently: the host builds batch k (``DataFeeder.feed`` +
-``device_put``), the device computes step k, and the host reads the
-fetches back. This module decouples them — the same overlap-hiding
-principle the reference's C++ double-buffer data provider applied to
-disk reads (reference: gserver/dataproviders DoubleBufferedDataProvider)
-and HiCCL (arxiv 2408.05962) applies to collectives: keep every resource
-busy by separating producer from consumer.
+A training step uses three resources that can run concurrently: the host
+builds batch k (``DataFeeder.feed`` + ``device_put``), the device
+computes step k, and the host reads the fetches back. Trainer's default
+loop already overlaps the first two by ONE batch, on the training thread
+and with no thread of its own: it dispatches step k, prepares batch k+1
+while the device computes, and only then reads step k's loss
+(``Trainer.train``). This module adds what that cannot give: a feed
+thread that runs up to ``depth`` batches ahead, so a feed that outlasts
+the device step is hidden too as long as the thread keeps up on average,
+and fetches that stay on the device until somebody reads them — the same
+overlap-hiding principle the reference's C++ double-buffer data provider
+applied to disk reads (reference: gserver/dataproviders
+DoubleBufferedDataProvider) and HiCCL (arxiv 2408.05962) applies to
+collectives: keep every resource busy by separating producer from
+consumer.
 
 Three stages:
 

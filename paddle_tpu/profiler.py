@@ -125,7 +125,8 @@ def update_pipeline_counters(**counters):
     few dict adds per pass/materialisation, not per op). Keys in use:
     ``feed_wait_ms``, ``dispatch_depth`` (kept as a max, not a sum),
     ``fetch_sync_count``, ``compile_cache_hits``, ``pipeline_batches``,
-    ``slot_reuse``, ``fallback_sync``."""
+    ``slot_reuse``, ``fallback_sync``; of Trainer's default loop,
+    ``lookahead_steps`` and ``lookahead_loss_ready``."""
     for k, v in counters.items():
         if k == "dispatch_depth":
             _pipeline_counters[k] = max(_pipeline_counters[k], float(v))

@@ -45,8 +45,8 @@ class EndIteration(object):
     """Under the async pipeline, ``cost``/``metrics`` hold lazy
     AsyncFetch handles: a handler that never touches them costs no
     device sync, one that reads them materialises exactly then (the
-    declared per-iteration sync point). Synchronous mode stores plain
-    floats/arrays and behaves as before."""
+    declared per-iteration sync point). The default loop stores a plain
+    float and host arrays: it has read them before the event fires."""
 
     def __init__(self, pass_id, batch_id, cost, metrics=None):
         self.pass_id = pass_id
@@ -77,11 +77,14 @@ class EndIteration(object):
 def _step_spans(batches, pass_id, first_step):
     """``enumerate(batches)`` with every iteration of the caller's loop
     inside its own ``paddle_tpu/train_step`` span: from taking the batch
-    off the reader to the end of the loop's body (after ``EndIteration``'s
-    handler). ``step_num`` counts on from ``first_step`` (batches since
-    ``train()`` began). A span cannot be taken back, so the call that
-    finds the reader exhausted leaves one too, marked ``end_of_pass=1``
-    and with no batch in it."""
+    off ``batches`` to the end of the loop's body (after ``EndIteration``'s
+    handler). Over a :class:`_Lookahead` the batch of step n is in hand
+    already (step 0's is taken here), so ``train_step(n)`` covers the
+    dispatch of step n, the ``feed`` and ``upload`` of batch n+1, the
+    ``fetch`` of step n's loss and the handler. ``step_num`` counts on
+    from ``first_step`` (batches since ``train()`` began). A span cannot
+    be taken back, so the call that finds the reader exhausted leaves one
+    too, marked ``end_of_pass=1`` and with no batch in it."""
     from . import profiler as _prof
     batches = iter(batches)
     batch_id = 0
@@ -95,6 +98,45 @@ def _step_spans(batches, pass_id, first_step):
                 return
             yield batch_id, data
         batch_id += 1
+
+
+class _Lookahead(object):
+    """The default loop's batches: those of ``batches`` as device-resident
+    feed dicts (``feeder.feed`` + ``Executor.prepare_feed``), at most one
+    ahead of the step that runs. ``take()`` prepares the next one now —
+    the loop calls it between dispatching step n and reading its loss, so
+    batch n+1 is stacked and uploaded while the device computes — and
+    holds it, or what taking it raised (the reader's end too), for the
+    ``next()`` that follows ``EndIteration(n)``. ``next()`` with nothing
+    held takes the batch itself. ``feed`` and ``prepare_feed`` are looked
+    up at every batch: a tracer may have replaced them on the
+    instances."""
+
+    def __init__(self, batches, trainer):
+        self._batches = iter(batches)
+        self._trainer = trainer
+        self._held = None
+
+    def take(self):
+        """Prepare the next batch; True when one is now in hand."""
+        t = self._trainer
+        try:
+            raw = next(self._batches)
+            self._held = (t.exe.prepare_feed(t.feeder.feed(raw)), None)
+        except Exception as e:
+            self._held = (None, e)
+        return self._held[1] is None
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        if self._held is None:
+            self.take()
+        (feed, error), self._held = self._held, None
+        if error is not None:
+            raise error
+        return feed
 
 
 class Trainer(object):
@@ -268,14 +310,34 @@ class Trainer(object):
               pipeline=None, pipeline_depth=None, elastic=None,
               task_reader=None, elastic_root=None, on_commit=None,
               on_skip=None, on_resume=None):
-        """``pipeline=True`` runs the async execution pipeline
-        (paddle_tpu.pipeline): a feed thread prepares + device_puts batch
-        k+1 while batch k computes, and fetches stay on device until a
-        real sync point — the handler touching ``.cost``/``.metrics``,
-        the log-period progress line, pass end, or a checkpoint. Losses
-        are bit-identical to the synchronous mode. Defaults follow
-        ``FLAGS.pipeline`` / ``FLAGS.pipeline_depth``; ``check_nan_inf``
-        always forces the synchronous per-op path.
+        """The default loop looks one batch ahead, on this thread.
+        Iteration n: ``BeginIteration(n)``; step n is dispatched on the
+        batch that is already on the device (``Executor.run(sync=False)``
+        returns once it is enqueued); batch n+1 is taken off the reader,
+        stacked (``DataFeeder.feed``) and uploaded
+        (``Executor.prepare_feed``) while the device computes; then the
+        loss and the other fetches of step n are read to the host (a
+        ``float``, NumPy arrays), and guard, commit, log line and
+        ``EndIteration(n)`` follow as ever. The reader is never asked for
+        batch n+2 before ``EndIteration(n)``, and step n+1 is not
+        dispatched before that handler returns: the scope a handler reads
+        holds the state after step n. The lookahead does not cross a
+        pass. What taking batch n+1 raises is raised after
+        ``EndIteration(n)``. On preemption one batch may have been taken
+        off the reader and not trained. ``elastic`` with ``task_reader``
+        takes batch n+1 only after step n's lease is committed (the
+        master hands out no lease past a pending last one). Losses are
+        those of feeding and running strictly in turn, bit for bit.
+
+        ``pipeline=True`` runs the async execution pipeline
+        (paddle_tpu.pipeline) on top: a feed thread prepares +
+        device_puts up to ``pipeline_depth`` batches ahead, so a feed
+        that outlasts the device step is hidden too, and fetches stay on
+        device until a real sync point — the handler touching
+        ``.cost``/``.metrics``, the log-period progress line, pass end,
+        or a checkpoint. Losses are bit-identical to the default loop.
+        Defaults follow ``FLAGS.pipeline`` / ``FLAGS.pipeline_depth``;
+        ``check_nan_inf`` always forces the per-op path on this thread.
 
         ``elastic=True`` runs the loop as an ELASTIC WORKER
         (paddle_tpu.elastic.worker, doc/elasticity.md): the launcher
@@ -345,6 +407,13 @@ class Trainer(object):
         if use_pipe and (depth < 1 or self.exe.check_nan_inf):
             # the NaN/Inf scan needs the synchronous per-op path
             use_pipe = False
+        # the master answers "wait" while any lease is pending, and the
+        # lease of batch n is pending until commit(n): asked for batch n+1
+        # before that, this thread would wait on its own commit until the
+        # lease lapsed. The lease path takes batch n+1 after commit(n)
+        # (FeedPipeline looks ahead there: its thread waits, this one
+        # commits)
+        leased = worker is not None and task_reader is not None
         watchdog = None
         if FLAGS.step_timeout_s > 0:
             watchdog = StepWatchdog(FLAGS.step_timeout_s)
@@ -398,7 +467,7 @@ class Trainer(object):
                                             self.exe, depth=depth)
                         batches = pipe
                     else:
-                        batches = reader()
+                        batches = _Lookahead(reader(), self)
                     last_iter_t = None
                     feed_wait_seen = 0.0
                     commit_ms_last = 0.0
@@ -437,21 +506,26 @@ class Trainer(object):
                         # that propagates (the supervisor's
                         # transient-restart path)
                         fault_point("trainer.step")
-                        if use_pipe:
-                            # data is already a device-resident
-                            # feed dict from the pipeline ring
-                            outs = self.exe.run(
-                                self.main_program, feed=data,
-                                fetch_list=self.fetch_list,
-                                sync=False)
-                            cost = outs[0]  # lazy AsyncFetch
-                        else:
-                            outs = self.exe.run(
-                                self.main_program,
-                                feed=self.feeder.feed(data),
-                                fetch_list=self.fetch_list)
-                            cost = float(
-                                np.asarray(outs[0]).reshape(-1)[0])
+                        # data is a device-resident feed dict (from
+                        # the lookahead or the pipeline ring); the
+                        # call returns once the step is enqueued
+                        outs = self.exe.run(
+                            self.main_program, feed=data,
+                            fetch_list=self.fetch_list, sync=False)
+                        cost = outs[0]  # lazy AsyncFetch
+                        if not use_pipe:
+                            # the device computes this step while the
+                            # host stacks and uploads the next batch
+                            if not leased and batches.take():
+                                ready = int(cost.ready)
+                                es = self.exe.stats
+                                es["lookahead_steps"] += 1
+                                es["lookahead_loss_ready"] += ready
+                                _prof.update_pipeline_counters(
+                                    lookahead_steps=1,
+                                    lookahead_loss_ready=ready)
+                            cost = materialize_scalar(cost)
+                            outs = materialize(outs)
                         skipped = False
                         if guard is not None:
                             # the guardrail sync point: a wedged

@@ -82,16 +82,20 @@ def test_pipeline_flag_default(monkeypatch):
     # FLAGS.pipeline drives the default; explicit arg wins
     with pt.flags_guard(pipeline=True):
         l_pipe, _, tr = _train(None)  # pipeline=None -> FLAGS
-    assert tr.exe.stats["lazy_fetches"] > 0
+    assert tr.exe.stats["dispatch_depth"] >= 1
     l_sync, _, tr2 = _train(False)
-    assert tr2.exe.stats["lazy_fetches"] == 0
+    # the default loop fetches lazily too (it reads the loss after it has
+    # taken the next batch); what tells the feed thread is its ring
+    assert tr2.exe.stats["dispatch_depth"] == 0
+    assert tr2.exe.stats["lookahead_steps"] == 3 * (N_BATCHES - 1)
     assert l_pipe == l_sync
 
 
 def test_check_nan_inf_forces_synchronous():
     with pt.flags_guard(check_nan_inf=True):
         _, _, tr = _train(True, num_passes=1)
-    assert tr.exe.stats["lazy_fetches"] == 0  # stayed synchronous
+    assert tr.exe.stats["dispatch_depth"] == 0  # no feed thread
+    assert tr.exe.stats["eager_runs"] == N_BATCHES + 1  # startup too
 
 
 # -- ring buffer --------------------------------------------------------------

@@ -899,6 +899,9 @@ def test_trainer_elastic_worker_leases_pairs_and_resumes(
         ["batch-%d" % i for i in range(5)]
     assert tr.exe.stats["elastic_tasks_committed"] == 5
     assert tr.exe.stats["elastic_lease_losses"] == 0
+    # a leased batch is taken after the commit of the one before it: asked
+    # for earlier, the master would say "wait" on this worker's own lease
+    assert tr.exe.stats["lookahead_steps"] == 0
     # every retained checkpoint carries its paired master snapshot
     snaps = glob.glob(os.path.join(root, "ckpt-*",
                                    resume_mod.SNAP_IN_DIR))

@@ -48,7 +48,8 @@ BATCH_NBYTES = BATCH * (3 * 8 * 8 * 4 + 8)
 
 
 def _spans(logdir):
-    """[(name, start_ns, end_ns, args)] of the paddle_tpu/ spans, by start."""
+    """[(name, start_ns, end_ns, args, line)] of the paddle_tpu/ spans, by
+    start; ``line`` tells the host thread that opened the span."""
     from jax.profiler import ProfileData
     found = glob.glob(os.path.join(str(logdir), "plugins", "profile", "*",
                                    "*.xplane.pb"))
@@ -62,7 +63,7 @@ def _spans(logdir):
                     out.append((ev.name[len("paddle_tpu/"):],
                                 int(ev.start_ns),
                                 int(ev.start_ns) + int(ev.duration_ns),
-                                dict(ev.stats)))
+                                dict(ev.stats), line.name))
     return sorted(out, key=lambda t: (t[1], -t[2]))
 
 
@@ -95,23 +96,64 @@ def _train_steps(spans):
 
 
 @pytest.mark.parametrize("k", range(STEPS))
-def test_each_step_span_holds_its_feed_and_run_nested_and_in_order(traced, k):
+def test_each_step_span_holds_its_run_the_next_feed_and_its_fetch(traced, k):
+    """train_step(k): run(k) returns once the step is dispatched, batch
+    k+1 is fed and uploaded, then step k's loss is fetched. Step 0 also
+    takes its own batch, the last step finds the reader exhausted."""
     spans = traced["spans"]
     step = _train_steps(spans)[k]
     assert (step[3]["step_num"], step[3]["pass_id"],
             step[3]["batch_id"]) == (k, 0, k)
-    (feed,), (run,) = _inside(spans, step, "feed"), _inside(spans, step,
-                                                           "run")
-    assert feed[3]["rows"] == BATCH
+    (run,), (fetch,) = _inside(spans, step, "run"), _inside(spans, step,
+                                                            "fetch")
     assert run[3]["program"] == traced["main"]._uid
-    assert feed[2] <= run[1]                    # fed, then run
-    (upload,), (dispatch,), (fetch,) = (
-        _inside(spans, run, n) for n in ("upload", "dispatch", "fetch"))
-    assert upload[3]["bytes"] == BATCH_NBYTES
-    assert upload[2] <= dispatch[1] and dispatch[2] <= fetch[1]
-    # nothing of this step lies outside it, nothing of another inside
-    for name in ("feed", "run", "upload", "dispatch", "fetch"):
-        assert len(_inside(spans, step, name)) == 1
+    (dispatch,) = _inside(spans, run, "dispatch")
+    assert not _inside(spans, run, "upload")    # the batch was here
+    assert not _inside(spans, run, "fetch")     # run() does not wait
+    assert dispatch[2] <= run[2] <= fetch[1]
+    feeds, uploads = (_inside(spans, step, n) for n in ("feed", "upload"))
+    own = 1 if k == 0 else 0
+    ahead = 1 if k < STEPS - 1 else 0
+    assert len(feeds) == len(uploads) == own + ahead
+    for feed, upload in zip(feeds, uploads):
+        assert feed[3]["rows"] == BATCH
+        assert upload[3]["bytes"] == BATCH_NBYTES
+        assert feed[2] <= upload[1]
+    if own:
+        assert uploads[0][2] <= run[1]          # fed, then run
+    if ahead:                    # between the dispatch and the loss
+        assert run[2] <= feeds[-1][1] and uploads[-1][2] <= fetch[1]
+    # all of it on the thread that called train()
+    assert {s[4] for n in ("feed", "upload", "run", "dispatch", "fetch")
+            for s in _inside(spans, step, n)} == {step[4]}
+
+
+def test_one_upload_span_a_batch_and_none_of_no_bytes(traced):
+    uploads = [s for s in traced["spans"] if s[0] == "upload"]
+    assert [u[3]["bytes"] for u in uploads] == [BATCH_NBYTES] * STEPS
+    steps = _train_steps(traced["spans"])
+    assert all(any(st[1] <= u[1] and u[2] <= st[2] for st in steps)
+               for u in uploads)
+
+
+def test_prepare_feed_holds_the_upload_span_and_run_opens_none(tmp_path):
+    x = layers.data("x", shape=[4])
+    out = layers.mean(layers.fc(x, size=2))
+    exe = pt.Executor(pt.CPUPlace())
+    exe.run(pt.default_startup_program())
+    host = {"x": np.ones((2, 4), np.float32)}
+    exe.run(feed=host, fetch_list=[out])
+    with profiler.xla_trace(tmp_path):
+        here = exe.prepare_feed(host)
+        exe.run(feed=here, fetch_list=[out])
+        exe.run(feed=here, fetch_list=[out])
+        exe.run(feed=host, fetch_list=[out])
+    spans = _spans(tmp_path)
+    runs = [s for s in spans if s[0] == "run"]
+    uploads = [s for s in spans if s[0] == "upload"]
+    assert [u[3]["bytes"] for u in uploads] == [32, 32]
+    assert uploads[0][2] <= runs[0][1]          # prepare_feed's, outside
+    assert [len(_inside(spans, r, "upload")) for r in runs] == [0, 0, 1]
 
 
 @pytest.mark.parametrize("name,count", [
