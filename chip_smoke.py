@@ -9,7 +9,9 @@ network), and checks what comes out by the repo's own references:
 
 - ``train``  : ResNet-50 (3x224x224, 1000 classes), batch 128, Momentum,
   pure AMP, through ``pt.Trainer(place=TPUPlace(0)).train(reader)`` — the
-  path ``paddle_tpu train`` takes.
+  path ``paddle_tpu train`` takes; then the rule by which its
+  ``DataFeeder`` writes a fed array again, tried with no device step to
+  wait behind (``_staging_reuse``).
 - ``serve``  : ``TransformerLM`` hidden 768 / 12 layers / 12 heads / vocab
   50257 / max_seq 1024, ``export_generative`` -> ``python -m paddle_tpu
   serve --port 0`` as a child process, concurrent ``:generate`` requests
@@ -232,6 +234,78 @@ def _train_steps(pt, trainer, scope, reader):
             len(hits))
 
 
+def _staging_reuse(trainer, cfg, rounds, rehearse):
+    """``DataFeeder`` writes a staging array again once its own reference
+    is the last one; that rests on jax holding one until the
+    host-to-device copy is done. Tried here at the earliest moment the
+    rule allows, with no device step to wait behind: batch n is fed and
+    its upload enqueued (``prepare_feed``, not waited for); after an odd
+    batch the next is fed at once, after an even one as soon as jax has
+    let go of the array (its reference count is polled, a tiny
+    ``device_put`` between polls as the label's is in the loop). Every
+    row of a batch differs from that row of the three before it, so a
+    copy that was still reading would land later values in batch n's
+    device array, which is read back and compared."""
+    import jax
+    import numpy as np
+    feeder, exe = trainer.feeder, trainer.exe
+    device = exe._device()
+    row_ids = np.arange(cfg["batch"], dtype="float32")[:, None, None, None]
+    pools = [np.empty((cfg["batch"], 3, cfg["image"], cfg["image"]),
+                      "float32") for _ in range(4)]
+    for k, pool in enumerate(pools):
+        pool[:] = 1000.0 * k + row_ids
+    labels = np.zeros((cfg["batch"], 1), "int64")
+    batches = [[(pool[i], labels[i]) for i in range(len(pool))]
+               for pool in pools]
+    tiny = np.zeros(1, "float32")
+    rec = {"rounds": rounds, "taken_again": 0, "taken_again_when_let_go": 0,
+           "let_go_ms": [], "copy_done_when_let_go": 0, "never_let_go": 0}
+    seen, pending = set(), None
+
+    def settle(number, dev):
+        got = np.asarray(jax.device_get(dev["img"]))
+        torn = int((got != pools[number % 4]).any(axis=(1, 2, 3)).sum())
+        _check(torn == 0, "a staging array was written while its upload "
+               "still read it: %d of %d rows of batch %d differ on the "
+               "device" % (torn, len(got), number))
+
+    for number in range(rounds):
+        fed = feeder.feed(batches[number % 4])
+        if pending is not None:     # written over batch number-1's array?
+            settle(*pending)
+        img = fed.pop("img")        # the feeder's reference and this one
+        again = img.ctypes.data in seen
+        seen.add(img.ctypes.data)
+        rec["taken_again"] += again
+        rec["taken_again_when_let_go"] += again and number % 2 == 1
+        base = sys.getrefcount(img)
+        t0 = time.perf_counter()
+        dev = exe.prepare_feed(dict(img=img, **fed))
+        del fed
+        if number % 2 == 0:
+            ready_at = None
+            while sys.getrefcount(img) > base:
+                now = time.perf_counter()
+                if ready_at is None and dev["img"].is_ready():
+                    ready_at = now
+                if ready_at is not None and now - ready_at > 0.25:
+                    rec["never_let_go"] += 1    # XLA:CPU aliases the array
+                    break
+                jax.device_put(tiny, device)
+            else:
+                rec["let_go_ms"].append(
+                    round(1e3 * (time.perf_counter() - t0), 2))
+                rec["copy_done_when_let_go"] += bool(dev["img"].is_ready())
+        del img
+        pending = (number, dev)
+    settle(*pending)
+    _check(rehearse or rec["taken_again_when_let_go"] > 0,
+           "no staging array was taken again when jax let go of it, so "
+           "the comparison showed nothing: %r" % rec)
+    return rec
+
+
 # ---------------------------------------------------------------------------
 # phase: train
 # ---------------------------------------------------------------------------
@@ -275,6 +349,8 @@ def phase_train(cfg, seed, rehearse):
     _check(late_compiles == 0, "%d XLA compile(s) after the first step"
            % late_compiles)
     mem = jax.devices()[0].memory_stats() or {}
+    with pt.scope_guard(scope):
+        staging = _staging_reuse(trainer, cfg, 12, rehearse)
     rec = {"phase": "train", "passed": True, "device": dev,
            "model": "resnet%d %dx%d classes=%d" % (
                cfg["depth"], cfg["image"], cfg["image"], cfg["classes"]),
@@ -291,6 +367,7 @@ def phase_train(cfg, seed, rehearse):
                          "hybrid_runs", "compile_cache_hits")},
            "xla_compiles_after_first_step": late_compiles,
            "peak_hbm_bytes": mem.get("peak_bytes_in_use"),
+           "staging_reuse": staging,
            "compile_cache": _cache_report(
                cache_before, programs_read_from_cache=cache_hits)}
     rec.update(_audit("train"))

@@ -3,8 +3,15 @@
 reference: python/paddle/fluid/data_feeder.py:118 (DataFeeder /
 DataToLoDTensorConverter) — rows of python/numpy values become dense arrays,
 lod_level>0 fields become LoDTensors with offsets built from nested lists.
+
+A dense field is stacked once, row by row, into a staging array the feeder
+keeps and takes again when nothing else refers to it any more (see
+``DataFeeder``); ragged and LoD fields go through the converter.
 """
 from __future__ import annotations
+
+import sys
+import threading
 
 import numpy as np
 
@@ -14,7 +21,22 @@ from .core.lod import LoDTensor, lengths_to_offsets
 from .core.types import convert_dtype
 
 
+def _refs(held, i):
+    return sys.getrefcount(held[i])
+
+
+# what _refs reads for an array that only ``held`` refers to
+_SOLE_REF = _refs([np.empty(0)], 0)
+
+
 class DataToLoDTensorConverter(object):
+    """Collects one field's samples in a Python list and builds the batch
+    with ``np.array(list, dtype)`` in ``done()``: a fresh array each time.
+    ``DataFeeder`` uses it for LoD fields (``lod_level > 0``, nested lists
+    flattened and their lengths kept as offsets) and for the dense batches
+    it cannot stack in place (an empty batch, rows of unequal shape: the
+    errors are ``np.array``'s)."""
+
     def __init__(self, lod_level, shape, dtype):
         self.lod_level = lod_level
         self.shape = tuple(s for s in shape if s != -1) if shape else ()
@@ -54,8 +76,48 @@ class DataToLoDTensorConverter(object):
         return t
 
 
+def _row(value, dtype):
+    """A sample's value as an array: an ndarray as it is (its dtype is
+    converted by the copy into the batch), anything else as
+    ``np.array(list, dtype)`` would read it."""
+    return value if type(value) is np.ndarray else np.asarray(value, dtype)
+
+
+def _copy_rows(rows, samples, field):
+    """Each sample's value of ``field`` into its row of ``rows``; False at
+    the first that is no row of ``rows``' shape (nothing broadcasts) or
+    that numpy cannot convert."""
+    shape, dtype = rows.shape[1:], rows.dtype
+    try:
+        for i, each_sample in enumerate(samples):
+            value = _row(each_sample[field], dtype)
+            if value.shape != shape:
+                return False
+            rows[i] = value
+    except (ValueError, TypeError, OverflowError):
+        return False        # np.array says which, in its own words
+    return True
+
+
 class DataFeeder(object):
-    """reference: python/paddle/fluid/data_feeder.py DataFeeder."""
+    """reference: python/paddle/fluid/data_feeder.py DataFeeder.
+
+    **Who owns a fed array.** A dense field (``lod_level == 0``) comes
+    back as a *staging array* that the feeder keeps a reference to. The
+    array is the caller's for as long as the caller — or anything that
+    got it from the caller — refers to it: the feed dict, a view or
+    slice, a ``memoryview``, an ``Executor.prepare_feed`` /
+    ``jax.device_put`` still copying from it, a device array that aliases
+    it (XLA:CPU may keep the numpy buffer as the device buffer). Once the
+    feeder's own reference is the only one left, a later ``feed`` of the
+    same batch shape writes the next batch into it, so a training loop
+    maps its batch memory once instead of once a step. The test is the
+    array's reference count; nothing has to be handed back and no call
+    marks a batch as done. Keep the array (or a view) and it is never
+    written again; keep only a raw address (``arr.ctypes.data``) and it
+    may be. LoD fields, an empty batch and rows of unequal shape are
+    built fresh by ``DataToLoDTensorConverter``.
+    """
 
     def __init__(self, feed_list, place=None, program=None):
         self.feed_dtypes = []
@@ -74,31 +136,92 @@ class DataFeeder(object):
             self.feed_shapes.append(each_var.shape)
             self.feed_dtypes.append(convert_dtype(each_var.dtype))
         self.place = place
-        # per-field converter specs, resolved once: feed() builds fresh
-        # converters from these each call, so it carries no mutable state
-        # between calls — safe to run on the async pipeline's feed thread
-        # concurrently with Executor.run on the main thread
-        self._converter_specs = list(zip(self.feed_lod_level,
-                                         self.feed_shapes,
-                                         self.feed_dtypes))
+        # per-field (lod_level, shape less its batch wildcards, dtype),
+        # resolved once
+        self._converter_specs = [
+            (lod, tuple(d for d in shape or () if d != -1), dtype)
+            for lod, shape, dtype in zip(self.feed_lod_level,
+                                         self.feed_shapes, self.feed_dtypes)]
+        # the staging arrays handed out, per field, under _lock: the only
+        # state that feed() calls share
+        self._staged = [[] for _ in self.feed_names]
+        self._lock = threading.Lock()
 
     def feed(self, iterable):
         """Minibatch (iterable of per-sample field tuples) -> feed dict.
-        Stateless per call (thread-safe; see _converter_specs)."""
+
+        Dense fields are stacked into staging arrays (class docstring)
+        on the calling thread, inside the ``feed`` span (args ``rows``,
+        ``bytes``).
+
+        Safe to call from several threads at once (the ``FeedPipeline``
+        feed thread beside the training thread): a staging array is taken
+        under the feeder's lock and, from then on, referred to by the
+        call that took it, so no other call can take it; everything else
+        is local to the call."""
         with _prof.span("feed") as span:
-            converters = [
-                DataToLoDTensorConverter(lod_level=lod, shape=shape or (),
-                                         dtype=dtype)
-                for lod, shape, dtype in self._converter_specs]
-            rows = 0
-            for each_sample in iterable:
-                if len(each_sample) != len(converters):
+            samples = (iterable if isinstance(iterable, (list, tuple))
+                       else list(iterable))
+            fields = len(self._converter_specs)
+            for each_sample in samples:
+                if len(each_sample) != fields:
                     raise ValueError(
                         "sample has %d fields, feed_list expects %d"
-                        % (len(each_sample), len(converters)))
-                for value, conv in zip(each_sample, converters):
-                    conv.feed(value)
-                rows += 1
-            span.set_metadata(rows=rows)
-            return {name: conv.done()
-                    for name, conv in zip(self.feed_names, converters)}
+                        % (len(each_sample), fields))
+            out = {name: self._stack(samples, field)
+                   for field, name in enumerate(self.feed_names)}
+            span.set_metadata(rows=len(samples),
+                              bytes=sum(np.asarray(batch).nbytes
+                                        for batch in out.values()))
+            return out
+
+    def _stack(self, samples, field):
+        """The batch of one field: stacked in place where it is dense and
+        its rows have one shape, else as the converter builds it."""
+        lod_level, shape, dtype = self._converter_specs[field]
+        batch = None
+        if lod_level == 0 and samples:
+            batch = self._stack_dense(samples, field, shape, dtype)
+        if batch is None:
+            conv = DataToLoDTensorConverter(lod_level, shape, dtype)
+            for each_sample in samples:
+                conv.feed(each_sample[field])
+            batch = conv.done()
+        return batch
+
+    def _stack_dense(self, samples, field, shape, dtype):
+        """samples' rows of ``field`` in a staging array, in the bytes,
+        shape and dtype of ``np.array(rows, dtype)`` (+ the converter's
+        reshape of a batch of scalars); None where they do not stack
+        (the array taken stays with the feeder for a later batch)."""
+        n = len(samples)
+        try:
+            row_shape = _row(samples[0][field], dtype).shape
+        except (ValueError, TypeError, OverflowError):
+            return None
+        batch_shape = (n,) + row_shape
+        if not row_shape and shape:
+            per = int(np.prod(shape))
+            if per and n % per == 0:
+                batch_shape = (n // per,) + shape
+        batch = self._staging(field, batch_shape, dtype)
+        rows = batch.reshape((n,) + row_shape)          # a view
+        return batch if _copy_rows(rows, samples, field) else None
+
+    def _staging(self, field, shape, dtype):
+        """An array of ``shape`` that nothing but this call can read or
+        write: one handed out earlier that only the feeder still refers
+        to, else a new one. The other arrays found unreferenced are let
+        go: the feeder holds what is in flight and no more."""
+        with self._lock:
+            held = self._staged[field]
+            taken = None
+            for i in reversed(range(len(held))):
+                if _refs(held, i) == _SOLE_REF:
+                    spare = held.pop(i)
+                    if taken is None and spare.shape == shape:
+                        taken = spare
+            if taken is None:
+                taken = np.empty(shape, dtype)
+            held.append(taken)
+            return taken

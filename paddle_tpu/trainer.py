@@ -328,6 +328,11 @@ class Trainer(object):
         takes batch n+1 only after step n's lease is committed (the
         master hands out no lease past a pending last one). Losses are
         those of feeding and running strictly in turn, bit for bit.
+        The host arrays of a batch are the feeder's staging arrays
+        (``DataFeeder``): the loop drops them once they are uploaded, and
+        the feeder writes a later batch into them when the upload has let
+        go of them too — a reader sees none of this, its samples are only
+        read.
 
         ``pipeline=True`` runs the async execution pipeline
         (paddle_tpu.pipeline) on top: a feed thread prepares +

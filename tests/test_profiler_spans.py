@@ -117,7 +117,7 @@ def test_each_step_span_holds_its_run_the_next_feed_and_its_fetch(traced, k):
     assert len(feeds) == len(uploads) == own + ahead
     for feed, upload in zip(feeds, uploads):
         assert feed[3]["rows"] == BATCH
-        assert upload[3]["bytes"] == BATCH_NBYTES
+        assert feed[3]["bytes"] == upload[3]["bytes"] == BATCH_NBYTES
         assert feed[2] <= upload[1]
     if own:
         assert uploads[0][2] <= run[1]          # fed, then run
