@@ -28,7 +28,7 @@ from ..core import registry
 from ..core.ir import grad_var_name
 from ..core.executor import raw_data, with_lod_of
 from ..core.registry import register_op
-from .common import bcast_y_to_x, flatten_to_2d
+from .common import bcast_y_to_x, flatten_to_2d, prod
 
 
 def _is_diffable(block, name, no_grad):
@@ -426,6 +426,11 @@ def batch_norm_grad(ctx):
         mean, inv = saved_mean, 1.0 / jnp.sqrt(saved_var + eps)
     else:
         mean, inv = saved_mean, saved_var  # SavedVariance holds inv-std
+    # both sums accumulate in >=f32 and in ONE dtype: siblings over the
+    # same operands that XLA serves from one read of dy and x (a bf16 dbias
+    # beside an f32 dscale takes a pass of its own, and enters dx rounded)
+    if dy.dtype in (jnp.bfloat16, jnp.float16):
+        dy = dy.astype(jnp.float32)
     xhat = (x - mean.reshape(cshape)) * inv.reshape(cshape)
     dscale = jnp.sum(dy * xhat, axis=axes)
     dbias = jnp.sum(dy, axis=axes)
@@ -437,9 +442,7 @@ def batch_norm_grad(ctx):
         if is_test:
             dx = dy * (scale * inv).reshape(cshape)
         else:
-            n = 1
-            for a in axes:
-                n *= x.shape[a]
+            n = prod(x.shape[a] for a in axes)
             dx = (scale * inv).reshape(cshape) / n * (
                 n * dy - dbias.reshape(cshape) - xhat * dscale.reshape(cshape))
         ctx.set_output("X@GRAD", dx.astype(x.dtype))

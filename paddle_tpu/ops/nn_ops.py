@@ -723,8 +723,23 @@ def batch_norm(ctx):
         saved_mean, saved_var = mean, var
         new_mean, new_var = mean, var
     else:
-        bm = jnp.mean(xs, axis=axes)
-        bv = jnp.var(xs, axis=axes)
+        # both sums in ONE read of x: neither takes a value from the other,
+        # so XLA can carry the pair where x is made (a conv's epilogue).
+        # The shift by the running mean, known before the pass, is exact
+        # algebra and keeps E[d^2] - E[d]^2 from cancelling in f32 when
+        # |mean| >> sigma; a shift computed from x would chain the reads.
+        # The trade: the variance's relative error grows as ~1e-5 x
+        # ((batch mean - running mean) / sigma)^2, the two-pass form's
+        # within a sigma, 1e-3 at 10 sigma, noise past ~100 sigma until
+        # the running mean has come close (a fresh layer over uncentred
+        # features: |mean| x momentum^k). The mean holds everywhere
+        # (tests/test_batch_norm_stats.py pins both)
+        n = prod(x.shape[a] for a in axes)
+        shift = mean.astype(xs.dtype)
+        d = xs - shift.reshape(cshape)
+        m1 = jnp.sum(d, axis=axes) / n
+        bm = shift + m1
+        bv = jnp.maximum(jnp.sum(d * d, axis=axes) / n - m1 * m1, 0.0)
         use_mean, use_var = bm, bv
         saved_mean = bm
         saved_var = 1.0 / jnp.sqrt(bv + eps)
