@@ -32,7 +32,7 @@ Two worker shapes share the harness (``PADDLE_TPU_CHAOS_MODE``):
   ranks 1..W-1 are heartbeating liveness bodies.
 - ``trainer`` (the real thing): EVERY rank runs
   ``Trainer.train(elastic=True)`` — the actual training loop with the
-  async pipeline and the ``comm_overlap`` step builds. Rank 0 owns the
+  ``comm_overlap`` step builds. Rank 0 owns the
   audited lease stream (``task_reader`` batches leased from the
   supervisor's master, checkpoints PAIRED with master snapshots);
   ranks 1..W-1 run the same code path lease-free on a local data
@@ -290,13 +290,13 @@ def _build_chaos_trainer():
 
 def trainer_worker_main(world_size, rank):
     """One rank of the real-Trainer elastic job: ``Trainer.train(
-    elastic=True)`` with the async pipeline on (``comm_overlap`` etc.
-    arrive via PADDLE_TPU_FLAGS). Rank 0 owns the audited lease
+    elastic=True)`` (``comm_overlap`` etc. arrive via
+    PADDLE_TPU_FLAGS). Rank 0 owns the audited lease
     stream + paired checkpoints; other ranks run the same loop
     lease-free on local batches scoped to the master's pass."""
     import numpy as np
 
-    from paddle_tpu.pipeline import materialize_scalar
+    from paddle_tpu.core.executor import materialize_scalar
 
     state_dir = os.environ["PADDLE_TPU_ELASTIC_STATE"]
     gen = int(os.environ.get("PADDLE_TPU_ELASTIC_GENERATION", "0"))
@@ -356,8 +356,7 @@ def trainer_worker_main(world_size, rank):
                 time.sleep(0.05)
 
         try:
-            trainer.train(body_reader, num_passes=1, elastic=True,
-                          pipeline=True)
+            trainer.train(body_reader, num_passes=1, elastic=True)
         finally:
             poll.close()
         return 0
@@ -402,7 +401,7 @@ def trainer_worker_main(world_size, rank):
     trainer.train(elastic=True, task_reader=task_reader,
                   elastic_root=root, on_resume=on_resume,
                   on_commit=on_commit, on_skip=on_skip,
-                  num_passes=1, pipeline=True)
+                  num_passes=1)
     return 0
 
 

@@ -122,7 +122,7 @@ def _device(rehearse, want_count):
 def _cache_entries():
     """(directory, entry count) of the persistent compile cache, by the
     program's own placement rule."""
-    from paddle_tpu.pipeline import compile_cache_dir
+    from paddle_tpu.core.compile_cache import compile_cache_dir
     dirname = compile_cache_dir()
     try:
         return dirname, len(os.listdir(dirname))

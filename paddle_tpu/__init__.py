@@ -36,8 +36,7 @@ from .parallel import DistributeTranspiler  # noqa: F401
 from . import comm  # noqa: F401
 from . import concurrency  # noqa: F401
 from .concurrency import Go, Channel  # noqa: F401
-from . import pipeline  # noqa: F401
-from .pipeline import AsyncFetch, FeedPipeline  # noqa: F401
+from .core.executor import AsyncFetch  # noqa: F401
 from . import trainer as trainer_mod  # noqa: F401
 from .trainer import (Trainer, BeginPass, EndPass, BeginIteration,  # noqa: F401
                       EndIteration)

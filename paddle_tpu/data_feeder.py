@@ -154,8 +154,7 @@ class DataFeeder(object):
         on the calling thread, inside the ``feed`` span (args ``rows``,
         ``bytes``).
 
-        Safe to call from several threads at once (the ``FeedPipeline``
-        feed thread beside the training thread): a staging array is taken
+        Safe to call from several threads at once: a staging array is taken
         under the feeder's lock and, from then on, referred to by the
         call that took it, so no other call can take it; everything else
         is local to the call."""

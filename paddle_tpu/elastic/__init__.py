@@ -20,7 +20,7 @@ pserver re-registration). Five parts:
   processed or lost across a resize.
 - :mod:`.worker` — ``ElasticWorker``: the WORKER half of the protocol
   as a first-class role, so ``Trainer.train(elastic=True)`` — the real
-  training loop, pipeline and comm_overlap included — leases batches
+  training loop, comm_overlap included — leases batches
   through the supervisor-owned task master, pairs its checkpoints with
   master snapshots, and resumes cross-world like the chaos harness
   always did by hand.

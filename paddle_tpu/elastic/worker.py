@@ -6,8 +6,8 @@ since PR 8 — classify death, re-queue leases, re-plan, relaunch — but
 its only in-tree client was the raw-Executor loop in
 ``benchmark/chaos_run.py``. This module is the worker half as a
 first-class role, so the REAL training loop (``Trainer.train`` with
-the PR-3 pipeline, the PR-7 ``comm_overlap`` step builds and the PR-13
-fingerprint exchange) runs as an elastic worker with no bespoke glue:
+the PR-7 ``comm_overlap`` step builds and the PR-13 fingerprint
+exchange) runs as an elastic worker with no bespoke glue:
 
 - **world** — resolve + validate the launcher env
   (``parallel.env.world()``), ``replan(world).apply_flags()`` the
@@ -187,8 +187,8 @@ class ElasticWorker(object):
     def reader(self):
         """Reader factory for the Trainer loop: leases tasks, maps them
         through ``task_reader``, tracks the lease ledger in batch order
-        (the async pipeline preserves reader order, so commits pop the
-        ledger head). A poisoned task (task_reader raise) is failed
+        (the loop trains in reader order, so commits pop the ledger
+        head). A poisoned task (task_reader raise) is failed
         back to the master — the PR-1 reader.next contract — and the
         stream continues with the next lease."""
         from .. import profiler as _prof
@@ -335,17 +335,17 @@ class ElasticWorker(object):
                     rank=self.rank, generation=self.generation)
         return True
 
-    def publish_heartbeat(self, step_ms, feed_wait_ms=None):
+    def publish_heartbeat(self, step_ms):
         """Publish this rank's per-step wall time into the elastic
         state dir (``heartbeat-rank<r>.json``, atomic replace) — the
         metric the supervisor's gray-failure sweep judges against the
         peer ranks. ``step_ms`` is the iteration wall delta (dispatch
-        + reader wait + any injected delay — an async pipeline makes a
-        device-timer-only number blind to exactly the stalls gray
+        + reader wait + any injected delay — an asynchronous dispatch
+        makes a device-timer-only number blind to exactly the stalls gray
         detection exists for) with the commit/checkpoint span excluded
         by the caller (legitimate per-role overhead: only the lease
         owner pays it, and it must not make that rank a false
-        outlier); ``feed_wait_ms`` rides along for the audit trail.
+        outlier).
         No state dir -> no-op (a non-elastic run has no supervisor to
         read it)."""
         if not self.state_dir:
@@ -363,8 +363,6 @@ class ElasticWorker(object):
             "step_ms": round(step_ms, 3),
             "step_ms_ewma": round(self._hb_ewma, 3),
             "step_ms_window": [round(v, 3) for v in self._hb_window],
-            "feed_wait_ms": (round(float(feed_wait_ms), 3)
-                             if feed_wait_ms is not None else None),
             "time": time.time(),
         }
         path = os.path.join(self.state_dir,

@@ -258,19 +258,6 @@ DEFINE_int32("log_period", 100,
 DEFINE_string("lstm_impl", "scan",
               "whole-sequence LSTM lowering: 'scan' (lax.scan) or "
               "'pallas' (fused VMEM-resident kernel, standard gate set)")
-DEFINE_bool("pipeline", False,
-            "default Trainer.train execution mode. False: the training "
-            "thread prepares batch k+1 (DataFeeder.feed + device_put) "
-            "while the device computes step k, then reads step k's loss. "
-            "True adds a feed thread that runs pipeline_depth batches "
-            "ahead and defers fetch materialization to real sync points "
-            "(paddle_tpu.pipeline; per-call override via "
-            "Trainer.train(pipeline=...)). Losses are bit-identical in "
-            "both; check_nan_inf keeps the feed on the training thread")
-DEFINE_int32("pipeline_depth", 2,
-             "bounded ring of device-resident prefetched feed buffers the "
-             "async pipeline keeps in flight (2 = classic double "
-             "buffering; <1 disables pipelining)")
 DEFINE_bool("compile_cache", True,
             "persist XLA compilations via jax's on-disk compilation "
             "cache so repeat runs skip the cold compile; set to 0 to "
@@ -448,9 +435,8 @@ DEFINE_int32("loss_skip_budget", 0,
              "checkpoint in elastic mode) once per budget window and "
              "keeps training; a second consecutive exhaustion with no "
              "accepted batch in between gives up with "
-             "FloatingPointError. Each skip forces a per-batch loss "
-             "materialization — under pipeline=True the guardrail "
-             "check is a declared sync point")
+             "FloatingPointError. The check reads the loss the loop has "
+             "already brought to the host")
 DEFINE_int32("elastic_ckpt_period", 1,
              "elastic Trainer worker (Trainer.train(elastic=True)): "
              "lease-committed batches between paired checkpoint+"

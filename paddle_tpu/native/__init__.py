@@ -343,12 +343,12 @@ class MasterClient(object):
         self._call_lock = threading.Lock()
 
     def _call(self, op, payload=b""):
-        # one request/response pair at a time: under pipeline=True the
-        # feed thread leases (GET) while the main thread commits (FIN)
-        # on the SAME connection — unserialized, the two readers cross
-        # responses, so a commit can consume a lease reply (a spurious
-        # "lease lost" for a task the master counted done — a row
-        # silently missing from the exactly-once audit trail)
+        # one request/response pair at a time: a thread that leases
+        # (GET) while another commits (FIN) on the SAME connection
+        # would — unserialized — cross responses, so a commit could
+        # consume a lease reply (a spurious "lease lost" for a task the
+        # master counted done — a row silently missing from the
+        # exactly-once audit trail)
         import struct
         with self._call_lock:
             self._sock.sendall(struct.pack("<BI", op, len(payload))

@@ -56,7 +56,7 @@ _records = defaultdict(list)  # label -> [seconds]
 _op_events = []               # chrome-trace X events (eager per-op spans)
 _program_analyses = {}        # label -> {flops, bytes, collectives, ...}
 _profiled_steps = {}          # label -> compiled step run while profiling
-_pipeline_counters = defaultdict(float)  # async-pipeline observability
+_pipeline_counters = defaultdict(float)  # fetch syncs, cache hits, lookahead
 _serving_counters = defaultdict(float)   # online-serving observability
 _comm_counters = defaultdict(float)      # gradient-communication observability
 _tune_counters = defaultdict(float)      # kernel-autotuning observability
@@ -121,21 +121,16 @@ def reset_profiler():
 
 
 def update_pipeline_counters(**counters):
-    """Accumulate async-pipeline observability counters (always on — a
-    few dict adds per pass/materialisation, not per op). Keys in use:
-    ``feed_wait_ms``, ``dispatch_depth`` (kept as a max, not a sum),
-    ``fetch_sync_count``, ``compile_cache_hits``, ``pipeline_batches``,
-    ``slot_reuse``, ``fallback_sync``; of Trainer's default loop,
-    ``lookahead_steps`` and ``lookahead_loss_ready``."""
+    """Accumulate the step-feeding observability counters (always on — a
+    few dict adds per step/materialisation, not per op). Keys in use:
+    ``fetch_sync_count``, ``compile_cache_hits``; of Trainer's default
+    loop, ``lookahead_steps`` and ``lookahead_loss_ready``."""
     for k, v in counters.items():
-        if k == "dispatch_depth":
-            _pipeline_counters[k] = max(_pipeline_counters[k], float(v))
-        else:
-            _pipeline_counters[k] += float(v)
+        _pipeline_counters[k] += float(v)
 
 
 def pipeline_counters():
-    """Snapshot {counter: value} of the async-pipeline counters."""
+    """Snapshot {counter: value} of :func:`update_pipeline_counters`."""
     return dict(_pipeline_counters)
 
 
@@ -620,9 +615,8 @@ def write_timeline(path):
     - ``host_events``: aggregated wall-time table (profiler.h role).
     - ``programs``: per-compiled-program XLA cost analysis, collective
       census ('barrier stat' for mesh runs) and memory analysis.
-    - ``pipeline``: async-execution-pipeline counters (feed-wait ms,
-      dispatch depth, fetch syncs, compile-cache hits) — the overlap
-      evidence for paddle_tpu.pipeline.
+    - ``pipeline``: fetch syncs, compile-cache hits and the default
+      loop's lookahead counters (doc/feeding.md).
     - ``serving``: online-serving counters (requests, batches, padded
       rows, queue-wait ms, shed counts, max batch occupancy) — the
       coalescing evidence for paddle_tpu.serving.

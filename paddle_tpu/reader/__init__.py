@@ -143,9 +143,8 @@ def buffered(reader, size):
     in gserver/dataproviders/DataProvider.h DoubleBufferedDataProvider).
 
     A producer-thread exception is re-raised in the consumer instead of
-    silently truncating the stream — the host-side feed stage of
-    paddle_tpu.pipeline relies on this to tell "reader done" from
-    "reader died"."""
+    silently truncating the stream: the consumer can tell "reader done"
+    from "reader died"."""
 
     class _End(object):
         pass

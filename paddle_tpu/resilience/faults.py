@@ -23,10 +23,6 @@ site                      where
 ``async_sgd.pull_params`` pserver->trainer parameter pull, per RPC attempt
 ``reader.next``           each record out of the native recordio reader
 ``dataset.download``      each dataset cache-lookup attempt
-``pipeline.feed_next``    the async pipeline's feed thread, per batch,
-                          before feed conversion + device_put (a raise
-                          kills the thread -> recorded fallback to
-                          synchronous feeding)
 ``serving.dispatch``      the micro-batcher's device dispatch, per batch,
                           before run/run_many (a raise fails that batch's
                           requests with a recorded batch_failed event —
@@ -236,7 +232,6 @@ SITE_TABLE = {
     "async_sgd.pull_params": ("parallel/async_sgd.py", True, False),
     "reader.next": ("native/__init__.py", True, False),
     "dataset.download": ("dataset/common.py", True, False),
-    "pipeline.feed_next": ("pipeline.py", True, False),
     "serving.dispatch": ("serving/batcher.py", True, True),
     "serving.reload": ("serving/registry.py", True, False),
     "serving.generate": ("serving/generator.py", True, True),
@@ -349,9 +344,9 @@ def fault_point(site, payload=None):
     """Declare a failure-relevant edge. Returns ``payload`` (possibly
     corrupted); raises/delays when the site is armed and the hit count is
     inside the firing window. Disarmed cost: one LOCK-FREE dict lookup —
-    this sits on pipelined hot loops (reader.next, pipeline.feed_next),
-    where taking the registry lock per call would serialise the feed
-    thread against arm/disarm and every other instrumented site."""
+    this sits on hot loops (reader.next, trainer.step), where taking the
+    registry lock per call would serialise a prefetch thread against
+    arm/disarm and every other instrumented site."""
     _load_env_once()
     if site not in _faults:
         # read-mostly fast path: membership reads on a dict are atomic

@@ -10,8 +10,7 @@ record, an fp blow-up, a loss spike) as an event to survive, not a
 verdict.
 
 :class:`NumericGuard` watches the per-batch LOSS (cheap: it is already
-fetched; under the async pipeline the check is a declared per-batch
-materialization sync point) and classifies each batch:
+fetched: the default loop has read it to the host before the check) and classifies each batch:
 
 - **accept** — finite and, when ``FLAGS.loss_spike_factor`` > 0, below
   ``factor x`` the running median of recently accepted losses;

@@ -12,8 +12,8 @@ an operator notices.
 :class:`StepWatchdog` closes that gap from the INSIDE. The training
 loop arms a deadline per step (``FLAGS.step_timeout_s``; default off)
 and pings it at every progress point — each batch, and each declared
-materialization sync point, since under the async pipeline that is
-where a wedged device actually surfaces. A monitor thread (daemon, one
+materialization sync point, since a step is dispatched asynchronously
+and that is where a wedged device actually surfaces. A monitor thread (daemon, one
 comparison per poll) fires when the deadline lapses:
 
 1. records a durable ``step_hung`` event (``record_durable_event`` —

@@ -3,7 +3,7 @@
 Ties the registry, micro-batcher, and admission controller into one
 object with a blocking ``infer()`` / non-blocking ``infer_async()`` API
 and a metrics surface (``.stats``) on the same pattern as
-``Executor.stats`` and the async pipeline's profiler counters: request
+``Executor.stats`` and the training loop's profiler counters: request
 and shed counts, batch occupancy, queue wait, and p50/p99 end-to-end
 latency, mirrored into ``profiler.serving_counters()`` and the
 ``serving`` section of the timeline artifact.

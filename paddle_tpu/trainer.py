@@ -16,10 +16,9 @@ import numpy as np
 
 from . import io as _io
 from .core import ir
-from .core.executor import Executor
+from .core.executor import Executor, materialize, materialize_scalar
 from .core.scope import global_scope
 from .data_feeder import DataFeeder
-from .pipeline import FeedPipeline, materialize, materialize_scalar
 from .resilience import (NumericGuard, StepWatchdog, fault_point,
                          record_durable_event)
 
@@ -42,36 +41,14 @@ class BeginIteration(object):
 
 
 class EndIteration(object):
-    """Under the async pipeline, ``cost``/``metrics`` hold lazy
-    AsyncFetch handles: a handler that never touches them costs no
-    device sync, one that reads them materialises exactly then (the
-    declared per-iteration sync point). The default loop stores a plain
-    float and host arrays: it has read them before the event fires."""
+    """``cost`` is a plain float and ``metrics["fetches"]`` host arrays:
+    the loop has read both off the device before the event fires."""
 
     def __init__(self, pass_id, batch_id, cost, metrics=None):
         self.pass_id = pass_id
         self.batch_id = batch_id
-        self._cost = cost
-        self._metrics = metrics or {}
-
-    @property
-    def cost(self):
-        self._cost = materialize_scalar(self._cost)
-        return self._cost
-
-    @cost.setter
-    def cost(self, value):
-        self._cost = value
-
-    @property
-    def metrics(self):
-        self._metrics = {k: materialize(v)
-                         for k, v in self._metrics.items()}
-        return self._metrics
-
-    @metrics.setter
-    def metrics(self, value):
-        self._metrics = value or {}
+        self.cost = cost
+        self.metrics = metrics or {}
 
 
 def _step_spans(batches, pass_id, first_step):
@@ -307,9 +284,8 @@ class Trainer(object):
         return self._load_checkpoint_state()
 
     def train(self, reader=None, num_passes=1, event_handler=None,
-              pipeline=None, pipeline_depth=None, elastic=None,
-              task_reader=None, elastic_root=None, on_commit=None,
-              on_skip=None, on_resume=None):
+              elastic=None, task_reader=None, elastic_root=None,
+              on_commit=None, on_skip=None, on_resume=None):
         """The default loop looks one batch ahead, on this thread.
         Iteration n: ``BeginIteration(n)``; step n is dispatched on the
         batch that is already on the device (``Executor.run(sync=False)``
@@ -334,16 +310,6 @@ class Trainer(object):
         go of them too — a reader sees none of this, its samples are only
         read.
 
-        ``pipeline=True`` runs the async execution pipeline
-        (paddle_tpu.pipeline) on top: a feed thread prepares +
-        device_puts up to ``pipeline_depth`` batches ahead, so a feed
-        that outlasts the device step is hidden too, and fetches stay on
-        device until a real sync point — the handler touching
-        ``.cost``/``.metrics``, the log-period progress line, pass end,
-        or a checkpoint. Losses are bit-identical to the default loop.
-        Defaults follow ``FLAGS.pipeline`` / ``FLAGS.pipeline_depth``;
-        ``check_nan_inf`` always forces the per-op path on this thread.
-
         ``elastic=True`` runs the loop as an ELASTIC WORKER
         (paddle_tpu.elastic.worker, doc/elasticity.md): the launcher
         env is resolved and validated, the (host, chip)/comm plan is
@@ -353,17 +319,15 @@ class Trainer(object):
         one minibatch``) — batches lease through the supervisor-owned
         task master with exactly-once commit accounting. Without
         ``task_reader`` the plain ``reader`` drives a lease-free worker
-        (same role minus the master). Composes with ``pipeline=`` and
-        the ``comm_overlap``/``comm_policy`` flags in one job.
+        (same role minus the master). Composes with the
+        ``comm_overlap``/``comm_policy`` flags in one job.
 
         Two loop-level failure policies, both off by default:
         ``FLAGS.step_timeout_s`` arms the step-hang watchdog (a wedged
         step exits 75 for a transient supervisor restart) and
         ``FLAGS.loss_skip_budget`` arms the numeric guardrails
         (non-finite/spiking losses skip the batch, budget exhaustion
-        rewinds to the last checkpoint once per window). The guardrail
-        check materializes each batch's loss — a declared per-batch
-        sync point under ``pipeline=True``."""
+        rewinds to the last checkpoint once per window)."""
         from . import profiler as _prof
         from .flags import FLAGS
         use_elastic = FLAGS.elastic if elastic is None else bool(elastic)
@@ -406,18 +370,10 @@ class Trainer(object):
         self._maybe_init()
         handler = event_handler or (lambda e: None)
         log_period = FLAGS.log_period
-        use_pipe = FLAGS.pipeline if pipeline is None else bool(pipeline)
-        depth = int(pipeline_depth if pipeline_depth is not None
-                    else FLAGS.pipeline_depth)
-        if use_pipe and (depth < 1 or self.exe.check_nan_inf):
-            # the NaN/Inf scan needs the synchronous per-op path
-            use_pipe = False
         # the master answers "wait" while any lease is pending, and the
         # lease of batch n is pending until commit(n): asked for batch n+1
         # before that, this thread would wait on its own commit until the
         # lease lapsed. The lease path takes batch n+1 after commit(n)
-        # (FeedPipeline looks ahead there: its thread waits, this one
-        # commits)
         leased = worker is not None and task_reader is not None
         watchdog = None
         if FLAGS.step_timeout_s > 0:
@@ -460,138 +416,110 @@ class Trainer(object):
                 handler(BeginPass(pass_id))
                 costs = []
                 batch_id = -1
-                pipe = None
                 if watchdog is not None:
                     # the deadline covers the first batch's feed+compile
                     # too — a reader wedged before its first yield is
                     # still a hang
                     watchdog.arm("pass%d/start" % pass_id)
-                try:
-                    if use_pipe:
-                        pipe = FeedPipeline(reader, self.feeder,
-                                            self.exe, depth=depth)
-                        batches = pipe
-                    else:
-                        batches = _Lookahead(reader(), self)
-                    last_iter_t = None
-                    feed_wait_seen = 0.0
+                batches = _Lookahead(reader(), self)
+                last_iter_t = None
+                commit_ms_last = 0.0
+                for batch_id, data in _step_spans(batches, pass_id,
+                                                  steps_done):
+                    # the gray-failure heartbeat: the wall delta
+                    # between iteration starts (reader wait + dispatch
+                    # + any injected stall — a device-timer-only
+                    # number is blind to these) MINUS the
+                    # commit/checkpoint span: that is legitimate
+                    # per-role overhead (only the lease owner pays
+                    # it), not gray slowness — the step watchdog
+                    # pauses around it for the same reason
+                    now_t = time.monotonic()
+                    if worker is not None and \
+                            last_iter_t is not None:
+                        worker.publish_heartbeat(
+                            max((now_t - last_iter_t) * 1e3
+                                - commit_ms_last, 0.0))
+                    last_iter_t = now_t
                     commit_ms_last = 0.0
-                    for batch_id, data in _step_spans(batches, pass_id,
-                                                      steps_done):
-                        # the gray-failure heartbeat: the wall
-                        # delta between iteration starts (reader
-                        # wait + dispatch + any injected stall —
-                        # the async pipeline makes a batch-timer-
-                        # only number blind to these) MINUS the
-                        # commit/checkpoint span: that is
-                        # legitimate per-role overhead (only the
-                        # lease owner pays it), not gray slowness —
-                        # the step watchdog pauses around it for
-                        # the same reason
-                        now_t = time.monotonic()
-                        if worker is not None and \
-                                last_iter_t is not None:
-                            fw = None
-                            if pipe is not None:
-                                total = pipe.stats["feed_wait_ms"]
-                                fw = total - feed_wait_seen
-                                feed_wait_seen = total
-                            worker.publish_heartbeat(
-                                max((now_t - last_iter_t) * 1e3
-                                    - commit_ms_last, 0.0),
-                                feed_wait_ms=fw)
-                        last_iter_t = now_t
-                        commit_ms_last = 0.0
-                        handler(BeginIteration(pass_id, batch_id))
+                    handler(BeginIteration(pass_id, batch_id))
+                    if watchdog is not None:
+                        watchdog.ping("pass%d/batch%d"
+                                      % (pass_id, batch_id))
+                    # chaos lever: delay = a wedged step (the
+                    # watchdog's quarry), raise = a step failure
+                    # that propagates (the supervisor's
+                    # transient-restart path)
+                    fault_point("trainer.step")
+                    # data is a device-resident feed dict (from the
+                    # lookahead); the call returns once the step is
+                    # enqueued
+                    outs = self.exe.run(
+                        self.main_program, feed=data,
+                        fetch_list=self.fetch_list, sync=False)
+                    cost = outs[0]  # lazy AsyncFetch
+                    # the device computes this step while the host
+                    # stacks and uploads the next batch
+                    if not leased and batches.take():
+                        ready = int(cost.ready)
+                        es = self.exe.stats
+                        es["lookahead_steps"] += 1
+                        es["lookahead_loss_ready"] += ready
+                        _prof.update_pipeline_counters(
+                            lookahead_steps=1,
+                            lookahead_loss_ready=ready)
+                    # the step's sync point: a wedged device surfaces
+                    # HERE, inside the armed deadline
+                    cost = materialize_scalar(cost)
+                    outs = materialize(outs)
+                    skipped = False
+                    if guard is not None:
+                        skipped = guard.check(
+                            cost, pass_id=pass_id,
+                            batch_id=batch_id) != "ok"
                         if watchdog is not None:
-                            watchdog.ping("pass%d/batch%d"
+                            watchdog.ping(
+                                "pass%d/batch%d/guarded"
+                                % (pass_id, batch_id))
+                    counted = True
+                    if worker is not None:
+                        # lease commit + (on the cadence) the
+                        # paired checkpoint — not a step, so the
+                        # step deadline pauses around it
+                        if watchdog is not None:
+                            watchdog.disarm()
+                        commit_t0 = time.monotonic()
+                        counted = worker.commit(cost=cost,
+                                                skipped=skipped)
+                        commit_ms_last = (time.monotonic()
+                                          - commit_t0) * 1e3
+                        if watchdog is not None:
+                            watchdog.arm("pass%d/batch%d/next"
+                                         % (pass_id, batch_id))
+                    if not skipped and counted:
+                        # a lapsed lease (counted=False) is a
+                        # batch the audited timeline disowns —
+                        # a survivor re-runs it; pass metrics
+                        # must agree with the lease accounting
+                        costs.append(cost)
+                    if log_period and \
+                            (batch_id + 1) % log_period == 0:
+                        # the reference's per-log_period batch line
+                        # (reference: TrainerInternal.cpp:159-171)
+                        window = costs[-log_period:]
+                        if window:
+                            print("pass %d batch %d: cost=%.6f "
+                                  "(avg %.6f)"
+                                  % (pass_id, batch_id, window[-1],
+                                     float(np.mean(window))))
+                        if watchdog is not None:
+                            watchdog.ping("pass%d/batch%d/log"
                                           % (pass_id, batch_id))
-                        # chaos lever: delay = a wedged step (the
-                        # watchdog's quarry), raise = a step failure
-                        # that propagates (the supervisor's
-                        # transient-restart path)
-                        fault_point("trainer.step")
-                        # data is a device-resident feed dict (from
-                        # the lookahead or the pipeline ring); the
-                        # call returns once the step is enqueued
-                        outs = self.exe.run(
-                            self.main_program, feed=data,
-                            fetch_list=self.fetch_list, sync=False)
-                        cost = outs[0]  # lazy AsyncFetch
-                        if not use_pipe:
-                            # the device computes this step while the
-                            # host stacks and uploads the next batch
-                            if not leased and batches.take():
-                                ready = int(cost.ready)
-                                es = self.exe.stats
-                                es["lookahead_steps"] += 1
-                                es["lookahead_loss_ready"] += ready
-                                _prof.update_pipeline_counters(
-                                    lookahead_steps=1,
-                                    lookahead_loss_ready=ready)
-                            cost = materialize_scalar(cost)
-                            outs = materialize(outs)
-                        skipped = False
-                        if guard is not None:
-                            # the guardrail sync point: a wedged
-                            # device surfaces HERE under the async
-                            # pipeline, inside the armed deadline
-                            cost = materialize_scalar(cost)
-                            skipped = guard.check(
-                                cost, pass_id=pass_id,
-                                batch_id=batch_id) != "ok"
-                            if watchdog is not None:
-                                watchdog.ping(
-                                    "pass%d/batch%d/guarded"
-                                    % (pass_id, batch_id))
-                        counted = True
-                        if worker is not None:
-                            # lease commit + (on the cadence) the
-                            # paired checkpoint — not a step, so the
-                            # step deadline pauses around it
-                            if watchdog is not None:
-                                watchdog.disarm()
-                            commit_t0 = time.monotonic()
-                            counted = worker.commit(cost=cost,
-                                                    skipped=skipped)
-                            commit_ms_last = (time.monotonic()
-                                              - commit_t0) * 1e3
-                            if watchdog is not None:
-                                watchdog.arm("pass%d/batch%d/next"
-                                             % (pass_id, batch_id))
-                        if not skipped and counted:
-                            # a lapsed lease (counted=False) is a
-                            # batch the audited timeline disowns —
-                            # a survivor re-runs it; pass metrics
-                            # must agree with the lease accounting
-                            costs.append(cost)
-                        if log_period and \
-                                (batch_id + 1) % log_period == 0:
-                            # the reference's per-log_period batch line
-                            # (reference: TrainerInternal.cpp:159-171)
-                            # — a declared materialization point
-                            window = [materialize_scalar(c)
-                                      for c in costs[-log_period:]]
-                            if window:
-                                print("pass %d batch %d: cost=%.6f "
-                                      "(avg %.6f)"
-                                      % (pass_id, batch_id, window[-1],
-                                         float(np.mean(window))))
-                            if watchdog is not None:
-                                watchdog.ping("pass%d/batch%d/log"
-                                              % (pass_id, batch_id))
-                        handler(EndIteration(pass_id, batch_id, cost,
-                                             {"fetches": outs[1:]}))
-                        if self.preempted:
-                            break
-                finally:
-                    if pipe is not None:
-                        pipe.close()
-                        self._merge_pipeline_stats(pipe, _prof)
+                    handler(EndIteration(pass_id, batch_id, cost,
+                                         {"fetches": outs[1:]}))
+                    if self.preempted:
+                        break
                 steps_done += batch_id + 1
-                # pass end is a materialization point (and it precedes
-                # every checkpoint below, keeping saves synchronous)
-                costs = [materialize_scalar(c) for c in costs]
                 if watchdog is not None:
                     watchdog.disarm()
                 # a guardrail-skipped batch's update may still sit in
@@ -634,21 +562,6 @@ class Trainer(object):
             if hook_installed:
                 signal.signal(signal.SIGTERM, old_sigterm)
 
-    def _merge_pipeline_stats(self, pipe, _prof):
-        """Fold one pass's FeedPipeline counters into Executor.stats and
-        the profiler's pipeline section so the overlap is observable."""
-        st = pipe.stats
-        es = self.exe.stats
-        es["feed_wait_ms"] += st["feed_wait_ms"]
-        es["dispatch_depth"] = max(es["dispatch_depth"],
-                                   st["max_in_flight"])
-        _prof.update_pipeline_counters(
-            feed_wait_ms=st["feed_wait_ms"],
-            dispatch_depth=st["max_in_flight"],
-            pipeline_batches=st["batches"],
-            slot_reuse=st["slot_reuse"],
-            fallback_sync=1 if st["fallback_sync"] else 0)
-
     def _test_program(self, fetches):
         """Pruned for-test clone: drops backward + optimizer ops so
         evaluation never updates parameters or accumulators (reference:
@@ -662,65 +575,24 @@ class Trainer(object):
             self._test_cache = (names, pruned)
         return self._test_cache[1]
 
-    def test(self, reader, fetch_list=None, program=None, pipeline=None,
-             pipeline_depth=None):
+    def test(self, reader, fetch_list=None, program=None):
         """Average fetched metrics over a reader (reference:
-        v2/trainer.py test / fluid book tests' test loops).
-
-        ``pipeline=True`` (default ``FLAGS.pipeline``) runs the eval
-        loop through the same async pipeline as training: a feed thread
-        prepares + device_puts batch k+1 while batch k computes, and
-        fetches materialise one batch BEHIND the dispatch (batch k's
-        metrics are read while k+1 computes; the final batch at the
-        return-value sync point) — the loop never blocks on the batch it
-        just launched, and accumulation stays O(1) in pass length.
-        Results are bit-identical to the synchronous loop;
-        ``check_nan_inf`` forces synchronous."""
+        v2/trainer.py test / fluid book tests' test loops). Feeds and
+        runs ``program`` (default: the pruned for-test clone, which
+        writes no parameter or accumulator) one batch after the other on
+        this thread."""
         self._maybe_init()
-        from . import profiler as _prof
-        from .flags import FLAGS
         fetches = fetch_list or self.fetch_list
         program = program or self._test_program(fetches)
-        use_pipe = FLAGS.pipeline if pipeline is None else bool(pipeline)
-        depth = int(pipeline_depth if pipeline_depth is not None
-                    else FLAGS.pipeline_depth)
-        if use_pipe and (depth < 1 or self.exe.check_nan_inf):
-            use_pipe = False
-        state = {"acc": None, "n": 0}
-
-        def fold(outs):
+        acc, n = None, 0
+        for data in reader():
             # accumulation is O(1) in pass length — a 50k-batch eval
             # must not buffer 50k fetch tensors host- or device-side
-            vals = [materialize_scalar(o) for o in outs]
-            state["acc"] = (vals if state["acc"] is None
-                            else [a + v for a, v in zip(state["acc"],
-                                                        vals)])
-            state["n"] += 1
-
-        pipe = None
-        try:
-            if use_pipe:
-                pipe = FeedPipeline(reader, self.feeder, self.exe,
-                                    depth=depth)
-                prev = None  # fold batch k-1 while batch k computes
-                for data in pipe:
-                    outs = self.exe.run(program, feed=data,
-                                        fetch_list=fetches, sync=False)
-                    if prev is not None:
-                        fold(prev)
-                    prev = outs
-                if prev is not None:
-                    fold(prev)  # the pass-end sync point
-            else:
-                for data in reader():
-                    fold(self.exe.run(program,
-                                      feed=self.feeder.feed(data),
-                                      fetch_list=fetches))
-        finally:
-            if pipe is not None:
-                pipe.close()
-                self._merge_pipeline_stats(pipe, _prof)
-        return [a / max(state["n"], 1) for a in (state["acc"] or [])]
+            vals = [materialize_scalar(o) for o in self.exe.run(
+                program, feed=self.feeder.feed(data), fetch_list=fetches)]
+            acc = vals if acc is None else [a + v for a, v in zip(acc, vals)]
+            n += 1
+        return [a / max(n, 1) for a in (acc or [])]
 
     def save_checkpoint(self, dirname=None, sharded=False, async_=False,
                         step=None):

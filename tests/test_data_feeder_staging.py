@@ -285,15 +285,34 @@ def by_hand_losses():
             fetch_list=[cost])[0]).reshape(-1)[0]) for n in range(STEPS)]
 
 
-@pytest.mark.parametrize("depth", [None, 1, 2, 4],
-                         ids=["lookahead", "depth1", "depth2", "depth4"])
-def test_the_loops_train_on_the_bytes_a_bare_executor_is_fed(depth,
-                                                             by_hand_losses):
+@pytest.fixture(scope="module")
+def by_hand_eval():
+    """The forward ops alone on a bare Executor: the mean loss over the
+    batches, parameters as the startup program left them."""
     xs, ys = _train_batches()
-    losses = []
+    with pt.scope_guard(pt.Scope()):
+        main, startup, cost, _ = _net()
+        exe = pt.Executor(pt.CPUPlace())
+        exe.run(startup)
+        return sum(float(np.asarray(exe.run(
+            main, feed={"x": np.array(list(xs[n]), "float32"),
+                        "y": np.array(list(ys[n]), "float32")},
+            fetch_list=[cost])[0]).reshape(-1)[0])
+            for n in range(STEPS)) / STEPS
 
-    def reader():
-        for n in range(STEPS):
+
+@pytest.mark.parametrize("loop", ["one_pass", "two_passes", "eval"])
+def test_the_loops_train_on_the_bytes_a_bare_executor_is_fed(
+        loop, by_hand_losses, by_hand_eval):
+    xs, ys = _train_batches()
+    passes = 2 if loop == "two_passes" else 1
+    per_pass = STEPS // passes
+    losses, started = [], []
+
+    def reader():       # the second pass goes on where the first ended
+        started.append(len(started))
+        for n in range(started[-1] * per_pass,
+                       (started[-1] + 1) * per_pass):
             yield [(xs[n, i], ys[n, i]) for i in range(BATCH)]
 
     def handler(e):
@@ -306,13 +325,16 @@ def test_the_loops_train_on_the_bytes_a_bare_executor_is_fed(depth,
                              feed_list=feeds, place=pt.CPUPlace(),
                              main_program=main, startup_program=startup)
         again = _spy_on_staging(trainer.feeder)
-        if depth is None:
-            trainer.train(reader, num_passes=1, event_handler=handler)
+        if loop == "eval":
+            # Trainer.test hands the staging arrays to Executor.run itself
+            assert trainer.test(reader) == [by_hand_eval]
         else:
-            trainer.train(reader, num_passes=1, event_handler=handler,
-                          pipeline=True, pipeline_depth=depth)
-    assert losses == by_hand_losses
+            trainer.train(reader, num_passes=passes, event_handler=handler)
+            assert losses == by_hand_losses
     assert len(again) == 2 * STEPS and any(again)
+    if loop == "two_passes":
+        # the arrays outlive the pass: its first batch takes used ones
+        assert all(again[2 * per_pass:2 * per_pass + 2])
 
 
 # -- two callers at once --------------------------------------------------------

@@ -7,7 +7,7 @@ import jax
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu import pipeline as pl
+from paddle_tpu.core import compile_cache as pl
 from paddle_tpu import place as place_mod
 
 

@@ -1014,30 +1014,81 @@ def test_trainer_elastic_poison_task_follows_failure_contract(
     assert tr.exe.stats["elastic_task_failures"] == 1
 
 
-def test_trainer_elastic_pipeline_feed_fault_degrades_exactly_once(
+def test_trainer_elastic_lease_n_plus_1_is_asked_for_after_commit_n(
         tmp_path, monkeypatch):
-    """PR-3 contract inside the elastic pass: an armed
-    pipeline.feed_next raise flips the pipeline to synchronous feeding,
-    RETRYING the failed batch — and the lease accounting still commits
-    every task exactly once."""
+    """The lease path under the default loop: the master hands out no
+    lease past a pending one, so batch n+1 is asked for only after
+    ``commit(n)`` — the loop looks ahead of nothing there."""
     master = _mk_master(4)
-    root = str(tmp_path / "ckpt")
     _lease_env(monkeypatch, master, tmp_path)
     tr = _worker_trainer()
-    commits = []
-    R.arm("pipeline.feed_next", "raise", nth=2, times=1)
+    log = []
+
+    def on_resume(worker):      # the worker is set up: listen to its client
+        get_task, finished = (worker.client.get_task,
+                              worker.client.task_finished)
+
+        def logged_get(**kw):
+            tid, payload = get_task(**kw)
+            log.append(("lease", payload and payload.decode()))
+            return tid, payload
+
+        def logged_finished(tid):
+            log.append(("commit",))
+            return finished(tid)
+        worker.client.get_task = logged_get
+        worker.client.task_finished = logged_finished
+
+    def handler(e):
+        if isinstance(e, pt.EndIteration):
+            log.append(("end", e.batch_id))
     try:
         with flags_guard(comm_hosts=FLAGS.comm_hosts):
             tr.train(elastic=True, task_reader=_task_batch,
-                     elastic_root=root, pipeline=True, pipeline_depth=2,
-                     on_commit=lambda s, t, p, c: commits.append(
-                         p.decode()))
+                     elastic_root=str(tmp_path / "ckpt"),
+                     on_resume=on_resume, event_handler=handler)
     finally:
-        R.disarm("pipeline.feed_next")
         master.close()
-    assert sorted(commits) == ["batch-%d" % i for i in range(4)]
-    assert R.events(kind="pipeline_degraded")
+    leased = [e[1] for e in log if e[0] == "lease"]
+    assert sorted(leased[:-1]) == ["batch-%d" % i for i in range(4)]
+    assert leased[-1] is None                   # the pass's end
+    assert [e[:1] + e[2:] if e[0] == "lease" else e for e in log] == [
+        step for n in range(4)
+        for step in (("lease",), ("commit",), ("end", n))] + [("lease",)]
+
+
+def test_trainer_elastic_feed_that_raises_on_task_k_commits_the_k_before(
+        tmp_path, monkeypatch):
+    """``DataFeeder.feed`` raising on task k ends ``train()``: tasks
+    0..k-1 are committed exactly once, task k is not, and its lease is
+    still pending at the master for a survivor to take when it lapses."""
+    k = 2
+    master = _mk_master(4)
+    _lease_env(monkeypatch, master, tmp_path)
+    tr = _worker_trainer()
+    leases, commits = [], []
+
+    def a_slot_short_on_lease_k(payload):
+        leases.append(payload.decode())
+        batch = _task_batch(payload)
+        return [row[:1] for row in batch] if len(leases) == k + 1 else batch
+    try:
+        with flags_guard(comm_hosts=FLAGS.comm_hosts):
+            with pytest.raises(Exception) as err:
+                tr.train(elastic=True, task_reader=a_slot_short_on_lease_k,
+                         elastic_root=str(tmp_path / "ckpt"),
+                         on_commit=lambda s, t, p, c: commits.append(
+                             p.decode()))
+        counts = master.counts()
+    finally:
+        master.close()
+    assert not isinstance(err.value, (KeyboardInterrupt, SystemExit))
+    assert len(leases) == k + 1 and commits == leases[:k]
+    assert len(set(commits)) == k
+    assert tr.exe.stats["elastic_tasks_committed"] == k
     assert tr.exe.stats["elastic_lease_losses"] == 0
+    assert (counts["done"], counts["pending"], counts["todo"]) == (
+        k, 1, 4 - k - 1)
 
 
 def test_trainer_elastic_reader_next_fault_retries_exactly_once(

@@ -309,9 +309,8 @@ def test_trainer_kill_and_resume(tmp_path):
 
 
 def test_master_client_concurrent_calls_never_cross_responses():
-    """ONE MasterClient connection used from two threads (the elastic
-    worker's reality under pipeline=True: the feed thread leases while
-    the main thread commits) must serialize request/response pairs —
+    """ONE MasterClient connection used from two threads (one leases
+    while the other commits) must serialize request/response pairs —
     crossed frames made a successful FIN read a GET's reply, a spurious
     lease-lost that silently dropped a row from the exactly-once audit
     trail."""

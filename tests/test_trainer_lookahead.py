@@ -186,8 +186,8 @@ def test_the_handler_of_end_n_reads_the_state_after_step_n(
 
 def test_cost_is_a_float_and_fetches_are_host_arrays(one_pass, by_hand):
     for n, e in enumerate(one_pass.ends):
-        assert type(e._cost) is float and type(e.cost) is float
-        (pred,) = e._metrics["fetches"]
+        assert type(e.cost) is float and "cost" in vars(e)
+        (pred,) = vars(e)["metrics"]["fetches"]     # plain attributes
         assert type(pred) is np.ndarray
         np.testing.assert_array_equal(e.metrics["fetches"][0],
                                       by_hand[2][n])

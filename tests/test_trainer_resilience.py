@@ -385,20 +385,6 @@ def test_trainer_guard_is_inert_by_default():
     assert R.events(kind="batch_skipped") == []
 
 
-def test_trainer_guard_composes_with_pipeline(tmp_path):
-    """The guardrail check is a declared per-batch sync point under the
-    async pipeline: same skip/rewind behavior, loss parity on the
-    accepted batches."""
-    tr = _build_trainer(checkpoint_dir=str(tmp_path))
-    tr.train(_batches(4), num_passes=1)
-    R.clear_events()
-    with flags_guard(loss_skip_budget=2):
-        tr.train(_batches(8, nan_at=3), num_passes=1, pipeline=True,
-                 pipeline_depth=2)
-    assert R.events(kind="batch_skipped")
-    assert len(R.events(kind="guard_rewind")) == 1
-
-
 # ---------------------------------------------------------------------------
 # preemption x supervisor escalation (trainer.py SIGTERM hook)
 

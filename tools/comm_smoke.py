@@ -24,9 +24,8 @@ policies must hold their numerics contract on a forced 8-device run —
 
 The measurement lives in benchmark/comm_bench.py — the SAME harness any
 bench comm phase emits evidence from, so gate and evidence cannot
-drift. Companion to tools/lint.sh (static), tools/perf_smoke.sh (async
-pipeline), tools/serve_smoke.sh (serving). Exit 0 on pass, 1 on
-failure; prints a one-line JSON summary either way.
+drift. Companion to tools/lint.sh (static), tools/serve_smoke.sh
+(serving). Exit 0 on pass, 1 on failure; prints a one-line JSON summary either way.
 
 Invoked by tools/comm_smoke.sh; usable directly:
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \

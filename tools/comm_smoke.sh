@@ -7,8 +7,7 @@
 # (buckets < param count), and the comm/compute-overlap matrix: every
 # policy x comm_overlap=1 parity plus a no-slower step-time leg (banked
 # as a paddle_tpu.bench.v1 row). Companion to tools/lint.sh /
-# perf_smoke.sh / serve_smoke.sh. One retry damps shared-CI scheduler
-# noise.
+# serve_smoke.sh. One retry damps shared-CI scheduler noise.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"

@@ -21,8 +21,7 @@ hold on a real multi-process chaos run —
     with every task processed.
 
 The REAL-TRAINER legs (``mode="trainer"``: every rank runs
-``Trainer.train(elastic=True)`` with ``pipeline=True`` under
-``comm_overlap`` — the PR-8 protocol spoken by the actual loop):
+``Trainer.train(elastic=True)`` under ``comm_overlap`` — the PR-8 protocol spoken by the actual loop):
 
 (g) **trainer chaos**: rank 0 (the lease owner) SIGKILLed mid-pass —
     resize 4 -> 3, every task exactly once, probe-loss continuity at
@@ -47,7 +46,7 @@ The REAL-TRAINER legs (``mode="trainer"``: every rank runs
 
 The measurement lives in benchmark/chaos_run.py — the same harness an
 operator points at a real TPU pod (cluster/README.md). Companion to
-tools/{lint,perf_smoke,serve_smoke,comm_smoke,tune_smoke}.sh. Exit 0
+tools/{lint,serve_smoke,comm_smoke,tune_smoke}.sh. Exit 0
 on pass, 1 on failure; prints a one-line JSON summary either way.
 
 Invoked by tools/elastic_smoke.sh; usable directly:
@@ -118,7 +117,7 @@ def main():
         failures.append("fault leg exactly_once: %s" % p)
 
     # (g): the REAL Trainer as elastic worker — every rank runs
-    # Trainer.train(elastic=True, pipeline=True) under comm_overlap;
+    # Trainer.train(elastic=True) under comm_overlap;
     # the lease-owning rank is SIGKILLed mid-pass
     tleg = cr.run_chaos(
         tempfile.mkdtemp(prefix="elastic_smoke_trainer_"),

@@ -7,14 +7,14 @@
 # the no-failure elastic run bit-identical to the fail-fast launcher.
 # An armed elastic.replan fault degrades (recorded) instead of killing.
 # Real-Trainer legs beside the raw-Executor ones: every rank runs
-# Trainer.train(elastic=True, pipeline=True) under comm_overlap — the
+# Trainer.train(elastic=True) under comm_overlap — the
 # lease owner SIGKILLed mid-pass (resize 4->3, exactly-once,
 # continuity), a seeded-NaN batch skipped by the numeric guardrail
 # (recorded batch_skipped + bounded rewind), and a seeded hung read
 # tripping the step watchdog into one transient restart (step_hung,
 # exit 75, full world back — never a wedged gang).
-# Companion to tools/lint.sh / perf_smoke.sh / serve_smoke.sh /
-# comm_smoke.sh / tune_smoke.sh. One retry damps shared-CI scheduler
+# Companion to tools/lint.sh / serve_smoke.sh / comm_smoke.sh /
+# tune_smoke.sh. One retry damps shared-CI scheduler
 # noise.
 set -uo pipefail
 cd "$(dirname "$0")/.."

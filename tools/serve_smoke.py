@@ -11,13 +11,12 @@ in-process (no sockets — the HTTP shell has its own tests), flood it
 with in-flight ``infer_async`` requests (the realistic overload shape:
 full batches form instantly, no formation-timeout stalls), and time the
 sequential loop over the same feeds on the same warmed model. Both
-measurements run per wave; the best-of-``WAVES`` ratio is gated, the
-same scheduler-noise damping perf_smoke.py uses.
+measurements run per wave; the best-of-``WAVES`` ratio is gated, to damp
+scheduler noise.
 
-Companion to tools/lint.sh (static) and tools/perf_smoke.sh (training
-pipeline); invoked by tools/serve_smoke.sh, which retries once to damp
-shared-CI scheduler noise. Exit 0 on pass, 1 on failure; prints a
-one-line JSON summary either way.
+Companion to tools/lint.sh (static); invoked by tools/serve_smoke.sh,
+which retries once to damp shared-CI scheduler noise. Exit 0 on pass, 1
+on failure; prints a one-line JSON summary either way.
 
     JAX_PLATFORMS=cpu python tools/serve_smoke.py
 """

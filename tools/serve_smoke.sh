@@ -3,9 +3,8 @@
 # bit-identical outputs to direct CompiledModel.run(), really coalesce
 # concurrent requests, and beat a sequential per-request loop on
 # throughput — CPU tier-1, in-process, no device or sockets needed.
-# Companion to tools/lint.sh (static) and tools/perf_smoke.sh (training
-# pipeline). One retry damps shared-CI scheduler noise before calling a
-# throughput loss real.
+# Companion to tools/lint.sh (static). One retry damps shared-CI
+# scheduler noise before calling a throughput loss real.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"

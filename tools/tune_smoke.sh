@@ -5,9 +5,8 @@
 # injected per-candidate fault, detect (and re-tune past) a corrupted
 # cache entry, and dispatch must honor the cache switch — fallbacks
 # recorded with tune=0, hits with the cache armed. Runs against a
-# throwaway cache dir. Companion to tools/lint.sh / perf_smoke.sh /
-# serve_smoke.sh / comm_smoke.sh. One retry damps shared-CI scheduler
-# noise.
+# throwaway cache dir. Companion to tools/lint.sh / serve_smoke.sh /
+# comm_smoke.sh. One retry damps shared-CI scheduler noise.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
