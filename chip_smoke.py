@@ -348,6 +348,11 @@ def phase_train(cfg, seed, rehearse):
                         "hybrid_runs")})
     _check(late_compiles == 0, "%d XLA compile(s) after the first step"
            % late_compiles)
+    _check(exe.stats["ahead_steps"] == cfg["steps"]
+           and exe.stats["ahead_dropped"] == 0,
+           "expected every step but the first dispatched ahead and none "
+           "dropped, got %r" % {k: exe.stats[k] for k in
+                                ("ahead_steps", "ahead_dropped")})
     mem = jax.devices()[0].memory_stats() or {}
     with pt.scope_guard(scope):
         staging = _staging_reuse(trainer, cfg, 12, rehearse)
@@ -368,6 +373,10 @@ def phase_train(cfg, seed, rehearse):
            "xla_compiles_after_first_step": late_compiles,
            "peak_hbm_bytes": mem.get("peak_bytes_in_use"),
            "staging_reuse": staging,
+           # the loop's dispatch ahead: every step but the first was in
+           # the chip's queue before the loss of the one before it was read
+           "ahead_steps": exe.stats["ahead_steps"],
+           "ahead_dropped": exe.stats["ahead_dropped"],
            "compile_cache": _cache_report(
                cache_before, programs_read_from_cache=cache_hits)}
     rec.update(_audit("train"))

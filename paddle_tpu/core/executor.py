@@ -709,19 +709,31 @@ class _TracedOnce(object):
     def __call__(self, *args):
         if self._ready.is_set():
             return self.fn(*args)
-        from .. import profiler as _prof
         with _FIRST_TRACE_LOCK:
             if self._ready.is_set():    # another thread's first call won
                 return self.fn(*args)
-            # before the call: it donates the state's buffers
-            self._avals = jax.tree_util.tree_map(_abstract, args)
-            _prof.set_phase("trace")    # per-op spans now time the lowering
-            try:
-                with _prof.span("compile", **self._span_args):
-                    out = self.fn(*args)
-            finally:
-                _prof.set_phase("eager")
-            self._ready.set()
+            return self._trace(args, lambda: self.fn(*args))
+
+    def memory(self, *args):
+        """``memory_analysis()`` of the step compiled for ``args`` in
+        place of a tracing first call: the calls that follow find trace
+        and executable in jax's own caches, so the step is still traced
+        and compiled once. None where the backend gives none."""
+        with _FIRST_TRACE_LOCK:
+            return self._trace(args, lambda: self.fn.lower(
+                *self._avals).compile().memory_analysis())
+
+    def _trace(self, args, first):
+        from .. import profiler as _prof
+        # before the call: it may donate the state's buffers
+        self._avals = jax.tree_util.tree_map(_abstract, args)
+        _prof.set_phase("trace")        # per-op spans now time the lowering
+        try:
+            with _prof.span("compile", **self._span_args):
+                out = first()
+        finally:
+            _prof.set_phase("eager")
+        self._ready.set()
         return out
 
     def facts(self):
@@ -762,6 +774,56 @@ def _step_name(program, feed_template, fetch_names, repeat, dist):
     return "paddle_tpu_step_%08x" % zlib.crc32(content.encode())
 
 
+def _device_bytes_limit(device):
+    """The bytes the device says it can hold, None where it does not say
+    (the CPU)."""
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+def _held_step_fits(mem, limit):
+    """The memory rule of ``Executor.run(hold=True)``: a held step reads
+    the state from one set of buffers and writes the new state into a
+    second, spare one (which two steps in flight share in turn), where a
+    donating step needs one. ``mem`` is the held step's
+    ``memory_analysis()``: its arguments hold both sets, and beside its
+    peak one more copy of its outputs has to fit, for what the analysis
+    does not see (the loop's next batch on its way to the device). No
+    limit or no analysis reported: it fits."""
+    if mem is None or limit is None:
+        return True
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    return peak + mem.output_size_in_bytes <= limit
+
+
+def _spare_like(value, spare):
+    """``spare`` where it can take ``value``'s place as a donated
+    argument (a device array of the same shape, dtype and sharding that
+    is not ``value`` and was not given away), else fresh zeros like
+    ``value``."""
+    if (isinstance(spare, jax.Array) and isinstance(value, jax.Array)
+            and spare is not value and not spare.is_deleted()
+            and spare.shape == value.shape and spare.dtype == value.dtype
+            and spare.sharding == value.sharding):
+        return spare
+    return jax.tree_util.tree_map(
+        lambda v: jax.device_put(np.zeros(v.shape, v.dtype), v.sharding),
+        value)
+
+
+class _HeldStep(object):
+    """What a step run with ``hold=True`` would have written to
+    ``scope``, and the scope's write stamp when it was dispatched."""
+
+    __slots__ = ("scope", "stamp", "state", "rng_key")
+
+    def __init__(self, scope, state, rng_key):
+        self.scope = scope
+        self.stamp = scope.write_stamp()
+        self.state = state
+        self.rng_key = rng_key
+
+
 def compiled_steps():
     """The compiled steps the process keeps (the warm registry)."""
     return list(_WARM_JIT_CACHE.values())
@@ -800,6 +862,9 @@ class Executor(object):
         # those of them that had finished on the device when the host came
         # back for the loss: a ratio near 1 says the host sets the pace,
         # near 0 the device
+        # ahead_steps counts the steps of that loop that were dispatched
+        # before the loss of the step before them was read (run(hold=True)),
+        # ahead_dropped the held steps that were discarded, not committed
         # the comm_* entries model the DP grad-sync wire traffic of the
         # compiled program under the active comm policy (paddle_tpu.comm;
         # refreshed per compile), and record quant fallbacks folded in by
@@ -822,6 +887,7 @@ class Executor(object):
                       "lazy_fetches": 0, "fetch_sync_count": 0,
                       "compiles": 0, "compile_cache_hits": 0,
                       "lookahead_steps": 0, "lookahead_loss_ready": 0,
+                      "ahead_steps": 0, "ahead_dropped": 0,
                       "comm_bytes": 0, "comm_buckets": 0,
                       "comm_quant_fallbacks": 0,
                       "comm_path": "",
@@ -849,6 +915,15 @@ class Executor(object):
         # across scope lifetimes.
         import weakref
         self._state_memo = weakref.WeakKeyDictionary()
+        # the step run(hold=True) keeps back until commit() or drop(), and
+        # the dead buffers its successor's new state will be written into:
+        # the values a commit replaced in the scope, or a dropped step's
+        self._held = None
+        self._spare = None
+        # (uid, version) of programs whose compiled step cannot be held
+        # back: two steps in flight do not fit the device's memory, or
+        # the step cannot be built without donation
+        self._unheld = set()
 
     @property
     def check_nan_inf(self):
@@ -935,7 +1010,7 @@ class Executor(object):
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, use_jit=True, feed_var_name="feed",
             fetch_var_name="fetch", dist_context=None, repeat=1,
-            sync=True):
+            sync=True, hold=False):
         """``repeat=K`` compiles K whole training steps into one
         ``lax.scan`` dispatch (fetches come from the last step). This is the
         standard TPU step-fusion pattern: one host round-trip amortises K
@@ -951,17 +1026,94 @@ class Executor(object):
         (Trainer's default loop does). Values materialise lazily at
         first access; paths that compute eagerly on the host
         (``check_nan_inf``, host ops) still return handles, just trivially
-        ready ones."""
+        ready ones.
+
+        ``hold=True`` dispatches the step and keeps its new state back:
+        the scope is not written and the state's buffers are not donated,
+        so the scope goes on holding the state the step started from,
+        alive, while the step runs. :meth:`commit` writes the held state
+        to the scope, :meth:`drop` discards it; a second ``hold=True``
+        drops what the first one held. Trainer's default loop dispatches
+        step n+1 this way before it reads step n's loss, and commits it
+        after ``EndIteration(n)``. What a held step donates instead is a
+        spare set of dead buffers for its new state to land in: the
+        values the last commit replaced in the scope (the state BEFORE
+        the one this step reads, which nothing in the scope refers to any
+        more), or fresh ones where there are none. So held steps take
+        turns on two sets of buffers, and a device array that a caller
+        took from the scope is given away one step later than under
+        ``run``'s donation, not never. Returns None, with nothing run and
+        nothing written, where the step cannot be held back: a program
+        off the jit path (:meth:`can_hold`), a step with no such build
+        (the explicit-comm dp step), or one whose two sets of buffers do
+        not fit the device's memory (decided once per compiled step from
+        its ``memory_analysis()`` and the device's ``bytes_limit``; no
+        limit reported, as on the CPU, means it fits)."""
         from .. import profiler as _prof
         program = program if program is not None else ir.default_main_program()
         with _prof.span("run", program=program._uid):
             return self._run(program, feed, fetch_list, scope, return_numpy,
-                             use_jit, dist_context, repeat, sync)
+                             use_jit, dist_context, repeat, sync, hold)
+
+    def can_hold(self, program):
+        """False where ``run(program, hold=True)`` is known to give None:
+        the program runs off the jit path, which writes the scope as it
+        goes (host ops, ``check_nan_inf``, a trace that fell back to the
+        interpreter), or its compiled step was refused before."""
+        return not (self._off_jit(program)
+                    or (program._uid, program._version) in self._unheld)
+
+    def _off_jit(self, program):
+        return (_is_host_block(program.global_block()) or self.check_nan_inf
+                or program._uid in self._force_eager)
+
+    def commit(self):
+        """Write the state that ``run(hold=True)`` kept back to its scope.
+        False, with the scope untouched, where nothing is held or where
+        the scope was written since the step was dispatched (a handler's
+        ``set_var``, a checkpoint load, another run): the step was
+        computed from a state that is no longer the scope's and is
+        dropped, for the caller to run again."""
+        held = self._held
+        if held is None:
+            return False
+        if held.scope.write_stamp() != held.stamp:
+            self._abandon()
+            return False
+        self._held, spare = None, {}
+        for n, v in held.state.items():
+            spare[n] = held.scope.find_var(n)   # dead once replaced
+            held.scope.set_var(n, v)
+        held.scope.set_var(RNG_VAR, held.rng_key)
+        self._spare = spare
+        return True
+
+    def drop(self):
+        """Discard the state that ``run(hold=True)`` kept back, if any
+        (the scope goes on holding the state that step started from), and
+        let go of the spare buffers kept for the next held step."""
+        self._abandon()
+        self._spare = None
+
+    def _abandon(self):
+        """The held step will never be committed: its new state's buffers
+        are the next held step's spare ones."""
+        if self._held is not None:
+            from .. import profiler as _prof
+            self._held, self._spare = None, self._held.state
+            self.stats["ahead_dropped"] += 1
+            _prof.update_pipeline_counters(ahead_dropped=1)
 
     def _run(self, program, feed, fetch_list, scope, return_numpy, use_jit,
-             dist_context, repeat, sync):
+             dist_context, repeat, sync, hold=False):
         from .. import profiler as _prof
         self._maybe_verify(program)
+        block = program.global_block()
+        off_jit = not use_jit or self._off_jit(program)
+        if hold:
+            self._abandon()
+            if off_jit or (program._uid, program._version) in self._unheld:
+                return None
         scope = scope if scope is not None else global_scope()
         feed = feed or {}
         fetch_list = fetch_list or []
@@ -972,12 +1124,10 @@ class Executor(object):
         # under a mesh, leave feeds uncommitted: jit's in_shardings place them
         dev = None if dist is not None else self._device()
         dev_feed = _upload(feed, dev)
-        block = program.global_block()
 
         timing = _prof.profiler_enabled()
         t0 = time.perf_counter() if timing else 0.0
-        if (_is_host_block(block) or not use_jit or self.check_nan_inf
-                or program._uid in self._force_eager):
+        if off_jit:
             # host ops (save/load) can't be jit-traced. Instead of dropping
             # the WHOLE program to the per-op interpreter (r1 weak item 3),
             # partition it: contiguous device-op segments jit-compile,
@@ -1025,7 +1175,9 @@ class Executor(object):
         else:
             try:
                 outs = self._run_jit(program, dev_feed, fetch_names, scope,
-                                     dist=dist, repeat=repeat)
+                                     dist=dist, repeat=repeat, hold=hold)
+                if outs is None:
+                    return None
                 self.stats["jit_runs"] += 1
             except (jax.errors.ConcretizationTypeError,
                     jax.errors.TracerArrayConversionError,
@@ -1044,6 +1196,8 @@ class Executor(object):
                     "from now on (10-100x slower on TPU). Cause: %s"
                     % (program._uid, str(e).splitlines()[0]), RuntimeWarning)
                 self._force_eager.add(program._uid)
+                if hold:
+                    return None     # the trace failed: nothing has run
                 self.stats["eager_runs"] += 1
                 outs = self._run_eager(program, dev_feed, fetch_names, scope)
         if timing:
@@ -1247,13 +1401,14 @@ class Executor(object):
 
     # -- jit path --------------------------------------------------------------
     def _run_jit(self, program, feed, fetch_names, scope, dist=None,
-                 repeat=1):
+                 repeat=1, hold=False):
         from .. import profiler as _prof
         with _prof.span("dispatch"):
             return self._dispatch(program, feed, fetch_names, scope,
-                                  dist, repeat)
+                                  dist, repeat, hold)
 
-    def _dispatch(self, program, feed, fetch_names, scope, dist, repeat):
+    def _dispatch(self, program, feed, fetch_names, scope, dist, repeat,
+                  hold=False):
         per_scope = self._state_memo.setdefault(scope, {})
         # parent scopes can own persistables found via the lookup walk;
         # include their name-set versions so additions there invalidate
@@ -1295,10 +1450,11 @@ class Executor(object):
         # site): nothing numpy-backed may reach the donated argument
         # position; PADDLE_TPU_SANITIZE=alias additionally proves the
         # copies above did not zero-copy alias their host sources
-        from ..analysis.sanitize import check_donated
-        check_donated(state, "executor._run_jit", always=True,
-                      host_sources={n: v for n, v in raw_state.items()
-                                    if isinstance(v, np.ndarray)})
+        if not hold:
+            from ..analysis.sanitize import check_donated
+            check_donated(state, "executor._run_jit", always=True,
+                          host_sources={n: v for n, v in raw_state.items()
+                                        if isinstance(v, np.ndarray)})
         if dist is not None:
             # align committed buffers with the declared shardings (no-op when
             # already placed; reshards e.g. replicated startup output → tp)
@@ -1316,6 +1472,9 @@ class Executor(object):
             state = {n: v if getattr(v, "committed", True)
                      else jax.device_put(v, dev) for n, v in state.items()}
         from .. import profiler as _prof
+        rng_key = self._rng_key(program, scope)
+        if dist is None:
+            rng_key = jax.device_put(rng_key, dev)
         key = (program._uid, program._version, _feed_signature(feed),
                tuple(fetch_names), repeat,
                dist.cache_token() if dist is not None else None,
@@ -1323,7 +1482,9 @@ class Executor(object):
                # mesh (explicit collective routing + the byte model):
                # a flags_guard flip must not hit a stale compile
                _comm_flags_sig() if dist is not None else None,
-               state_sig)
+               state_sig,
+               # a held step is another executable: it donates nothing
+               hold)
         fn = self._cache.get(key)
         if fn is None:
             # warm start: another Executor in this process already compiled
@@ -1348,10 +1509,18 @@ class Executor(object):
                 self._sharding_preflight(program, dist)
             shardings = (_dist_shardings(dist, state, feed)
                          if dist is not None else None)
-            fn = _TracedOnce(
-                self._compile(program, feed, fetch_names, state_names,
-                              shardings=shardings, dist=dist, repeat=repeat),
-                program, dist.num_devices if dist is not None else 1)
+            jitted = self._compile(program, feed, fetch_names, state_names,
+                                   shardings=shardings, dist=dist,
+                                   repeat=repeat, donate=not hold)
+            fn = None if jitted is None else _TracedOnce(
+                jitted, program, dist.num_devices if dist is not None else 1)
+            # (the state stands in for the spare set: the same avals)
+            if hold and (fn is None or not _held_step_fits(
+                    fn.memory(state, feed, rng_key, state),
+                    _device_bytes_limit(dev if dist is None
+                                        else dist.mesh.devices.flat[0]))):
+                self._unheld.add((program._uid, program._version))
+                return None
             self.stats["compiles"] += 1
             if dist is not None:
                 self._record_comm_model(program, dist)
@@ -1361,16 +1530,21 @@ class Executor(object):
             _WARM_JIT_CACHE[key] = fn
         if _prof.profiler_enabled():
             _prof.note_profiled_step("program_%d" % program._uid, fn)
-        rng_key = self._rng_key(program, scope)
-        if dist is None:
-            rng_key = jax.device_put(rng_key, dev)
+        args = (state, feed, rng_key)
+        if hold:
+            spare, self._spare = self._spare or {}, None
+            args += ({n: _spare_like(v, spare.get(n))
+                      for n, v in state.items()},)
         try:
-            fetches, new_state, new_key = fn(state, feed, rng_key)
+            fetches, new_state, new_key = fn(*args)
         except Exception:
             # a failed first trace must not leave a dead compiled fn cached
             self._cache.pop(key, None)
             _WARM_JIT_CACHE.pop(key, None)
             raise
+        if hold:
+            self._held = _HeldStep(scope, new_state, new_key)
+            return fetches
         for n, v in new_state.items():
             scope.set_var(n, v)
         scope.set_var(RNG_VAR, new_key)
@@ -1716,7 +1890,15 @@ class Executor(object):
             comm_payload_bytes=summary["comm_payload_bytes"])
 
     def _compile(self, program, feed_template, fetch_names, state_names,
-                 shardings=None, dist=None, repeat=1):
+                 shardings=None, dist=None, repeat=1, donate=True):
+        """The jitted step ``fn(state, feed, rng_key)``, which donates
+        the state. ``donate=False`` (``run(hold=True)``) builds
+        ``fn(state, feed, rng_key, spare)`` instead, which donates only
+        ``spare``: buffers like the state's that it never reads, for XLA
+        to write the new state into (a step that allocates its ~430
+        outputs anew takes the TPU runtime ~19 ms longer to enqueue: my
+        chip run, PR 31). None where this step has no such build (the
+        explicit-comm dp step)."""
         # first compile in the process configures jax's on-disk XLA cache
         # (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache;
         # FLAGS.compile_cache=0 opts out) so repeat runs skip the cold
@@ -1781,10 +1963,21 @@ class Executor(object):
         fn.__name__ = _step_name(program, feed_template, fetch_names, repeat,
                                  dist)
 
-        if shardings is not None:
-            jitted = jax.jit(fn, donate_argnums=(0,), in_shardings=shardings)
+        if donate:
+            donated = {"donate_argnums": (0,)}
         else:
-            jitted = jax.jit(fn, donate_argnums=(0,))
+            step = fn
+
+            def fn(state, feed, rng_key, spare):
+                return step(state, feed, rng_key)
+            fn.__name__ = step.__name__
+            donated = {"donate_argnums": (3,), "keep_unused": True}
+            if shardings is not None:
+                shardings += (shardings[0],)
+        if shardings is not None:
+            jitted = jax.jit(fn, in_shardings=shardings, **donated)
+        else:
+            jitted = jax.jit(fn, **donated)
         if dist is not None:
             # (4) of the comm tentpole: eligible pure-DP programs route
             # their grad sync through the explicit comm collectives; the
@@ -1792,6 +1985,8 @@ class Executor(object):
             # if the build cannot hold the contract
             plan = self._explicit_comm_plan(program, block, dist,
                                             feed_template)
+            if plan is not None and not donate:
+                return None
             if plan is not None:
                 return self._compile_explicit_comm(
                     program, block, dist, plan, feed_template,

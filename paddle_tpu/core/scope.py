@@ -21,12 +21,19 @@ class Scope(object):
         # don't bump (shapes/dtypes of existing entries are re-validated
         # only when the set changes, which is when new persistables appear)
         self._names_version = 0
+        # bumped by every write that changes what the scope holds (``var``'s
+        # create, ``set_var`` of another object, ``erase``): an executor
+        # that holds a step's new state back (``Executor.run(hold=True)``)
+        # may commit it only if the scope it was computed from was not
+        # written since
+        self._writes = 0
 
     def var(self, name: str):
         """Find-or-create (reference: Scope::Var)."""
         if name not in self._vars:
             self._vars[name] = None
             self._names_version += 1
+            self._writes += 1
         return self._vars[name]
 
     def find_var(self, name: str):
@@ -50,16 +57,32 @@ class Scope(object):
         s = self
         while s is not None:
             if name in s._vars:
-                s._vars[name] = value
+                if s._vars[name] is not value:
+                    # a save program's write-back of the very object the
+                    # scope holds changes nothing
+                    s._vars[name] = value
+                    s._writes += 1
                 return
             s = s.parent
         self._vars[name] = value
         self._names_version += 1
+        self._writes += 1
 
     def erase(self, name: str):
         if name in self._vars:
             self._names_version += 1
+            self._writes += 1
         self._vars.pop(name, None)
+
+    def write_stamp(self):
+        """The write counts of this scope and its parents: equal stamps
+        mean that no variable a lookup from here can reach was written in
+        between."""
+        stamp, s = [], self
+        while s is not None:
+            stamp.append(s._writes)
+            s = s.parent
+        return tuple(stamp)
 
     def new_scope(self) -> "Scope":
         kid = Scope(self)

@@ -210,17 +210,21 @@ class DataFeeder(object):
     def _staging(self, field, shape, dtype):
         """An array of ``shape`` that nothing but this call can read or
         write: one handed out earlier that only the feeder still refers
-        to, else a new one. The other arrays found unreferenced are let
-        go: the feeder holds what is in flight and no more."""
+        to, else a new one. Of the other arrays found unreferenced one of
+        this shape stays as a spare and the rest are let go: the feeder
+        holds what is in flight and one batch more. The spare is for a
+        loop whose uploads take turns on two arrays (batch n+1 is stacked
+        while jax still refers to batch n's array): jax lets go of an
+        array at its next ``device_put`` or at any pass of Python's
+        collector, so now and then both are found free, and with one let
+        go the next batch would map 154 MB of fresh pages (~150 ms on the
+        chip's host) two steps later."""
         with self._lock:
             held = self._staged[field]
-            taken = None
-            for i in reversed(range(len(held))):
-                if _refs(held, i) == _SOLE_REF:
-                    spare = held.pop(i)
-                    if taken is None and spare.shape == shape:
-                        taken = spare
-            if taken is None:
-                taken = np.empty(shape, dtype)
+            free = [held.pop(i) for i in reversed(range(len(held)))
+                    if _refs(held, i) == _SOLE_REF]
+            fit = [a for a in free if a.shape == shape][:2]
+            taken = fit.pop(0) if fit else np.empty(shape, dtype)
+            held.extend(fit)            # the spare, if there was one
             held.append(taken)
             return taken

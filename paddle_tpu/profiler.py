@@ -124,7 +124,8 @@ def update_pipeline_counters(**counters):
     """Accumulate the step-feeding observability counters (always on — a
     few dict adds per step/materialisation, not per op). Keys in use:
     ``fetch_sync_count``, ``compile_cache_hits``; of Trainer's default
-    loop, ``lookahead_steps`` and ``lookahead_loss_ready``."""
+    loop, ``lookahead_steps``, ``lookahead_loss_ready``, ``ahead_steps``
+    and ``ahead_dropped``."""
     for k, v in counters.items():
         _pipeline_counters[k] += float(v)
 

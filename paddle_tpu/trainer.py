@@ -57,8 +57,10 @@ def _step_spans(batches, pass_id, first_step):
     off ``batches`` to the end of the loop's body (after ``EndIteration``'s
     handler). Over a :class:`_Lookahead` the batch of step n is in hand
     already (step 0's is taken here), so ``train_step(n)`` covers the
-    dispatch of step n, the ``feed`` and ``upload`` of batch n+1, the
-    ``fetch`` of step n's loss and the handler. ``step_num`` counts on
+    commit of step n's state, the ``feed`` and ``upload`` of batch n+1,
+    the ``run`` that dispatches step n+1 ahead, the ``fetch`` of step n's
+    loss and the handler (and the ``run`` of step n itself where it was
+    not dispatched ahead: step 0 of a pass). ``step_num`` counts on
     from ``first_step`` (batches since ``train()`` began). A span cannot
     be taken back, so the call that finds the reader exhausted leaves one
     too, marked ``end_of_pass=1`` and with no batch in it."""
@@ -81,10 +83,11 @@ class _Lookahead(object):
     """The default loop's batches: those of ``batches`` as device-resident
     feed dicts (``feeder.feed`` + ``Executor.prepare_feed``), at most one
     ahead of the step that runs. ``take()`` prepares the next one now —
-    the loop calls it between dispatching step n and reading its loss, so
-    batch n+1 is stacked and uploaded while the device computes — and
-    holds it, or what taking it raised (the reader's end too), for the
-    ``next()`` that follows ``EndIteration(n)``. ``next()`` with nothing
+    the loop calls it before it reads step n's loss, so batch n+1 is
+    stacked and uploaded while the device computes — and holds it (where
+    the loop dispatches step n+1 ahead, ``held`` is its feed), or what
+    taking it raised (the reader's end too), for the ``next()`` that
+    follows ``EndIteration(n)``. ``next()`` with nothing
     held takes the batch itself. ``feed`` and ``prepare_feed`` are looked
     up at every batch: a tracer may have replaced them on the
     instances."""
@@ -103,6 +106,11 @@ class _Lookahead(object):
         except Exception as e:
             self._held = (None, e)
         return self._held[1] is None
+
+    @property
+    def held(self):
+        """The feed dict ``take()`` has in hand, or None."""
+        return self._held[0] if self._held is not None else None
 
     def __iter__(self):
         return self
@@ -286,24 +294,56 @@ class Trainer(object):
     def train(self, reader=None, num_passes=1, event_handler=None,
               elastic=None, task_reader=None, elastic_root=None,
               on_commit=None, on_skip=None, on_resume=None):
-        """The default loop looks one batch ahead, on this thread.
-        Iteration n: ``BeginIteration(n)``; step n is dispatched on the
-        batch that is already on the device (``Executor.run(sync=False)``
-        returns once it is enqueued); batch n+1 is taken off the reader,
-        stacked (``DataFeeder.feed``) and uploaded
-        (``Executor.prepare_feed``) while the device computes; then the
-        loss and the other fetches of step n are read to the host (a
-        ``float``, NumPy arrays), and guard, commit, log line and
-        ``EndIteration(n)`` follow as ever. The reader is never asked for
-        batch n+2 before ``EndIteration(n)``, and step n+1 is not
-        dispatched before that handler returns: the scope a handler reads
-        holds the state after step n. The lookahead does not cross a
-        pass. What taking batch n+1 raises is raised after
-        ``EndIteration(n)``. On preemption one batch may have been taken
-        off the reader and not trained. ``elastic`` with ``task_reader``
-        takes batch n+1 only after step n's lease is committed (the
-        master hands out no lease past a pending last one). Losses are
-        those of feeding and running strictly in turn, bit for bit.
+        """The default loop looks one batch ahead and dispatches one step
+        ahead, on this thread. Iteration n: ``BeginIteration(n)``; the
+        new state of step n, which the executor has held back since the
+        step was dispatched in iteration n-1, is committed to the scope
+        (``Executor.commit``; step 0 of a pass is dispatched here and
+        committed at once); batch n+1 is taken off the reader, stacked
+        (``DataFeeder.feed``) and uploaded (``Executor.prepare_feed``)
+        while the device computes; step n+1 is dispatched on it from the
+        state the scope holds (``Executor.run(sync=False, hold=True)``
+        returns once it is enqueued, consumes none of the state it reads
+        and writes nothing);
+        then the loss and the other fetches of step n are read to the
+        host (a ``float``, NumPy arrays), and guard, commit, log line
+        and ``EndIteration(n)`` follow as ever. When the device ends
+        step n, step n+1 is in its queue.
+
+        What a caller can see is the serial loop's. The reader is never
+        asked for batch n+2 before ``EndIteration(n)``. The scope a
+        handler of ``EndIteration(n)`` reads holds the state after step
+        n exactly: step n+1 reads those buffers and does not consume
+        them. Losses, fetches and state are those of feeding and running
+        strictly in turn, bit for bit. Whatever writes the scope between
+        the dispatch of step n+1 and its commit (a handler's
+        ``set_var`` at ``EndIteration(n)`` or ``BeginIteration(n+1)``,
+        the numeric guard's rewind, a checkpoint load, another
+        ``Executor.run``) makes the executor drop that step
+        (``Scope.write_stamp``), and it runs again from what the scope
+        holds: one wasted device step, the serial loop's answer.
+        Preemption and an exception out of a handler drop it and never
+        commit it: the scope holds the state after step n; one batch may
+        have been taken off the reader and not trained. Nothing is
+        dispatched ahead across a pass. What taking batch n+1 raises is
+        raised after ``EndIteration(n)``.
+
+        The loop dispatches step n+1 after ``EndIteration(n)`` instead,
+        from donated state as before PR 31, where the executor cannot
+        hold a step back: a program off the jit path (host ops,
+        ``check_nan_inf``), a dp step on the explicit comm collectives,
+        or a step whose two sets of state buffers (held steps write the
+        new state into the buffers of the state before the one they
+        read) do not fit the device's memory (decided by
+        ``Executor.run(hold=True)`` once per compiled step from its
+        ``memory_analysis()`` and the device's ``bytes_limit``; no flag
+        and no argument). ``elastic`` with ``task_reader`` takes
+        batch n+1 only after step n's lease is committed (the master
+        hands out no lease past a pending last one), so it has no step
+        to dispatch ahead and keeps that order too.
+        ``Executor.stats`` / ``profiler.pipeline_counters()``:
+        ``ahead_steps`` (steps dispatched before the loss of the step
+        before them was read; steps - 1 a pass) and ``ahead_dropped``.
         The host arrays of a batch are the feeder's staging arrays
         (``DataFeeder``): the loop drops them once they are uploaded, and
         the feeder writes a later batch into them when the upload has let
@@ -422,6 +462,12 @@ class Trainer(object):
                     # still a hang
                     watchdog.arm("pass%d/start" % pass_id)
                 batches = _Lookahead(reader(), self)
+                # the handles of step n+1 while it is dispatched ahead
+                # (None: the step is dispatched in its own iteration), and
+                # whether steps are run held back: never on the lease
+                # path, which has no batch n+1 to dispatch ahead on
+                ahead = None
+                holds = not leased and self.exe.can_hold(self.main_program)
                 last_iter_t = None
                 commit_ms_last = 0.0
                 for batch_id, data in _step_spans(batches, pass_id,
@@ -451,12 +497,25 @@ class Trainer(object):
                     # that propagates (the supervisor's
                     # transient-restart path)
                     fault_point("trainer.step")
-                    # data is a device-resident feed dict (from the
-                    # lookahead); the call returns once the step is
-                    # enqueued
-                    outs = self.exe.run(
-                        self.main_program, feed=data,
-                        fetch_list=self.fetch_list, sync=False)
+                    # this step was dispatched ahead, in the last
+                    # iteration: its state goes to the scope now. Where a
+                    # handler, a rewind or a checkpoint load wrote the
+                    # scope since, the executor drops it instead and the
+                    # step runs again, from what the scope holds
+                    outs, ahead = ahead, None
+                    if outs is None or not self.exe.commit():
+                        # step 0 of a pass, a step that was dropped,
+                        # and every step of a program the executor cannot
+                        # hold back: dispatched now (held steps committed
+                        # at once: one executable for every step). data
+                        # is a device-resident feed dict (from the
+                        # lookahead); the call returns once the step is
+                        # enqueued
+                        outs = self._dispatch(data, hold=True) \
+                            if holds else None
+                        holds = outs is not None and self.exe.commit()
+                        if not holds:
+                            outs = self._dispatch(data)
                     cost = outs[0]  # lazy AsyncFetch
                     # the device computes this step while the host
                     # stacks and uploads the next batch
@@ -468,6 +527,17 @@ class Trainer(object):
                         _prof.update_pipeline_counters(
                             lookahead_steps=1,
                             lookahead_loss_ready=ready)
+                        if holds:
+                            # step n+1 from the state the scope holds, in
+                            # the device's queue before step n's loss is
+                            # waited for; its new state is held back so
+                            # that EndIteration(n) reads state n
+                            ahead = self._dispatch(batches.held, hold=True)
+                            holds = ahead is not None
+                            if holds:
+                                es["ahead_steps"] += 1
+                                _prof.update_pipeline_counters(
+                                    ahead_steps=1)
                     # the step's sync point: a wedged device surfaces
                     # HERE, inside the armed deadline
                     cost = materialize_scalar(cost)
@@ -554,6 +624,9 @@ class Trainer(object):
                                 {"avg_cost": float(np.mean(costs))
                                  if costs else float("nan")}))
         finally:
+            # preemption, or an exception out of a handler: the step that
+            # was dispatched ahead is never committed
+            self.exe.drop()
             if watchdog is not None:
                 watchdog.close()
             if worker is not None:
@@ -561,6 +634,16 @@ class Trainer(object):
                 worker.close()
             if hook_installed:
                 signal.signal(signal.SIGTERM, old_sigterm)
+
+    def _dispatch(self, feed, hold=False):
+        """Enqueue one training step on ``feed``; lazy handles of the
+        fetches. ``hold=True``: with its new state held back
+        (``Executor.run(hold=True)``), or None where the executor cannot.
+        ``run`` is looked up on the instance at every step: a tracer may
+        have replaced it."""
+        return self.exe.run(self.main_program, feed=feed,
+                            fetch_list=self.fetch_list, sync=False,
+                            **({"hold": True} if hold else {}))
 
     def _test_program(self, fetches):
         """Pruned for-test clone: drops backward + optimizer ops so

@@ -1021,6 +1021,50 @@ def test_executor_explicit_comm_path(dp8_mesh):
                                rtol=1e-4, atol=1e-6)
 
 
+def test_executor_holds_a_gspmd_dp_step_and_not_an_explicit_comm_one(
+        dp8_mesh):
+    """``run(hold=True)`` (Trainer's dispatch ahead): the plain GSPMD dp
+    step is built without donation, held and committed to the losses of
+    the donating one; the explicit-comm step has no such build, so the
+    executor says no, runs nothing, and the caller's next plain run is
+    the explicit path as ever."""
+    from paddle_tpu.core.scope import Scope
+    from paddle_tpu.parallel import data_parallel
+    prog, startup, loss, _ = _dp_program()
+    ref, _, _ = _run_executor(prog, startup, [loss], dp8_mesh)
+    xs, ys = _mlp_data()
+    feed = {"x": xs, "y": ys[:, None]}
+
+    def held_run(exe, scope):
+        exe.run(startup, scope=scope)
+        out = exe.run(prog, feed=feed, fetch_list=[loss], scope=scope,
+                      hold=True)
+        return out and float(np.asarray(out[0]).reshape(()))
+    scope = Scope()
+    exe = pt.Executor(pt.CPUPlace(), dist_context=data_parallel(dp8_mesh))
+    got = [held_run(exe, scope)]
+    assert exe.commit() and exe.can_hold(prog)
+    for _ in range(2):
+        out = exe.run(prog, feed=feed, fetch_list=[loss], scope=scope,
+                      hold=True)
+        assert exe.commit()
+        got.append(float(np.asarray(out[0]).reshape(())))
+    assert got == ref                                   # bit for bit
+    # startup (which another Executor of this test compiled) + ONE step
+    assert exe.stats["compiles"] + exe.stats["compile_cache_hits"] == 2
+    with flags_guard(comm_policy="fused", comm_hosts=2):
+        scope = Scope()
+        exe = pt.Executor(pt.CPUPlace(),
+                          dist_context=data_parallel(dp8_mesh))
+        assert held_run(exe, scope) is None
+        assert not exe.can_hold(prog) and exe._held is None
+        assert exe.stats["jit_runs"] == 1               # startup's alone
+        out = exe.run(prog, feed=feed, fetch_list=[loss], scope=scope)
+    assert exe.stats["comm_path"] == "explicit"
+    np.testing.assert_allclose(float(np.asarray(out[0]).reshape(())),
+                               ref[0], rtol=1e-5)
+
+
 def test_executor_explicit_comm_overlap_and_policies(dp8_mesh):
     """hierarchical/multipath + comm_overlap ride the executor path
     too (overlap = backward-order bucket issue inside the trace)."""

@@ -229,13 +229,15 @@ def test_a_fed_batch_is_unchanged_while_anything_can_read_it(holding):
 
 
 def test_the_feeder_holds_what_is_in_flight_and_no_more():
+    """And one spare of the batch's shape."""
     feeder = _feeder(("x", [DIM], "float32"))
     kept = [feeder.feed([(np.full(DIM, n),)] * ROWS) for n in range(5)]
     assert len(feeder._staged[0]) == 5
     assert len({k["x"].ctypes.data for k in kept}) == 5
     del kept[1:]
-    one = feeder.feed([(np.full(DIM, 9),)] * ROWS)      # takes one, drops 3
-    assert len(feeder._staged[0]) == 2
+    # takes one, keeps one as the spare, drops 2
+    one = feeder.feed([(np.full(DIM, 9),)] * ROWS)
+    assert len(feeder._staged[0]) == 3
     assert np.array_equal(kept[0]["x"], np.zeros((ROWS, DIM), "float32"))
     assert np.array_equal(one["x"], np.full((ROWS, DIM), 9, "float32"))
     # another batch size is another array; the old size's goes when free
@@ -244,6 +246,31 @@ def test_the_feeder_holds_what_is_in_flight_and_no_more():
     del one
     feeder.feed([(np.full(DIM, 7),)] * 2)
     assert sorted(a.shape[0] for a in feeder._staged[0]) == [2, 2, ROWS]
+
+
+def test_two_arrays_that_take_turns_survive_a_feed_that_finds_both_free():
+    """Trainer's loop stacks batch n+1 while jax still refers to batch n's
+    array, so two arrays take turns. jax lets go at its next device_put
+    or at any pass of Python's collector: now and then a feed finds both
+    free. With one of them let go, the feed after the next would have to
+    map a fresh array (~150 ms for the benchmark's 154 MB batch)."""
+    feeder = _feeder(("x", [DIM], "float32"))
+    batch = [(np.zeros(DIM),)] * ROWS
+    in_flight = feeder.feed(batch)["x"]         # batch n, "uploading"
+    turns = {id(in_flight)}
+    for n in range(8):
+        fed = feeder.feed(batch)["x"]           # batch n+1 beside it
+        turns.add(id(fed))
+        assert fed is not in_flight and len(feeder._staged[0]) == 2
+        if n == 3:
+            # jax let go of batch n's array early, and the loop dropped
+            # batch n+1's: the next feed finds both free
+            del in_flight, fed
+            in_flight = feeder.feed(batch)["x"]
+            assert len(feeder._staged[0]) == 2  # one taken, one spare
+            continue
+        in_flight = fed                         # the older one is let go of
+    assert len(turns) == 2                      # never a third array
 
 
 # -- the training loops over it ---------------------------------------------

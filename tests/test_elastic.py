@@ -911,6 +911,33 @@ def test_trainer_elastic_worker_leases_pairs_and_resumes(
     assert tr._elastic_worker.step == 5
 
 
+def test_trainer_elastic_lease_path_dispatches_nothing_ahead(
+        tmp_path, monkeypatch):
+    """No batch n+1 before commit(n), so no step n+1 to dispatch ahead:
+    the lease path asks the executor to hold nothing back and runs the
+    donating step, one dispatch after the other's commit."""
+    master = _mk_master(3)
+    _lease_env(monkeypatch, master, tmp_path)
+    tr = _worker_trainer()
+    run, calls = tr.exe.run, []
+
+    def logged_run(program=None, **k):
+        if program is tr.main_program:
+            calls.append(k.get("hold", False))
+        return run(program, **k)
+    tr.exe.run = logged_run
+    try:
+        with flags_guard(comm_hosts=FLAGS.comm_hosts):
+            tr.train(elastic=True, task_reader=_task_batch,
+                     elastic_root=str(tmp_path / "ckpt"))
+    finally:
+        master.close()
+    assert calls == [False, False, False]
+    assert tr.exe.stats["elastic_tasks_committed"] == 3
+    assert tr.exe.stats["ahead_steps"] == 0
+    assert tr.exe.stats["ahead_dropped"] == 0 and tr.exe._held is None
+
+
 def test_trainer_elastic_worker_resumes_from_paired_point(
         tmp_path, monkeypatch):
     """A second generation over the same root resumes at the paired

@@ -97,32 +97,37 @@ def _train_steps(spans):
 
 @pytest.mark.parametrize("k", range(STEPS))
 def test_each_step_span_holds_its_run_the_next_feed_and_its_fetch(traced, k):
-    """train_step(k): run(k) returns once the step is dispatched, batch
-    k+1 is fed and uploaded, then step k's loss is fetched. Step 0 also
-    takes its own batch, the last step finds the reader exhausted."""
+    """train_step(k): batch k+1 is fed and uploaded, run(k+1) returns
+    once step k+1 is dispatched on it, then step k's loss is fetched.
+    Step 0 also takes its own batch and dispatches itself first, the last
+    step finds the reader exhausted and dispatches nothing."""
     spans = traced["spans"]
     step = _train_steps(spans)[k]
     assert (step[3]["step_num"], step[3]["pass_id"],
             step[3]["batch_id"]) == (k, 0, k)
-    (run,), (fetch,) = _inside(spans, step, "run"), _inside(spans, step,
-                                                            "fetch")
-    assert run[3]["program"] == traced["main"]._uid
-    (dispatch,) = _inside(spans, run, "dispatch")
-    assert not _inside(spans, run, "upload")    # the batch was here
-    assert not _inside(spans, run, "fetch")     # run() does not wait
-    assert dispatch[2] <= run[2] <= fetch[1]
-    feeds, uploads = (_inside(spans, step, n) for n in ("feed", "upload"))
+    runs, (fetch,) = _inside(spans, step, "run"), _inside(spans, step,
+                                                          "fetch")
     own = 1 if k == 0 else 0
     ahead = 1 if k < STEPS - 1 else 0
+    assert len(runs) == own + ahead
+    for run in runs:
+        assert run[3]["program"] == traced["main"]._uid
+        (dispatch,) = _inside(spans, run, "dispatch")
+        assert not _inside(spans, run, "upload")    # the batch was here
+        assert not _inside(spans, run, "fetch")     # run() does not wait
+        assert dispatch[2] <= run[2] <= fetch[1]
+    feeds, uploads = (_inside(spans, step, n) for n in ("feed", "upload"))
     assert len(feeds) == len(uploads) == own + ahead
     for feed, upload in zip(feeds, uploads):
         assert feed[3]["rows"] == BATCH
         assert feed[3]["bytes"] == upload[3]["bytes"] == BATCH_NBYTES
         assert feed[2] <= upload[1]
     if own:
-        assert uploads[0][2] <= run[1]          # fed, then run
-    if ahead:                    # between the dispatch and the loss
-        assert run[2] <= feeds[-1][1] and uploads[-1][2] <= fetch[1]
+        assert uploads[0][2] <= runs[0][1]      # fed, then run
+    if own and ahead:
+        assert runs[0][2] <= feeds[-1][1]
+    if ahead:           # fed, dispatched on it, and then the loss
+        assert uploads[-1][2] <= runs[-1][1] and runs[-1][2] <= fetch[1]
     # all of it on the thread that called train()
     assert {s[4] for n in ("feed", "upload", "run", "dispatch", "fetch")
             for s in _inside(spans, step, n)} == {step[4]}
@@ -173,7 +178,8 @@ def test_the_one_compile_span_is_in_step_0_inside_dispatch(traced):
     assert compile_[3] == {"program": traced["main"]._uid,
                            "version": traced["main"]._version}
     step0 = _train_steps(spans)[0]
-    (dispatch,) = _inside(spans, step0, "dispatch")
+    # step 0's own dispatch; step 1's, ahead, finds the step compiled
+    dispatch, _ahead = _inside(spans, step0, "dispatch")
     assert dispatch[1] <= compile_[1] and compile_[2] <= dispatch[2]
 
 
