@@ -49,7 +49,7 @@ import threading
 import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-PHASES_1CHIP = ("train", "serve", "kernels")
+PHASES_1CHIP = ("train", "serve", "kernels", "decoder")
 
 # events whose kind says a path degraded or fell back instead of running
 # what was asked; any of these fails the phase that recorded it
@@ -67,6 +67,12 @@ REAL = {
                 "matmul": 4096},
     "dp4": {"depth": 50, "image": 224, "classes": 1000, "batch": 128,
             "lr": 0.01, "steps": 4},
+    # the latent-attention / sparse-expert block at its real head widths
+    # (192-wide q/k, 128-wide v), 4 of 8 experts and 128 of 512 rows held
+    "decoder": {"hidden": 256, "heads": 4, "nope": 128, "rope": 64, "v": 128,
+                "rank": 128, "dense": 512, "expert": 128, "experts": 8,
+                "top_k": 2, "held": [4, 4], "vocab": 512,
+                "vocab_held": [128, 128], "seq": 256, "rows": 2},
 }
 TINY = {
     "train": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
@@ -80,6 +86,10 @@ TINY = {
                 "matmul": 256},
     "dp4": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
             "lr": 0.01, "steps": 3},
+    "decoder": {"hidden": 64, "heads": 4, "nope": 24, "rope": 8, "v": 16,
+                "rank": 32, "dense": 96, "expert": 32, "experts": 8,
+                "top_k": 2, "held": [4, 4], "vocab": 256,
+                "vocab_held": [64, 64], "seq": 32, "rows": 2},
 }
 
 
@@ -861,8 +871,95 @@ def phase_dp4(cfg, seed, rehearse):
     return rec
 
 
+# ---------------------------------------------------------------------------
+# phase: decoder (latent attention + an expert layer that holds a share)
+# ---------------------------------------------------------------------------
+
+def phase_decoder(cfg, seed, rehearse):
+    """``models.latent_moe_lm`` (1 dense + 1 expert layer) trained two
+    steps through Trainer under pure AMP with Adam, its half-layers
+    recomputed; every step's ``Load`` has to count every (token, pick) pair
+    and ``RowsHeld`` the pairs on the held experts."""
+    import math
+
+    import numpy as np
+
+    import paddle_tpu as pt
+    from paddle_tpu import layers
+    from paddle_tpu.models.latent_moe_lm import latent_moe_lm
+
+    dev = _device(rehearse, 1)
+    config = dict(
+        hidden_size=cfg["hidden"], num_attention_heads=cfg["heads"],
+        num_key_value_heads=cfg["heads"], qk_nope_head_dim=cfg["nope"],
+        qk_rope_head_dim=cfg["rope"], qk_head_dim=cfg["nope"] + cfg["rope"],
+        v_head_dim=cfg["v"], kv_lora_rank=cfg["rank"],
+        intermediate_size=cfg["dense"], moe_intermediate_size=cfg["expert"],
+        n_routed_experts=cfg["experts"], num_experts_per_tok=cfg["top_k"],
+        n_shared_experts=2, routed_scaling_factor=2.448,
+        first_k_dense_replace=1, num_hidden_layers=2, rms_norm_eps=1e-6,
+        rope_theta=1e6, vocab_size=cfg["vocab"], experts_held=cfg["held"],
+        vocab_held=cfg["vocab_held"])
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        tokens = layers.data("tokens", shape=[cfg["seq"]], dtype="int64")
+        labels = layers.data("labels", shape=[cfg["seq"]], dtype="int64")
+        out = latent_moe_lm(tokens, config, labels=labels)
+        pt.amp.enable(main, pure=True)
+        trainer = pt.Trainer(
+            cost=out["loss"], optimizer=pt.optimizer.AdamOptimizer(
+                learning_rate=1e-3, beta2=0.95),
+            feed_list=[tokens, labels], fetch_list=out["loads"]
+            + out["rows_held"], place=pt.TPUPlace(0), main_program=main,
+            startup_program=startup)
+        pt.memory_optimize(main)
+    rng = np.random.default_rng(seed)
+    first, count = cfg["vocab_held"]
+
+    def reader():
+        for _ in range(2):
+            ids = rng.integers(first, first + count,
+                               (cfg["rows"], cfg["seq"] + 1), dtype=np.int64)
+            yield [(row[:-1], row[1:]) for row in ids]
+
+    losses, loads, held = [], [], []
+
+    def handler(e):
+        if isinstance(e, pt.trainer_mod.EndIteration):
+            losses.append(float(e.cost))
+            load, rows = (np.asarray(v) for v in e.metrics["fetches"])
+            loads.append(load.tolist())
+            held.append(int(rows.sum()))
+
+    with pt.scope_guard(scope):
+        trainer.train(reader, num_passes=1, event_handler=handler)
+    pairs = cfg["rows"] * cfg["seq"] * cfg["top_k"]
+    lo, n = cfg["held"]
+    _check(len(losses) == 2 and all(math.isfinite(x) for x in losses),
+           "non-finite or missing losses: %r" % losses)
+    _check(all(sum(l) == pairs for l in loads),
+           "Load does not count every (token, pick) pair: %r" % loads)
+    _check(held == [sum(l[lo:lo + n]) for l in loads],
+           "RowsHeld %r is not Load over the held experts %r" % (held, loads))
+    stats = trainer.exe.stats
+    _check(stats["compiles"] == 2 and stats["eager_runs"] == 0,
+           "expected 1 startup + 1 step compile, got %r"
+           % {k: stats[k] for k in ("compiles", "jit_runs", "eager_runs")})
+    rec = {"phase": "decoder", "passed": True, "device": dev,
+           "model": "latent_moe_lm d%d heads %dx(%d+%d/%d) experts %d top %d"
+                    % (cfg["hidden"], cfg["heads"], cfg["nope"], cfg["rope"],
+                       cfg["v"], cfg["experts"], cfg["top_k"]),
+           "losses": [round(x, 5) for x in losses], "loads": loads,
+           "rows_held": held,
+           "moe_max_over_mean_load": layers.moe_load_stats(
+               loads[-1], held[-1])["moe_max_over_mean_load"]}
+    rec.update(_audit("decoder"))
+    return rec
+
+
 PHASES = {"train": phase_train, "serve": phase_serve,
-          "kernels": phase_kernels, "dp4": phase_dp4}
+          "kernels": phase_kernels, "dp4": phase_dp4,
+          "decoder": phase_decoder}
 
 
 # ---------------------------------------------------------------------------

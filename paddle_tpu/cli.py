@@ -864,10 +864,13 @@ def _tune_populations(program, batch, compute_dtype=None):
                 # the op runs at the declared q dtype
                 qv = block._find_var_recursive(op.input("Q")[0])
                 dt = str(getattr(qv, "dtype", "float32") or "float32")
-                add("flash_attention",
-                    {"b": qs[0], "s": qs[1], "h": qs[2], "d": qs[3],
-                     "causal": bool(op.attr("causal", False)),
-                     "dtype": dt})
+                vs = shape_of(block, op.input("V")[0])
+                key = {"b": qs[0], "s": qs[1], "h": qs[2], "d": qs[3],
+                       "causal": bool(op.attr("causal", False)),
+                       "dtype": dt}
+                if vs and len(vs) == 4 and vs[3] != qs[3]:
+                    key["dv"] = vs[3]   # as ops/attention_ops.attention
+                add("flash_attention", key)
             elif op.type == "mul":
                 xs = shape_of(block, op.input("X")[0])
                 ys = shape_of(block, op.input("Y")[0])

@@ -774,6 +774,28 @@ def _step_name(program, feed_template, fetch_names, repeat, dist):
     return "paddle_tpu_step_%08x" % zlib.crc32(content.encode())
 
 
+def _held_step_compiles_and_fits(fn, args, limit):
+    """``_held_step_fits`` of the held step ``fn`` compiled for ``args``
+    (state, feed, key, spare). Two sets of state that alone exceed the
+    device's limit need no compile to say no; and where XLA itself refuses
+    the step for the device's memory (XLA:TPU raises RESOURCE_EXHAUSTED at
+    compile time, it reports no analysis), that is a no as well, not an
+    error: the loop then dispatches from donated state."""
+    if limit is not None:
+        held = sum(int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
+                   for tree in (args[0], args[3])
+                   for v in jax.tree_util.tree_leaves(tree))
+        if held > limit:
+            return False
+    try:
+        mem = fn.memory(*args)
+    except jax.errors.JaxRuntimeError as e:
+        if "RESOURCE_EXHAUSTED" not in str(e):
+            raise
+        return False
+    return _held_step_fits(mem, limit)
+
+
 def _device_bytes_limit(device):
     """The bytes the device says it can hold, None where it does not say
     (the CPU)."""
@@ -1515,8 +1537,8 @@ class Executor(object):
             fn = None if jitted is None else _TracedOnce(
                 jitted, program, dist.num_devices if dist is not None else 1)
             # (the state stands in for the spare set: the same avals)
-            if hold and (fn is None or not _held_step_fits(
-                    fn.memory(state, feed, rng_key, state),
+            if hold and (fn is None or not _held_step_compiles_and_fits(
+                    fn, (state, feed, rng_key, state),
                     _device_bytes_limit(dev if dist is None
                                         else dist.mesh.devices.flat[0]))):
                 self._unheld.add((program._uid, program._version))

@@ -88,7 +88,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, causal, scale, block_k,
 
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)              # [BLOCK_Q, D]
-    bq, d = q.shape
+    bq = q.shape[0]
+    dv = v_ref.shape[-1]                          # v heads may be narrower
     n_k = kv_len // block_k
 
     def body(ki, acc):
@@ -108,7 +109,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, causal, scale, block_k,
     # per-row stats stay [bq, 1] columns (sublane-major, like the score
     # tile's rows) from the loop carry to the lse store
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    num0 = jnp.zeros((bq, d), jnp.float32)
+    num0 = jnp.zeros((bq, dv), jnp.float32)
     den0 = jnp.zeros((bq, 1), jnp.float32)
     if causal and bq == block_k:
         # blocks strictly above the diagonal contribute nothing
@@ -121,7 +122,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, causal, scale, block_k,
 
 def _fa_forward(q3, k3, v3, causal, scale, valid_len, interpret,
                 config=None):
-    """q3 [BH, Sq, D], k3/v3 [BH, Sk, D] -> (o [BH, Sq, D], lse [BH, Sq]).
+    """q3 [BH, Sq, D], k3 [BH, Sk, D], v3 [BH, Sk, Dv] -> (o [BH, Sq, Dv],
+    lse [BH, Sq]): the scores use D, the values and the output Dv (latent
+    attention has 192-wide q/k heads and 128-wide v heads).
     Sq may differ from Sk (ring-attention block chaining); causal requires
     Sq == Sk (aligned positions).
 
@@ -133,7 +136,7 @@ def _fa_forward(q3, k3, v3, causal, scale, valid_len, interpret,
     from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
     BH, Sq, D = q3.shape
-    Sk = k3.shape[1]
+    Sk, Dv = k3.shape[1], v3.shape[2]
     block_q, block_k = _blocks_from_config(config, Sq, Sk)
     kernel = functools.partial(_fa_kernel, causal=causal, scale=scale,
                                block_k=block_k, kv_len=Sk,
@@ -144,14 +147,14 @@ def _fa_forward(q3, k3, v3, causal, scale, valid_len, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Sk, Dv), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
+            jax.ShapeDtypeStruct((BH, Sq, Dv), q3.dtype),
             jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -182,7 +185,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, dl_ref,
 
     ki = pl.program_id(1)
     k_blk = k_ref[0].astype(jnp.float32)          # [BLOCK_K, D]
-    v_blk = v_ref[0].astype(jnp.float32)
+    v_blk = v_ref[0].astype(jnp.float32)          # [BLOCK_K, Dv]
     bk, d = k_blk.shape
     n_q = q_len // block_q
 
@@ -203,7 +206,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, dl_ref,
 
     start = (ki * bk) // block_q if (causal and bk == block_q) else 0
     dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
+    dv0 = jnp.zeros((bk, v_blk.shape[1]), jnp.float32)
     dk, dv = jax.lax.fori_loop(start, n_q, body, (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -216,7 +219,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, dl_ref,
 
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)              # [BLOCK_Q, D]
-    do = do_ref[0].astype(jnp.float32)
+    do = do_ref[0].astype(jnp.float32)            # [BLOCK_Q, Dv]
     lse = l_ref[0]                                # [BLOCK_Q, 1]
     delta = dl_ref[0]
     bq, d = q.shape
@@ -243,7 +246,7 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
     from jax.experimental import pallas as pl
 
     BH, Sq, D = q3.shape
-    Sk = k3.shape[1]
+    Sk, Dv = k3.shape[1], v3.shape[2]
     block_q, block_k = _blocks_from_config(config, Sq, Sk)
     lse = lse[:, :, None]          # [BH, Sq, 1] columns, see _fa_forward
     delta = delta[:, :, None]
@@ -255,18 +258,18 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
         in_specs=[
             pl.BlockSpec((1, Sq, D), lambda b, i: (b, 0, 0)),     # q
             pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),  # k blk
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),  # v blk
-            pl.BlockSpec((1, Sq, D), lambda b, i: (b, 0, 0)),     # do
+            pl.BlockSpec((1, block_k, Dv), lambda b, i: (b, i, 0)),  # v blk
+            pl.BlockSpec((1, Sq, Dv), lambda b, i: (b, 0, 0)),    # do
             pl.BlockSpec((1, Sq, 1), lambda b, i: (b, 0, 0)),     # lse
             pl.BlockSpec((1, Sq, 1), lambda b, i: (b, 0, 0)),     # delta
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sk, D), k3.dtype),
-            jax.ShapeDtypeStruct((BH, Sk, D), v3.dtype),
+            jax.ShapeDtypeStruct((BH, Sk, Dv), v3.dtype),
         ],
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta)
@@ -277,8 +280,8 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),  # q blk
             pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),     # k
-            pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),     # v
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),  # do blk
+            pl.BlockSpec((1, Sk, Dv), lambda b, i: (b, 0, 0)),    # v
+            pl.BlockSpec((1, block_q, Dv), lambda b, i: (b, i, 0)),  # do blk
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),  # lse
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),  # delta
         ],
@@ -294,7 +297,8 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q3, k3, v3, causal, scale, valid_len, config=None):
-    """[BH, S, D] x3 -> (o [BH, S, D], lse [BH, S]); S % block == 0."""
+    """q/k [BH, S, D], v [BH, S, Dv] -> (o [BH, S, Dv], lse [BH, S]);
+    S % block == 0."""
     return _fa_forward(q3, k3, v3, causal, scale, valid_len,
                        interpret=not on_tpu(), config=config)
 
@@ -330,7 +334,9 @@ def _pad_seq(x, S_pad):
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              config=None):
-    """q/k/v: [batch, seq, heads, dim] -> (out [B, S, H, D], lse [B, H, S]).
+    """q/k: [batch, seq, heads, D], v: [batch, seq, heads, Dv] (Dv may
+    differ from D) -> (out [B, S, H, Dv], lse [B, H, S]). ``scale``
+    multiplies the scores; None means D ** -0.5.
 
     Any sequence length: S pads up to the block width internally; padded
     k positions are masked inside the kernels and padded q rows sliced off.
@@ -339,7 +345,7 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     ({block_q, block_k}); None keeps the 128x128 defaults.
     """
     B, S, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[3]
     if causal and S != Sk:
         raise ValueError("causal flash attention needs q/k aligned lengths")
     scale = scale if scale is not None else D ** -0.5
@@ -349,14 +355,15 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     frozen = tuple(sorted(dict(config).items())) if config else None
     q3 = _pad_seq(q, S_pad).transpose(0, 2, 1, 3).reshape(B * H, S_pad, D)
     k3 = _pad_seq(k, Sk_pad).transpose(0, 2, 1, 3).reshape(B * H, Sk_pad, D)
-    v3 = _pad_seq(v, Sk_pad).transpose(0, 2, 1, 3).reshape(B * H, Sk_pad, D)
-    o3, lse = _flash(q3, k3, v3, causal, scale, Sk, frozen)
-    o = o3.reshape(B, H, S_pad, D)[:, :, :S].transpose(0, 2, 1, 3)
+    v3 = _pad_seq(v, Sk_pad).transpose(0, 2, 1, 3).reshape(B * H, Sk_pad, Dv)
+    o3, lse = _flash(q3, k3, v3, causal, float(scale), Sk, frozen)
+    o = o3.reshape(B, H, S_pad, Dv)[:, :, :S].transpose(0, 2, 1, 3)
     return o, lse.reshape(B, H, S_pad)[:, :, :S]
 
 
 def flash_attention(q, k, v, causal=False, scale=None, config=None):
-    """q/k/v: [batch, seq, heads, dim] -> [batch, seq, heads, dim].
+    """q/k: [batch, seq, heads, D], v: [batch, seq, heads, Dv] ->
+    [batch, seq, heads, Dv].
 
     Pallas streamed-softmax forward on TPU (interpret mode elsewhere),
     Pallas recompute backward (dq/dk/dv kernels) — no [S, S] buffer in

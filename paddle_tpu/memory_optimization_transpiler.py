@@ -81,7 +81,10 @@ class ControlFlowGraph(object):
 # NOT worth re-running)
 DEFAULT_REMAT_TYPES = frozenset((
     "conv2d", "depthwise_conv2d", "mul", "matmul", "dynamic_lstm",
-    "dynamic_gru", "sequence_conv", "flash_attention", "mdlstm"))
+    "dynamic_gru", "sequence_conv", "flash_attention", "mdlstm",
+    # whole half-layers of a decoder block (ops/decoder_ops.py): kept as
+    # their [tokens, hidden] input, recomputed in the backward pass
+    "latent_attention", "gated_ffn", "moe_ffn"))
 
 
 def memory_optimize(input_program: ir.Program, print_log=False, level=0,

@@ -16,3 +16,4 @@ from .transformer import (  # noqa: F401
     TransformerConfig, TransformerLM, transformer_lm, transformer_block,
 )
 from .ctr import wide_deep, deepfm, synthetic_click_batch  # noqa: F401
+from .latent_moe_lm import latent_moe_lm  # noqa: F401

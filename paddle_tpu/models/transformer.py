@@ -15,28 +15,20 @@ from __future__ import annotations
 from ..layers import nn as L
 from ..layers import ops as OPS
 from ..layers import tensor as T
-from ..layers.layer_helper import LayerHelper
+from ..layers.decoder import flash_attention
 from ..param_attr import ParamAttr
 
 
-def causal_flash_attention(q, k, v, num_heads):
-    """[B, S, hidden] q/k/v -> [B, S, hidden] via the flash_attention op
-    (causal)."""
-    B_S_H = q.shape
-    hidden = B_S_H[-1]
-    seq = B_S_H[-2]
-    dh = hidden // num_heads
-    qh = L.reshape(q, shape=[0, seq, num_heads, dh])
-    kh = L.reshape(k, shape=[0, seq, num_heads, dh])
-    vh = L.reshape(v, shape=[0, seq, num_heads, dh])
-    helper = LayerHelper("flash_attention")
-    out = helper.create_variable_for_type_inference(dtype=q.dtype)
-    out.shape = qh.shape
-    helper.append_op(type="flash_attention",
-                     inputs={"Q": [qh], "K": [kh], "V": [vh]},
-                     outputs={"Out": [out]},
-                     attrs={"causal": True})
-    return L.reshape(out, shape=[0, seq, hidden])
+def causal_flash_attention(q, k, v, num_heads, scale=None):
+    """[B, S, hidden] q/k and [B, S, v_hidden] v -> [B, S, v_hidden] via
+    the flash_attention op (causal). The v heads may be narrower than the
+    q/k heads; ``scale`` multiplies the scores (None: head size ** -0.5)."""
+    seq = q.shape[-2]
+    heads = lambda x: L.reshape(
+        x, shape=[0, seq, num_heads, x.shape[-1] // num_heads])
+    out = flash_attention(heads(q), heads(k), heads(v), causal=True,
+                          scale=scale)
+    return L.reshape(out, shape=[0, seq, v.shape[-1]])
 
 
 def transformer_block(x, hidden, num_heads, ffn_mult=4, prefix="blk"):

@@ -14,6 +14,7 @@ from . import (  # noqa: F401
     sequence_ops,
     control_flow_ops,
     attention_ops,
+    decoder_ops,
     detection_ops,
     misc_ops,
     channel_ops,

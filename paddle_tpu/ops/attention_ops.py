@@ -12,40 +12,48 @@ lowers through the dense einsum-softmax composition instead.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
-
-from ..core.executor import raw_data, with_lod_of
+from ..core.executor import raw_data
 from ..core.registry import register_op
 from ..kernels import flash_attention as _flash
 from ..kernels.flash_attention import _dense_reference
 
 
-def _dense_attention(q, k, v, causal):
+def _dense_attention(q, k, v, causal, scale):
     B, S, H, D = q.shape
-    t = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    o = _dense_reference(t(q), t(k), t(v), causal, D ** -0.5)
-    return o.reshape(B, H, S, D).transpose(0, 2, 1, 3).astype(q.dtype)
+    Dv = v.shape[3]
+    t = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, S, a.shape[3])
+    o = _dense_reference(t(q), t(k), t(v), causal, scale)
+    return o.reshape(B, H, S, Dv).transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-@register_op("flash_attention")
-def flash_attention_op(ctx):
-    """Q/K/V: [batch, seq, heads, dim] dense tensors."""
+def attention(q, k, v, causal=False, scale=None):
+    """q/k [batch, seq, heads, D], v [batch, seq, heads, Dv] -> [batch, seq,
+    heads, Dv]: the tuned-or-default flash kernel, or the dense composition
+    where a tuned winner says so. The one place an op's lowering reaches
+    the kernel from (``flash_attention``, ``latent_attention``)."""
     from .. import tune
-    q = raw_data(ctx.input("Q"))
-    k = raw_data(ctx.input("K"))
-    v = raw_data(ctx.input("V"))
-    causal = bool(ctx.attr("causal", False))
     B, S, H, D = q.shape
-    cfg = tune.lookup(
-        "flash_attention",
-        {"b": int(B), "s": int(S), "h": int(H), "d": int(D),
-         "causal": causal, "dtype": str(q.dtype)},
-        enabled=True)
+    scale = D ** -0.5 if scale is None else float(scale)
+    key = {"b": int(B), "s": int(S), "h": int(H), "d": int(D),
+           "causal": bool(causal), "dtype": str(q.dtype)}
+    if v.shape[3] != D:
+        # only where it differs: a winner cached for equal head sizes
+        # before the key had ``dv`` is still found
+        key["dv"] = int(v.shape[3])
+    cfg = tune.lookup("flash_attention", key, enabled=True)
     if cfg is None:
         # a tuned winner decided the dense lowering beats the streamed
         # kernel for this (device, shape) — e.g. short sequences where
         # the [S, S] tile fits VMEM anyway
-        out = _dense_attention(q, k, v, causal)
-    else:
-        out = _flash(q, k, v, causal=causal, config=cfg or None)
-    ctx.set_output("Out", out)
+        return _dense_attention(q, k, v, causal, scale)
+    return _flash(q, k, v, causal=causal, scale=scale, config=cfg or None)
+
+
+@register_op("flash_attention")
+def flash_attention_op(ctx):
+    """Q/K: [batch, seq, heads, D], V: [batch, seq, heads, Dv] dense
+    tensors; attr ``scale`` multiplies the scores (absent: D ** -0.5)."""
+    ctx.set_output("Out", attention(
+        raw_data(ctx.input("Q")), raw_data(ctx.input("K")),
+        raw_data(ctx.input("V")), causal=bool(ctx.attr("causal", False)),
+        scale=ctx.attr("scale", None)))

@@ -1,0 +1,256 @@
+"""Ops of a decoder block with latent attention and sparse experts (the
+DeepSeek-V3 block, arXiv:2412.19437 section 2.1): ``rms_norm``,
+``rotary_embedding``, and the three half-layers ``latent_attention``,
+``gated_ffn`` and ``moe_ffn``.
+
+No 2018 reference equivalent. Each half-layer (pre-norm, the products, the
+residual add) is ONE op whose lowering is a pure jax function, and all three
+are in ``memory_optimize``'s default ``remat_types``: ``generic_grad`` then
+recomputes the half-layer under ``jax.checkpoint`` and a layer keeps its two
+``[tokens, hidden]`` inputs for the backward pass instead of every product's
+operands. Inside a lowering ``profiler.part_scope`` names the parts
+(``proj``, ``rope``, ``attn``, ``route``, ``experts``, ``shared``), and
+``profiler.device_scopes()`` keeps that second level
+(``forward/latent_attention/attn``).
+
+Precision: under AMP the matrix products take bf16 operands and accumulate
+in f32; under pure AMP the residual stream and what a half-layer hands on
+is bf16. RMS statistics, rotary angles, the softmax (inside the attention
+kernel), the router's product, its sigmoid and the pick weights are f32
+always.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..core.executor import raw_data
+from ..core.registry import register_op
+from ..profiler import part_scope
+from .attention_ops import attention
+
+
+def _dtypes(ctx, x):
+    """(operand dtype of the matrix products, dtype of the stream)."""
+    from .. import amp
+    operand = jnp.bfloat16 if amp.active(ctx) else x.dtype
+    stream = jnp.bfloat16 if amp.keep_bf16(ctx, x.dtype) else x.dtype
+    return operand, stream
+
+
+def _mm(a, w, operand):
+    """a [.., K] @ w [K, N] with f32 accumulation; the result stays f32."""
+    return jnp.matmul(a.astype(operand), w.astype(operand),
+                      preferred_element_type=jnp.float32)
+
+
+def rms_norm(x, w, eps, out_dtype=None):
+    """x / sqrt(mean(x^2) + eps) * w over the last axis, statistics f32."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(out_dtype or x.dtype)
+
+
+def rotary(x, theta):
+    """x [B, S, H, R]: the pair (2i, 2i+1) of row s turned by the angle
+    s * theta^(-2i/R) (the interleaved layout; positions 0..S-1)."""
+    B, S, H, R = x.shape
+    inv = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
+    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    xf = x.astype(jnp.float32).reshape(B, S, H, R // 2, 2)
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(B, S, H, R).astype(x.dtype)
+
+
+def gated(h, w_gate, w_up, w_down, operand):
+    """(silu(h W_gate) * (h W_up)) W_down, f32 out."""
+    act = jax.nn.silu(_mm(h, w_gate, operand)) * _mm(h, w_up, operand)
+    return _mm(act, w_down, operand)
+
+
+def _infer_out_like_x(op, block):
+    xv = block._find_var_recursive(op.input("X")[0])
+    slot = "Out" if op.output("Out") else "Y"
+    ov = block._find_var_recursive(op.output(slot)[0])
+    if xv is not None and ov is not None:
+        ov.shape, ov.dtype = xv.shape, xv.dtype
+
+
+@register_op("rms_norm", infer_shape=_infer_out_like_x)
+def rms_norm_op(ctx):
+    x = raw_data(ctx.input("X"))
+    _operand, stream = _dtypes(ctx, x)
+    ctx.set_output("Y", rms_norm(x, raw_data(ctx.input("Scale")),
+                                 ctx.attr("epsilon", 1e-6), stream))
+
+
+@register_op("rotary_embedding", infer_shape=_infer_out_like_x)
+def rotary_embedding_op(ctx):
+    """X [batch, seq, heads, rotary size] -> the same, rows turned by
+    their positions 0..seq-1."""
+    ctx.set_output("Out", rotary(raw_data(ctx.input("X")),
+                                 float(ctx.attr("theta", 10000.0))))
+
+
+@register_op("latent_attention", infer_shape=_infer_out_like_x)
+def latent_attention_op(ctx):
+    """Out = X + W_o attention(...) of RMSNorm(X): queries straight from
+    the stream, keys and values through a ``kv_rank``-wide latent and one
+    rotary key shared by all heads (multi-head latent attention without a
+    query latent)."""
+    x = raw_data(ctx.input("X"))
+    operand, stream = _dtypes(ctx, x)
+    w_in, wq, wkva, w_kv, wkvb, wo = (
+        raw_data(ctx.input(s)) for s in
+        ("NormScale", "WQ", "WKVA", "KVNormScale", "WKVB", "WO"))
+    H, nope, rope, vdim = (int(ctx.attr(a)) for a in
+                           ("heads", "nope_dim", "rope_dim", "v_dim"))
+    rank = int(ctx.attr("kv_rank"))
+    eps, theta = ctx.attr("epsilon"), float(ctx.attr("theta"))
+    B, S, _d = x.shape
+    x = x.astype(stream)
+    with part_scope("proj"):
+        h = rms_norm(x, w_in, eps)
+        q = _mm(h, wq, operand).astype(stream).reshape(B, S, H, nope + rope)
+        c = _mm(h, wkva, operand).astype(stream)
+        kv = _mm(rms_norm(c[..., :rank], w_kv, eps), wkvb, operand)
+        kv = kv.astype(stream).reshape(B, S, H, nope + vdim)
+    with part_scope("rope"):
+        q = jnp.concatenate(
+            [q[..., :nope], rotary(q[..., nope:], theta)], axis=-1)
+        k_rope = rotary(c[:, :, None, rank:], theta)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, H, rope))],
+            axis=-1)
+    with part_scope("attn"):
+        o = attention(q, k, kv[..., nope:], causal=True,
+                      scale=(nope + rope) ** -0.5)
+    with part_scope("proj"):
+        a = _mm(o.reshape(B, S, H * vdim), wo, operand)
+        ctx.set_output("Out", (x.astype(jnp.float32) + a).astype(stream))
+
+
+@register_op("gated_ffn", infer_shape=_infer_out_like_x)
+def gated_ffn_op(ctx):
+    """Out = X + (silu(h W_gate) * (h W_up)) W_down, h = RMSNorm(X)."""
+    x = raw_data(ctx.input("X"))
+    operand, stream = _dtypes(ctx, x)
+    x = x.astype(stream)
+    h = rms_norm(x, raw_data(ctx.input("NormScale")), ctx.attr("epsilon"))
+    y = gated(h, raw_data(ctx.input("WGate")), raw_data(ctx.input("WUp")),
+              raw_data(ctx.input("WDown")), operand)
+    ctx.set_output("Out", (x.astype(jnp.float32) + y).astype(stream))
+
+
+def route(h, w_router, bias, top_k, scaling):
+    """Sigmoid scores over ALL experts, f32. The picks are the ``top_k``
+    largest of score + bias (ties: the lower index); their weights are the
+    scores WITHOUT the bias, normalised over the picks and scaled.
+    Returns (idx [T, k] int32, g [T, k] f32)."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _top, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], top_k)
+    g = jnp.take_along_axis(s, idx, axis=1)
+    g = g / (jnp.sum(g, axis=1, keepdims=True) + 1e-20) * scaling
+    return idx, g
+
+
+def _dispatch_combine(k):
+    """The two row movements of an expert layer over the T*k (token, pick)
+    pairs sorted by expert, each a gather in both directions: ``order`` is
+    a permutation of the pairs and ``inv`` its inverse, which autodiff
+    cannot know (it would scatter-add). ``live`` marks the sorted rows that
+    belong to a held expert: the grouped products neither read nor write
+    the others, so what comes back for them is dropped, not summed."""
+
+    @jax.custom_vjp
+    def dispatch(h, order, inv, live):      # [T, d] -> [T*k, d], sorted
+        return h[order // k]
+
+    def dispatch_fwd(h, order, inv, live):
+        return dispatch(h, order, inv, live), (inv, live)
+
+    def dispatch_bwd(res, g):
+        inv, live = res
+        g = jnp.where(live[:, None], g, 0)
+        back = g[inv].reshape(-1, k, g.shape[1]).astype(jnp.float32)
+        return back.sum(axis=1).astype(g.dtype), None, None, None
+
+    dispatch.defvjp(dispatch_fwd, dispatch_bwd)
+
+    @jax.custom_vjp
+    def combine(ys, order, inv):            # sorted [T*k, d] -> [T, k, d]
+        return ys[inv].reshape(-1, k, ys.shape[1])
+
+    def combine_fwd(ys, order, inv):
+        return combine(ys, order, inv), (order, inv)
+
+    def combine_bwd(res, g):
+        order, _inv = res
+        return g.reshape(-1, g.shape[2])[order], None, None
+
+    combine.defvjp(combine_fwd, combine_bwd)
+    return dispatch, combine
+
+
+@register_op("moe_ffn", infer_shape=_infer_out_like_x)
+def moe_ffn_op(ctx):
+    """Out = X + shared(h) + sum over the picks that fall on a HELD expert
+    of g_k E_k(h), h = RMSNorm(X). The router scores all ``n_experts``;
+    this op holds the experts ``[first, first + count)`` (stacked weights
+    ``[count, ...]``) and leaves out what the others would add: g stays
+    normalised over all the picks, no token is dropped, no capacity is
+    set. The (token, pick) pairs are sorted by expert and the held ones
+    go through grouped products (``jax.lax.ragged_dot``).
+    ``Load`` int32[n_experts]: picks per expert this step; ``RowsHeld``
+    int32[1]: pairs that fell on held experts."""
+    x = raw_data(ctx.input("X"))
+    operand, stream = _dtypes(ctx, x)
+    w_post, w_router, bias, eg, eu, ed, sg, su, sd = (
+        raw_data(ctx.input(s)) for s in
+        ("NormScale", "WRouter", "RouterBias", "ExpertGate", "ExpertUp",
+         "ExpertDown", "SharedGate", "SharedUp", "SharedDown"))
+    k, first = int(ctx.attr("top_k")), int(ctx.attr("first_expert"))
+    count, n_experts = eg.shape[0], w_router.shape[1]
+    B, S, d = x.shape
+    T = B * S
+    x = x.astype(stream)
+    h = rms_norm(x, w_post, ctx.attr("epsilon")).reshape(T, d)
+    dispatch, combine = _dispatch_combine(k)
+    with part_scope("route"):
+        idx, g = route(h, w_router, bias, k, ctx.attr("scaling"))
+        flat = idx.reshape(T * k)
+        load = jnp.sum(flat[:, None] == jnp.arange(n_experts)[None, :],
+                       axis=0, dtype=jnp.int32)
+        sizes = load[first:first + count]
+        held = (flat >= first) & (flat < first + count)
+        order = jnp.argsort(jnp.where(held, flat - first, count),
+                            stable=True)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=order.dtype))
+        live = held[order]
+        xs = dispatch(h, order, inv, live)
+    # (XLA:TPU makes ``ragged-dot-*`` kernels of the ragged dots under an
+    # ``op_name`` of its own: the scope table calls those unscoped)
+    with part_scope("experts"):
+        # rows past the held pairs belong to no group and a grouped product
+        # leaves there whatever the buffer held (NaN on the chip): every
+        # result is SELECTED by ``live`` before anything multiplies it, so
+        # that neither the values nor their gradients meet the garbage
+        rd = lambda a, w: jnp.where(live[:, None], jax.lax.ragged_dot(
+            a.astype(operand), w.astype(operand), sizes,
+            preferred_element_type=jnp.float32), 0.0)
+        ys = rd(jax.nn.silu(rd(xs, eg)) * rd(xs, eu), ed)
+    with part_scope("route"):
+        ys = (ys * g.reshape(T * k)[order][:, None]).astype(stream)
+        routed = combine(ys, order, inv).astype(jnp.float32).sum(axis=1)
+    with part_scope("shared"):
+        shared = gated(h, sg, su, sd, operand)
+    out = x.astype(jnp.float32) + (shared + routed).reshape(B, S, d)
+    ctx.set_output("Out", out.astype(stream))
+    ctx.set_output("Load", load)
+    ctx.set_output("RowsHeld", jnp.sum(sizes).reshape(1))

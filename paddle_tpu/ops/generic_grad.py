@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core import registry
 from ..core.executor import FunctionalContext, raw_data
@@ -97,8 +98,12 @@ def generic_grad(ctx):
                 g = raw_data(ctx.env[gn])
                 cots.append(jnp.asarray(g, outs[k].dtype)
                             .reshape(outs[k].shape))
-            else:
+            elif jnp.issubdtype(outs[k].dtype, jnp.inexact):
                 cots.append(jnp.zeros_like(outs[k]))
+            else:
+                # a counter beside the op's result (an integer output):
+                # its cotangent has jax's own zero type
+                cots.append(np.zeros(outs[k].shape, jax.dtypes.float0))
             k += 1
     gins = vjp(tuple(cots))
 

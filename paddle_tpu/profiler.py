@@ -29,7 +29,7 @@ __all__ = ["timer", "stat_summary", "print_stats", "reset_stats",
            "start_profiler", "stop_profiler", "reset_profiler", "profiler",
            "cuda_profiler", "xla_trace", "profiler_enabled", "record_run",
            "record_op_event", "record_program_analysis", "write_timeline",
-           "update_pipeline_counters", "pipeline_counters",
+           "update_pipeline_counters", "pipeline_counters", "part_scope",
            "reset_pipeline_counters",
            "update_serving_counters", "serving_counters",
            "reset_serving_counters",
@@ -559,19 +559,44 @@ def get_program_analysis(label):
     return _program_analyses.get(label)
 
 
-_SCOPE_RE = re.compile(r"(?:^|/)((?:forward|backward|update)/[^/\"]+)")
+_SCOPE_RE = re.compile(r"(?:^|/)((?:forward|backward|update)/[^/\"]+)(.*)")
 _INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 UNSCOPED = "unscoped"
+_PARTS = set()      # names ``part_scope`` has opened in this process
+
+
+def part_scope(name):
+    """A named part INSIDE one op's lowering (``attn`` of
+    ``latent_attention``): ``device_scopes()`` keeps it as a second level,
+    ``forward/latent_attention/attn``. For ops that span several kernels;
+    the name is a plain word, the same in forward and backward."""
+    _PARTS.add(name)
+    return jax.named_scope(name)
+
+
+def _part_of(rest):
+    """The first ``part_scope`` name in what follows ``<phase>/<op>`` in an
+    ``op_name``; autodiff wraps the components (``jvp(attn)``,
+    ``transpose(jvp(attn))``, ``checkpoint``), so each is searched for a
+    whole word."""
+    for comp in rest.split("/"):
+        for word in re.findall(r"[A-Za-z_][\w.\-]*", comp):
+            if word in _PARTS:
+                return word
+    return None
 
 
 def scopes_of_module(text):
     """(module name, {instruction name: scope}) from a compiled module's
     text. The scope is the outermost ``<phase>/<op>`` that
-    ``executor.trace_ops`` opened around the op's lowering, read from the
+    ``executor.trace_ops`` opened around the op's lowering, with the
+    ``part_scope`` the lowering itself opened inside it where there is one
+    (``<phase>/<op>/<part>``), read from the
     instruction's own ``op_name`` (a fusion takes the fusion
     instruction's); an instruction the compiler made with no such
-    ``op_name`` (copies, async starts) is ``unscoped``, never guessed.
+    ``op_name`` (copies, async starts; the ``ragged-dot-*`` kernels XLA:TPU
+    makes from ``jax.lax.ragged_dot``) is ``unscoped``, never guessed.
     Every computation of the module is read: a ``while`` body's
     instructions run as device ops of their own."""
     module, table = None, {}
@@ -584,7 +609,12 @@ def scopes_of_module(text):
             continue
         op_name = _OP_NAME_RE.search(line)
         scope = _SCOPE_RE.search(op_name.group(1)) if op_name else None
-        table[m.group(1)] = scope.group(1) if scope else UNSCOPED
+        if scope is None:
+            table[m.group(1)] = UNSCOPED
+            continue
+        part = _part_of(scope.group(2)) if _PARTS else None
+        table[m.group(1)] = (scope.group(1) + "/" + part if part
+                             else scope.group(1))
     return module, table
 
 

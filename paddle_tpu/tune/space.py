@@ -188,7 +188,8 @@ class Conv3x3Space(KernelSpace):
 class FlashAttentionSpace(KernelSpace):
     """Block space of kernels/flash_attention.py.
 
-    key: {b, s, h, d, causal, dtype}. The padded sequence rounds up to
+    key: {b, s, h, d, causal, dtype}, and ``dv`` where the v heads' width
+    differs from ``d``. The padded sequence rounds up to
     the block width, so every block size divides by construction; the
     constraints are alignment and the VMEM residency of the streamed
     k/v plus the [block_q, block_k] score tile."""
@@ -217,9 +218,10 @@ class FlashAttentionSpace(KernelSpace):
         bq, bk = int(config["block_q"]), int(config["block_k"])
         s = max(key["s"], bk)
         d = key["d"]
+        dv = key.get("dv", d)
         q_tile = bq * d * it
-        kv = 2 * s * d * it           # k and v stay resident per q block
-        o_tile = bq * d * it
+        kv = s * (d + dv) * it        # k and v stay resident per q block
+        o_tile = bq * dv * it
         score = bq * bk * 4           # f32 score/prob tile
         stats = 3 * bq * 4            # m / num-row / den rows
         return 2 * (q_tile + o_tile) + kv + score + stats
@@ -230,7 +232,8 @@ class FlashAttentionSpace(KernelSpace):
         shape = (key["b"], key["s"], key["h"], key["d"])
         q = jnp.asarray(rng.randn(*shape), key["dtype"])
         k = jnp.asarray(rng.randn(*shape), key["dtype"])
-        v = jnp.asarray(rng.randn(*shape), key["dtype"])
+        v = jnp.asarray(rng.randn(*shape[:3], key.get("dv", key["d"])),
+                        key["dtype"])
         return (q, k, v)
 
     def build(self, config, key):
@@ -253,9 +256,10 @@ class FlashAttentionSpace(KernelSpace):
         @jax.jit
         def fn(q, k, v):
             B, S, H, D = q.shape
-            t = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+            t = lambda a: a.transpose(0, 2, 1, 3).reshape(
+                B * H, S, a.shape[3])
             o = _dense_reference(t(q), t(k), t(v), causal, D ** -0.5)
-            return o.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+            return o.reshape(B, H, S, v.shape[3]).transpose(0, 2, 1, 3)
 
         return fn
 

@@ -85,6 +85,35 @@ def test_flash_attention_bwd_compiles(one_chip, shape, dtype):
     assert text.count("tpu_custom_call") >= 2
 
 
+# latent attention's heads: q/k 192 wide (128 + 64 rotary), v 128, at the
+# benchmark cell's own shape (2 rows x 32 heads, 4,096 tokens), and the
+# 24 + 8 / 16 heads of the small tests
+LATENT_SHAPES = [(64, 4096, 192, 128, jnp.bfloat16),
+                 (8, 256, 32, 16, jnp.float32)]
+
+
+@pytest.mark.parametrize("bh,s,d,dv,dtype", LATENT_SHAPES)
+def test_flash_attention_narrower_v_heads_compile(one_chip, bh, s, d, dv,
+                                                  dtype):
+    """Forward and both backward kernels with Dv != D: the scores use D,
+    ``num`` / ``o`` / ``dV`` are [block, Dv]; nothing is padded to D."""
+    fa = _kernel_module("flash_attention")
+
+    def fwd(q, k, v):
+        return fa._fa_forward(q, k, v, True, d ** -0.5, s, interpret=False)
+
+    def bwd(q, k, v, do, lse, delta):
+        return fa._fa_backward(q, k, v, do, lse, delta, True, d ** -0.5, s,
+                               interpret=False)
+
+    qk, vv, row = ((bh, s, d), dtype), ((bh, s, dv), dtype), \
+        ((bh, s), jnp.float32)
+    text = _compile(fwd, one_chip, qk, qk, vv)
+    assert "%d,%d,%d" % (bh, s, dv) in text.replace(" ", "")
+    text = _compile(bwd, one_chip, qk, qk, vv, vv, row, row)
+    assert text.count("tpu_custom_call") >= 2
+
+
 @pytest.mark.parametrize("kernel", ["lstm", "gru"])
 def test_fused_rnn_compiles(one_chip, kernel):
     """The stacked-LSTM cell shape: T100, N64, D512, f32."""

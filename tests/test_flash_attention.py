@@ -164,3 +164,70 @@ def test_flash_lse_merge_matches_full():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-4)
+
+
+# -- v heads narrower than q/k heads (latent attention), explicit scale ------
+
+def _dense_dv(q, k, v, causal, scale):
+    """The dense composition for q/k [B, S, H, D], v [B, S, H, Dv]."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    if causal:
+        S = q.shape[1]
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s,
+                      -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _qkv_dv(seed, S, D, Dv):
+    rng = np.random.RandomState(seed)
+    B, H = 1, 2
+    return (jnp.asarray(rng.randn(B, S, H, D), jnp.float32),
+            jnp.asarray(rng.randn(B, S, H, D), jnp.float32),
+            jnp.asarray(rng.randn(B, S, H, Dv), jnp.float32))
+
+
+@pytest.mark.parametrize("D,Dv,S", [(192, 128, 256), (24, 16, 96)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_with_narrower_v_heads_matches_dense(D, Dv, S, causal):
+    q, k, v = _qkv_dv(7, S, D, Dv)
+    scale = 0.37 * D ** -0.5            # given, not the default
+    out = flash_attention(q, k, v, causal=causal, scale=scale)
+    assert out.shape == (1, S, 2, Dv)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_dense_dv(q, k, v, causal, scale)),
+        rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("D,Dv,S", [(192, 128, 128), (24, 16, 96)])
+def test_flash_grads_with_narrower_v_heads_match_dense(D, Dv, S):
+    q, k, v = _qkv_dv(8, S, D, Dv)
+    scale = D ** -0.5
+    w = jnp.asarray(np.random.RandomState(9).randn(1, S, 2, Dv), jnp.float32)
+    g1 = jax.grad(lambda q, k, v: jnp.sum(w * flash_attention(
+        q, k, v, causal=True, scale=scale)), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(lambda q, k, v: jnp.sum(w * _dense_dv(
+        q, k, v, True, scale)), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g1, g2):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3,
+                                   atol=1e-4, err_msg="d" + name)
+
+
+def test_flash_attention_layer_passes_scale_and_head_sizes():
+    """``layers.flash_attention`` (the helper ``models.transformer`` and the
+    latent block share) hands the op its scale; the output takes v's head
+    size."""
+    import paddle_tpu as fluid
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        q = fluid.layers.data("q", shape=[64, 2, 24], dtype="float32")
+        v = fluid.layers.data("v", shape=[64, 2, 16], dtype="float32")
+        out = fluid.layers.flash_attention(q, q, v, causal=True, scale=0.11)
+    assert tuple(out.shape[1:]) == (64, 2, 16)
+    qv, _k, vv = _qkv_dv(10, 64, 24, 16)
+    got, = fluid.Executor(fluid.CPUPlace()).run(
+        main, feed={"q": np.asarray(qv), "v": np.asarray(vv)},
+        fetch_list=[out])
+    np.testing.assert_allclose(
+        got, np.asarray(_dense_dv(qv, qv, vv, True, 0.11)), rtol=2e-4,
+        atol=2e-5)

@@ -546,6 +546,52 @@ def test_where_two_steps_do_not_fit_the_loop_runs_todays_order_and_donates(
         np.testing.assert_array_equal(value, by_hand[1][-1][name])
 
 
+class _Held(object):
+    """A held step whose compile the device's compiler refuses."""
+
+    def __init__(self, error=None):
+        self.error, self.compiles = error, 0
+
+    def memory(self, *args):
+        self.compiles += 1
+        if self.error is not None:
+            raise self.error
+        return _Mem()
+
+
+def _args(n):
+    state = {"w": np.zeros((n,), np.float32)}
+    return (state, {"x": np.zeros((2,), np.float32)}, None, state)
+
+
+@pytest.mark.parametrize("case,fits,compiles", [
+    ("two_sets_exceed_the_limit", False, 0),
+    ("the_compiler_runs_out_of_device_memory", False, 1),
+    ("it_fits", True, 1)])
+def test_a_held_step_the_device_cannot_hold_is_a_no_not_an_error(
+        case, fits, compiles):
+    """XLA:TPU raises RESOURCE_EXHAUSTED at COMPILE time for a step that
+    exceeds the device's memory (it reports no analysis): the loop then
+    dispatches from donated state, it does not die. Two sets of state that
+    alone exceed the limit are not compiled at all."""
+    import jax
+    oom = jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 16.80G of 15.75G hbm.")
+    held = _Held(oom if case.startswith("the_compiler") else None)
+    n = 1000 if case.startswith("two_sets") else 10
+    assert executor_mod._held_step_compiles_and_fits(
+        held, _args(n), 2000) is fits
+    assert held.compiles == compiles
+
+
+def test_another_compile_error_of_the_held_step_is_raised():
+    import jax
+    held = _Held(jax.errors.JaxRuntimeError("INTERNAL: something else"))
+    with pytest.raises(jax.errors.JaxRuntimeError, match="something else"):
+        executor_mod._held_step_compiles_and_fits(held, _args(10), 2000)
+
+
 def test_a_limit_with_room_for_two_steps_dispatches_ahead(monkeypatch):
     monkeypatch.setattr(executor_mod, "_device_bytes_limit",
                         lambda d: 1 << 40)
