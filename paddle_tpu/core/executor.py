@@ -895,7 +895,9 @@ class Executor(object):
         # kernel-dispatch counters (hits = cached winner applied, misses
         # = kernel default config, fallbacks = stock XLA); dispatch
         # happens at trace time, so they move once per compile — the
-        # snapshot refreshes at the end of every run()
+        # snapshot refreshes at the end of every run(); flash_blocks is
+        # the same snapshot's tally of the blocks each flash-attention
+        # launch was traced at ({"fwd 512x512": n, ...})
         # comm_path says HOW the last compiled program's DP grads sync:
         # "explicit" = routed through the paddle_tpu.comm collectives
         # (comm_* stats measured from the traced plan), "model" = GSPMD
@@ -914,7 +916,7 @@ class Executor(object):
                       "comm_quant_fallbacks": 0,
                       "comm_path": "",
                       "tune_hits": 0, "tune_misses": 0,
-                      "tune_fallbacks": 0,
+                      "tune_fallbacks": 0, "flash_blocks": {},
                       "elastic_resizes": 0, "elastic_lost_ranks": 0,
                       "elastic_requeued_tasks": 0,
                       "elastic_resume_ms": 0.0,

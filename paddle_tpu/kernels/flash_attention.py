@@ -8,10 +8,27 @@ scheme as two Pallas kernels: a dK/dV kernel (grid over k blocks, loop over
 q blocks) and a dQ kernel (grid over q blocks, loop over k blocks); every
 score/probability tile lives only as a [block_q, block_k] VMEM tile.
 
-Ragged sequence lengths (S % 128 != 0) are handled by padding to the block
-size and masking padded k positions inside the kernels; padded q rows are
-sliced off (and contribute exactly zero to dK/dV because their dO rows are
-zero-padded).
+How the three kernels tile the score matrix is one decision, made per
+call from what the call can observe (``default_blocks``: lengths, head
+widths, dtype, causal, and a reckoning of what each kernel keeps in VMEM,
+``vmem_bytes``); a paddle_tpu.tune winner overrides it. Of a causal call's
+tiles only those the diagonal crosses are masked and those above it are
+never visited, whatever the two block widths; the padded-k mask is applied
+only to the tiles that hold padded positions.
+
+A sequence longer than one 128-wide tile runs at its length rounded up to
+128 (``padded_len``), whatever the blocks: padded k positions are masked
+inside the kernels; padded q rows are sliced off (and contribute exactly
+zero to dK/dV because their dO rows are zero-padded).
+
+The per-row statistics (lse, delta) reach the dK/dV kernel, which holds
+them for the whole sequence, as lane-dense rows ``[BH, Sq / block_q,
+block_q]``: that kernel works on the TRANSPOSED tile (``[block_k,
+block_q]``: k @ q^T), where a q row's statistic is a ``[1, block_q]`` row
+and both accumulations are plain products. As ``[BH, S, 1]`` columns they
+took 512 B a position in VMEM (a (8, 128) f32 tile for 8 numbers): 8 MB
+double-buffered at S = 4,096, half the scoped limit. The forward and dQ
+kernels see one q block of them a grid step and keep the columns.
 
 The logsumexp output is what lets parallel/ring.py chain per-ring-step
 flash calls with the numerically exact merge
@@ -30,24 +47,114 @@ import jax.numpy as jnp
 
 from ..place import on_tpu
 
-BLOCK_Q = 128
-BLOCK_K = 128
+LANE = 128
 NEG_INF = -1e30
+KERNELS = ("fwd", "dq", "dkv")
+BLOCK_WIDTHS = (512, 256, 128)
+# what Mosaic grants one kernel on a v5e unless told otherwise (its
+# "scoped vmem limit"); the reckoning keeps a shape under it
+VMEM_LIMIT = 16 * 1024 * 1024
 
-DEFAULT_CONFIG = {"block_q": BLOCK_Q, "block_k": BLOCK_K}
+_NT = (((1,), (1,)), ((), ()))      # a @ b^T: contract both minor dims
 
 
-def _blocks_from_config(config, Sq, Sk):
-    """Resolve (block_q, block_k) for the call shape: configured blocks
-    (a paddle_tpu.tune "flash_attention" pick) clamp to the sequence
-    lengths and fall back to the 128 defaults when they don't divide the
-    padded sequence — a stale cache entry must degrade, not fail."""
-    cfg = dict(DEFAULT_CONFIG)
-    cfg.update(dict(config) if config else {})
-    bq = min(int(cfg["block_q"]), max(Sq, 1))
-    bk = min(int(cfg["block_k"]), max(Sk, 1))
-    if bq < 1 or bk < 1:
-        bq, bk = min(BLOCK_Q, Sq), min(BLOCK_K, Sk)
+def padded_len(S):
+    """The length the kernels run S positions at: S itself up to one
+    128-wide tile (the block is the whole array), else S rounded up to
+    128. No choice of blocks adds to it."""
+    return S if S <= LANE else -(-S // LANE) * LANE
+
+
+def _block_bytes(rows, cols, itemsize):
+    """VMEM bytes of a [rows, cols] block: lanes round up to 128, rows to
+    the dtype's sublane tile (8 for f32, 16 for bf16)."""
+    sub = 8 * 4 // itemsize
+    return (-(-rows // sub) * sub) * (-(-cols // LANE) * LANE) * itemsize
+
+
+def vmem_bytes(kernel, bq, bk, Sq, Sk, D, Dv, itemsize):
+    """What ``kernel`` ("fwd", "dq" or "dkv") keeps in VMEM at blocks
+    (bq, bk), by the kernel's own BlockSpecs: every operand and result
+    block twice (the pipeline's two buffers; the whole-sequence operands
+    too), the blocks widened to f32, the f32 accumulators, and the
+    [bq, bk] f32 tiles live at once (scores, probabilities and, backward,
+    dP and dS). It errs high: by 0.1-2.9 MiB against what Mosaic needed
+    for a described v5e at the shapes tried, and no shape it grants was
+    refused on the chip (PERF.md §6, PR 33; tests/test_chip_compile.py
+    holds it to Mosaic's own refusals)."""
+    blk = functools.partial(_block_bytes, itemsize=itemsize)
+    f32 = functools.partial(_block_bytes, itemsize=4)
+    if kernel == "fwd":
+        piped = (blk(bq, D) + blk(Sk, D) + blk(Sk, Dv) + blk(bq, Dv)
+                 + f32(bq, 1))
+        wide = f32(bq, D) + f32(bk, D) + f32(bk, Dv) + 2 * f32(bq, Dv)
+        tiles = 3
+    elif kernel == "dq":
+        piped = (2 * blk(bq, D) + blk(Sk, D) + blk(Sk, Dv) + blk(bq, Dv)
+                 + 2 * f32(bq, 1))
+        wide = (2 * f32(bq, D) + f32(bq, Dv) + f32(bk, D) + f32(bk, Dv))
+        tiles = 4
+    elif kernel == "dkv":
+        piped = (blk(Sq, D) + blk(Sq, Dv) + 2 * blk(bk, D)
+                 + 2 * blk(bk, Dv) + 2 * f32(Sq // bq, bq))
+        wide = (f32(bq, D) + f32(bq, Dv) + 2 * f32(bk, D)
+                + 2 * f32(bk, Dv))
+        tiles = 4
+    else:
+        raise ValueError("no flash kernel %r" % (kernel,))
+    return 2 * piped + wide + tiles * f32(bq, bk)
+
+
+def _fits(kernel, bq, bk, Sq, Sk, D, Dv, itemsize):
+    return vmem_bytes(kernel, bq, bk, Sq, Sk, D, Dv, itemsize) <= VMEM_LIMIT
+
+
+def default_blocks(kernel, Sq, Sk, D, Dv, dtype, causal):
+    """(block_q, block_k) of ``kernel`` for a call of padded lengths
+    (Sq, Sk): a pure function of the call's shape, read off the sweep in
+    PERF.md §6 (PR 33). On each side the widest of 512 / 256 / 128 that
+    divides the length (so a block never adds padding; a sequence under
+    128 is one block), at every length and head width the sweep tried,
+    causal or not: the rule has no length threshold. Where ``vmem_bytes``
+    says Mosaic would refuse the pair it falls back a size, narrowing
+    first the side the kernel's grid walks (q for forward and dQ, k for
+    dK/dV) and keeping wide the side its inner loop walks, whose trips
+    are what a tile's fixed cost is paid on."""
+    del causal  # the sweep ranks the shapes alike causal and not
+    itemsize = jnp.dtype(dtype).itemsize
+    q_widths, k_widths = (
+        [w for w in BLOCK_WIDTHS if S % w == 0] or [S] for S in (Sq, Sk))
+    grid_is_q = kernel != "dkv"
+    grid, loop = (q_widths, k_widths) if grid_is_q else (k_widths, q_widths)
+    gi = li = 0
+    while True:
+        pair = (grid[gi], loop[li])
+        bq, bk = pair if grid_is_q else pair[::-1]
+        last_g, last_l = gi == len(grid) - 1, li == len(loop) - 1
+        if (last_g and last_l) or _fits(kernel, bq, bk, Sq, Sk, D, Dv,
+                                        itemsize):
+            return bq, bk
+        if not last_g and (grid[gi] >= loop[li] or last_l):
+            gi += 1
+        else:
+            li += 1
+
+
+def _blocks(kernel, config, q3, k3, v3, causal):
+    """The blocks ``kernel`` runs this call at: a paddle_tpu.tune
+    "flash_attention" winner ({block_q, block_k}, clamped to the lengths)
+    where it divides the padded lengths and fits VMEM, else the rule — a
+    stale or refused cache entry degrades, it never fails. Counts the
+    choice (``tune.counters()["flash_blocks"]``)."""
+    from .. import tune
+    (_, Sq, D), Sk, Dv = q3.shape, k3.shape[1], v3.shape[2]
+    cfg = dict(config) if config else {}
+    bq = min(int(cfg.get("block_q", 0)), Sq)
+    bk = min(int(cfg.get("block_k", 0)), Sk)
+    if bq < 1 or bk < 1 or Sq % bq or Sk % bk or not _fits(
+            kernel, bq, bk, Sq, Sk, D, Dv, q3.dtype.itemsize):
+        bq, bk = default_blocks(kernel, Sq, Sk, D, Dv, q3.dtype, causal)
+    tune.count_flash_blocks(kernel, bq, bk)
     return bq, bk
 
 
@@ -62,59 +169,112 @@ def _dense_reference(q, k, v, causal, scale):
 
 
 # ---------------------------------------------------------------------------
-# forward kernel: one q block vs streamed k/v blocks -> o block + lse rows
+# the score tile and its masks
 
-def _masked_scores(q, k_blk, q_start, k_start, *, causal, scale, valid_len,
-                   kv_len):
-    """Scaled q@k^T tile with the causal and padded-k masks applied — the
-    single source of masking truth shared by forward and both backward
-    kernels (they must never disagree)."""
-    s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
-    bq, bk = s.shape
-    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+
+def _masked_scores(q, k_blk, q_start, k_start, *, scale, causal=False,
+                   valid_len=None, transposed=False):
+    """Scaled score tile q @ k^T ([bq, bk]; ``transposed``: k @ q^T, [bk,
+    bq]) with the causal mask and, where ``valid_len`` is given, the
+    padded-k mask — the single source of masking truth shared by forward
+    and both backward kernels (they must never disagree). A kernel asks
+    for a mask only on the tiles that need it (``_k_segments``,
+    ``_q_segments``)."""
+    a, b = (k_blk, q) if transposed else (q, k_blk)
+    s = jax.lax.dot_general(a, b, _NT,
+                            preferred_element_type=jnp.float32) * scale
+    q_axis, k_axis = (1, 0) if transposed else (0, 1)
+    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32, s.shape)
     if causal:
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
-    if valid_len < kv_len:
-        s = jnp.where(kpos < valid_len, s, NEG_INF)
+        # qpos >= kpos: a loop-invariant difference against one scalar
+        s = jnp.where(iota(q_axis) - iota(k_axis) >= k_start - q_start, s,
+                      NEG_INF)
+    if valid_len is not None:
+        s = jnp.where(iota(k_axis) < valid_len - k_start, s, NEG_INF)
     return s
 
 
+def _k_segments(q_start, bq, bk, kv_len, valid_len, causal):
+    """The k tiles (``bk`` wide) a q block at rows [q_start, q_start + bq)
+    visits (forward, dQ), in order, as (masks, first, end) runs; ``masks``
+    are ``_masked_scores``' keywords for the run's tiles. Causal: the tiles
+    wholly at or under the block's first row need no mask, those the
+    diagonal crosses do, and none beyond the block's last row is visited,
+    whatever the two widths. The tiles that hold padded k positions are
+    masked ones: causal, they are among those the diagonal crosses (q and
+    k share their padding); not causal, they are the last."""
+    n_k = kv_len // bk
+    pad = valid_len if valid_len < kv_len else None
+    if causal:
+        clear = (q_start + 1) // bk
+        return (({}, 0, clear),
+                ({"causal": True, "valid_len": pad}, clear,
+                 -(-(q_start + bq) // bk)))
+    clear = n_k if pad is None else valid_len // bk
+    return ({}, 0, clear), ({"valid_len": pad}, clear, n_k)
+
+
+def _q_segments(k_start, bk, bq, n_q, causal, pad):
+    """The q tiles (``bq`` wide) a k block at positions [k_start, k_start
+    + bk) visits (dK/dV), as ``_k_segments`` gives them: causal, from the
+    q tile that holds its first position, masked up to the first q tile
+    wholly at or under its last position, clear from there to the end.
+    ``pad``: the valid length where the block holds padded positions
+    (every tile it visits then masks them), else None."""
+    first = k_start // bq if causal else 0
+    if pad is not None:
+        return (({"causal": causal, "valid_len": pad}, first, n_q),)
+    clear = -(-(k_start + bk - 1) // bq) if causal else 0
+    return ({"causal": True}, first, clear), ({}, clear, n_q)
+
+
+def _run_tiles(segments, step, acc):
+    """One fori_loop of ``step(masks)`` per run of tiles; a run that is
+    empty at trace time makes none."""
+    for masks, lo, hi in segments:
+        if not (isinstance(lo, int) and isinstance(hi, int) and lo >= hi):
+            acc = jax.lax.fori_loop(lo, hi, step(masks), acc)
+    return acc
+
+
+# ---------------------------------------------------------------------------
+# forward kernel: one q block vs streamed k/v blocks -> o block + lse rows
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, causal, scale, block_k,
                kv_len, valid_len):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)              # [BLOCK_Q, D]
+    q = q_ref[0].astype(jnp.float32)              # [block_q, D]
     bq = q.shape[0]
     dv = v_ref.shape[-1]                          # v heads may be narrower
-    n_k = kv_len // block_k
+    q_start = pl.program_id(1) * bq
 
-    def body(ki, acc):
-        m, num, den = acc
-        k_blk = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = _masked_scores(q, k_blk, qi * bq, ki * block_k, causal=causal,
-                           scale=scale, valid_len=valid_len, kv_len=kv_len)
-        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - new_m)
-        alpha = jnp.exp(m - new_m)
-        num = num * alpha + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32)
-        den = den * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        return new_m, num, den
+    def step(masks):
+        def body(ki, acc):
+            m, num, den = acc
+            k_start = ki * block_k
+            k_blk = k_ref[0, pl.ds(k_start, block_k), :].astype(jnp.float32)
+            v_blk = v_ref[0, pl.ds(k_start, block_k), :].astype(jnp.float32)
+            s = _masked_scores(q, k_blk, q_start, k_start, scale=scale,
+                               **masks)
+            new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - new_m)
+            alpha = jnp.exp(m - new_m)
+            num = num * alpha + jnp.dot(
+                p, v_blk, preferred_element_type=jnp.float32)
+            den = den * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            return new_m, num, den
+        return body
 
     # per-row stats stay [bq, 1] columns (sublane-major, like the score
     # tile's rows) from the loop carry to the lse store
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     num0 = jnp.zeros((bq, dv), jnp.float32)
     den0 = jnp.zeros((bq, 1), jnp.float32)
-    if causal and bq == block_k:
-        # blocks strictly above the diagonal contribute nothing
-        n_k = qi + 1
-    m, num, den = jax.lax.fori_loop(0, n_k, body, (m0, num0, den0))
+    m, num, den = _run_tiles(
+        _k_segments(q_start, bq, block_k, kv_len, valid_len, causal), step,
+        (m0, num0, den0))
     den_safe = jnp.maximum(den, 1e-20)
     o_ref[0] = (num / den_safe).astype(o_ref.dtype)
     l_ref[0] = m + jnp.log(den_safe)
@@ -128,16 +288,15 @@ def _fa_forward(q3, k3, v3, causal, scale, valid_len, interpret,
     Sq may differ from Sk (ring-attention block chaining); causal requires
     Sq == Sk (aligned positions).
 
-    Inside the pallas_calls the per-row operands (lse, delta) ride as
-    [BH, S, 1] columns: Mosaic wants a block's last two dims divisible by
-    (8, 128) or equal to the array's, and (block_q, 1) over [S, 1] is —
-    the natural (1, block_q) block over [BH, S] is not."""
+    The lse leaves the kernel as a [BH, Sq, 1] column: Mosaic wants a
+    block's last two dims divisible by (8, 128) or equal to the array's,
+    and (block_q, 1) over [Sq, 1] is — the kernel's running statistics
+    are [block_q, 1] columns already."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
     BH, Sq, D = q3.shape
     Sk, Dv = k3.shape[1], v3.shape[2]
-    block_q, block_k = _blocks_from_config(config, Sq, Sk)
+    block_q, block_k = _blocks("fwd", config, q3, k3, v3, causal)
     kernel = functools.partial(_fa_kernel, causal=causal, scale=scale,
                                block_k=block_k, kv_len=Sk,
                                valid_len=valid_len)
@@ -167,15 +326,9 @@ def _fa_forward(q3, k3, v3, causal, scale, valid_len, interpret,
 #
 # With p = exp(s - lse):  dv = p^T dO;  dp = dO v^T;
 # ds = p * (dp - delta) * scale where delta = rowsum(dO * o) - dlse;
-# dq = ds k;  dk = ds^T q.  All tiles [block_q, block_k] in VMEM.
-
-
-def _dot_tn(a, b):
-    """a^T @ b as one dot_general contracting the row axis of both — the
-    transposed-lhs form Mosaic feeds the MXU directly (no [bk, bq]
-    transpose materialised in VMEM)."""
-    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+# dq = ds k;  dk = ds^T q.  All tiles [block_q, block_k] in VMEM; the
+# dK/dV kernel holds their transposes, so p^T dO and ds^T q are plain
+# products and lse / delta are [1, block_q] rows.
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, dl_ref,
@@ -183,31 +336,43 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, dl_ref,
                        q_len, kv_len, valid_len):
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    k_blk = k_ref[0].astype(jnp.float32)          # [BLOCK_K, D]
-    v_blk = v_ref[0].astype(jnp.float32)          # [BLOCK_K, Dv]
+    k_blk = k_ref[0].astype(jnp.float32)          # [block_k, D]
+    v_blk = v_ref[0].astype(jnp.float32)          # [block_k, Dv]
     bk, d = k_blk.shape
+    k_start = pl.program_id(1) * bk
     n_q = q_len // block_q
 
-    def body(qi, acc):
-        dk, dv = acc
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = l_ref[0, pl.ds(qi * block_q, block_q), :]       # [bq, 1]
-        delta = dl_ref[0, pl.ds(qi * block_q, block_q), :]
-        s = _masked_scores(q, k_blk, qi * block_q, ki * bk, causal=causal,
-                           scale=scale, valid_len=valid_len, kv_len=kv_len)
-        p = jnp.exp(s - lse)
-        dv = dv + _dot_tn(p, do)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk = dk + _dot_tn(ds, q)
-        return dk, dv
+    def step(masks):
+        def body(qi, acc):
+            dk, dv = acc
+            q_start = qi * block_q
+            q = q_ref[0, pl.ds(q_start, block_q), :].astype(jnp.float32)
+            do = do_ref[0, pl.ds(q_start, block_q), :].astype(jnp.float32)
+            lse = l_ref[0, pl.ds(qi, 1), :]                   # [1, bq]
+            delta = dl_ref[0, pl.ds(qi, 1), :]
+            st = _masked_scores(q, k_blk, q_start, k_start, scale=scale,
+                                transposed=True, **masks)     # [bk, bq]
+            pt = jnp.exp(st - lse)
+            dv = dv + jnp.dot(pt, do, preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(v_blk, do, _NT,
+                                      preferred_element_type=jnp.float32)
+            dst = pt * (dpt - delta) * scale
+            dk = dk + jnp.dot(dst, q, preferred_element_type=jnp.float32)
+            return dk, dv
+        return body
 
-    start = (ki * bk) // block_q if (causal and bk == block_q) else 0
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, v_blk.shape[1]), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start, n_q, body, (dk0, dv0))
+    acc0 = (jnp.zeros((bk, d), jnp.float32),
+            jnp.zeros((bk, v_blk.shape[1]), jnp.float32))
+
+    def run(pad):
+        return _run_tiles(
+            _q_segments(k_start, bk, block_q, n_q, causal, pad), step, acc0)
+
+    if valid_len < kv_len:
+        dk, dv = jax.lax.cond(k_start + bk > valid_len,
+                              lambda: run(valid_len), lambda: run(None))
+    else:
+        dk, dv = run(None)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -217,39 +382,46 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, dl_ref,
                       valid_len):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)              # [BLOCK_Q, D]
-    do = do_ref[0].astype(jnp.float32)            # [BLOCK_Q, Dv]
-    lse = l_ref[0]                                # [BLOCK_Q, 1]
+    q = q_ref[0].astype(jnp.float32)              # [block_q, D]
+    do = do_ref[0].astype(jnp.float32)            # [block_q, Dv]
+    lse = l_ref[0]                                # [block_q, 1]
     delta = dl_ref[0]
     bq, d = q.shape
-    n_k = kv_len // block_k
+    q_start = pl.program_id(1) * bq
 
-    def body(ki, dq):
-        k_blk = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = _masked_scores(q, k_blk, qi * bq, ki * block_k, causal=causal,
-                           scale=scale, valid_len=valid_len, kv_len=kv_len)
-        p = jnp.exp(s - lse)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
+    def step(masks):
+        def body(ki, dq):
+            k_start = ki * block_k
+            k_blk = k_ref[0, pl.ds(k_start, block_k), :].astype(jnp.float32)
+            v_blk = v_ref[0, pl.ds(k_start, block_k), :].astype(jnp.float32)
+            s = _masked_scores(q, k_blk, q_start, k_start, scale=scale,
+                               **masks)
+            p = jnp.exp(s - lse)
+            dp = jax.lax.dot_general(do, v_blk, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * scale
+            return dq + jnp.dot(ds, k_blk,
+                                preferred_element_type=jnp.float32)
+        return body
 
-    if causal and bq == block_k:
-        n_k = qi + 1
-    dq = jax.lax.fori_loop(0, n_k, body, jnp.zeros((bq, d), jnp.float32))
+    dq = _run_tiles(
+        _k_segments(q_start, bq, block_k, kv_len, valid_len, causal), step,
+        jnp.zeros((bq, d), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
                  interpret, config=None):
+    """lse / delta [BH, Sq] -> (dq, dk, dv). Each kernel tiles by its own
+    blocks; the dK/dV kernel takes the statistics as rows of its block_q,
+    the dQ kernel as columns (module text)."""
     from jax.experimental import pallas as pl
 
     BH, Sq, D = q3.shape
     Sk, Dv = k3.shape[1], v3.shape[2]
-    block_q, block_k = _blocks_from_config(config, Sq, Sk)
-    lse = lse[:, :, None]          # [BH, Sq, 1] columns, see _fa_forward
-    delta = delta[:, :, None]
+    block_q, block_k = _blocks("dkv", config, q3, k3, v3, causal)
+    rows = (BH, Sq // block_q, block_q)
+    row_spec = pl.BlockSpec((1,) + rows[1:], lambda b, i: (b, 0, 0))
     dkv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, causal=causal, scale=scale,
                           block_q=block_q, q_len=Sq, kv_len=Sk,
@@ -260,8 +432,8 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
             pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),  # k blk
             pl.BlockSpec((1, block_k, Dv), lambda b, i: (b, i, 0)),  # v blk
             pl.BlockSpec((1, Sq, Dv), lambda b, i: (b, 0, 0)),    # do
-            pl.BlockSpec((1, Sq, 1), lambda b, i: (b, 0, 0)),     # lse
-            pl.BlockSpec((1, Sq, 1), lambda b, i: (b, 0, 0)),     # delta
+            row_spec,                                             # lse
+            row_spec,                                             # delta
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
@@ -272,7 +444,9 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
             jax.ShapeDtypeStruct((BH, Sk, Dv), v3.dtype),
         ],
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
+    )(q3, k3, v3, do3, lse.reshape(rows), delta.reshape(rows))
+    block_q, block_k = _blocks("dq", config, q3, k3, v3, causal)
+    col_spec = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, causal=causal, scale=scale,
                           block_k=block_k, kv_len=Sk, valid_len=valid_len),
@@ -282,13 +456,13 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
             pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),     # k
             pl.BlockSpec((1, Sk, Dv), lambda b, i: (b, 0, 0)),    # v
             pl.BlockSpec((1, block_q, Dv), lambda b, i: (b, i, 0)),  # do blk
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),  # lse
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),  # delta
+            col_spec,                                             # lse
+            col_spec,                                             # delta
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
+    )(q3, k3, v3, do3, lse[:, :, None], delta[:, :, None])
     return dq, dkv[0], dkv[1]
 
 
@@ -298,7 +472,7 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q3, k3, v3, causal, scale, valid_len, config=None):
     """q/k [BH, S, D], v [BH, S, Dv] -> (o [BH, S, Dv], lse [BH, S]);
-    S % block == 0."""
+    S = padded_len(the call's length)."""
     return _fa_forward(q3, k3, v3, causal, scale, valid_len,
                        interpret=not on_tpu(), config=config)
 
@@ -338,20 +512,18 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     differ from D) -> (out [B, S, H, Dv], lse [B, H, S]). ``scale``
     multiplies the scores; None means D ** -0.5.
 
-    Any sequence length: S pads up to the block width internally; padded
+    Any sequence length: S pads up to ``padded_len(S)`` internally; padded
     k positions are masked inside the kernels and padded q rows sliced off.
     The lse output makes per-block results mergeable (ring attention).
     ``config`` is a paddle_tpu.tune "flash_attention" pick
-    ({block_q, block_k}); None keeps the 128x128 defaults.
+    ({block_q, block_k}); None leaves the blocks to ``default_blocks``.
     """
     B, S, H, D = q.shape
     Sk, Dv = k.shape[1], v.shape[3]
     if causal and S != Sk:
         raise ValueError("causal flash attention needs q/k aligned lengths")
     scale = scale if scale is not None else D ** -0.5
-    bq, bk = _blocks_from_config(config, S, Sk)
-    S_pad = ((S + bq - 1) // bq) * bq
-    Sk_pad = ((Sk + bk - 1) // bk) * bk
+    S_pad, Sk_pad = padded_len(S), padded_len(Sk)
     frozen = tuple(sorted(dict(config).items())) if config else None
     q3 = _pad_seq(q, S_pad).transpose(0, 2, 1, 3).reshape(B * H, S_pad, D)
     k3 = _pad_seq(k, Sk_pad).transpose(0, 2, 1, 3).reshape(B * H, Sk_pad, D)

@@ -5,10 +5,11 @@ give the layers DSL a fused attention primitive the transformer-era models
 use, with the Pallas kernel on TPU and dense fallback elsewhere.
 
 Block sizes route through paddle_tpu.tune: a cached per-(device, shape)
-winner runs the kernel with the winning {block_q, block_k}; a miss runs
-the 128x128 default (the flash kernel IS this op's default lowering, so
-the site is always 'enabled'); a winner that says stock XLA is fastest
-lowers through the dense einsum-softmax composition instead.
+winner runs the kernel with the winning {block_q, block_k}; a miss leaves
+the blocks to the kernel's own rule for the call's shape
+(``flash_attention.default_blocks``; the flash kernel IS this op's default
+lowering, so the site is always 'enabled'); a winner that says stock XLA
+is fastest lowers through the dense einsum-softmax composition instead.
 """
 from __future__ import annotations
 
@@ -28,9 +29,10 @@ def _dense_attention(q, k, v, causal, scale):
 
 def attention(q, k, v, causal=False, scale=None):
     """q/k [batch, seq, heads, D], v [batch, seq, heads, Dv] -> [batch, seq,
-    heads, Dv]: the tuned-or-default flash kernel, or the dense composition
-    where a tuned winner says so. The one place an op's lowering reaches
-    the kernel from (``flash_attention``, ``latent_attention``)."""
+    heads, Dv]: the flash kernel at a tuned winner's blocks or at its own
+    rule's, or the dense composition where a tuned winner says so. The one
+    place an op's lowering reaches the kernel from (``flash_attention``,
+    ``latent_attention``)."""
     from .. import tune
     B, S, H, D = q.shape
     scale = D ** -0.5 if scale is None else float(scale)

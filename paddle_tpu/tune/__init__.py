@@ -55,6 +55,7 @@ __all__ = [
     "wall_timer", "model_timer", "table_timer", "time_best",
     "parity_ok", "parity_report",
     "lookup", "record_fallback", "counters", "reset_counters",
+    "count_flash_blocks",
 ]
 
 # -- dispatch counters --------------------------------------------------------
@@ -65,6 +66,10 @@ __all__ = [
 
 _counters_lock = threading.Lock()
 _counters = {"tune_hits": 0, "tune_misses": 0, "tune_fallbacks": 0}
+# {"fwd 256x256": launches traced}: the blocks each flash-attention kernel
+# launch was built with (kernels/flash_attention.py: _blocks), whoever
+# chose them — a cached winner or the kernel's own rule
+_flash_blocks = {}
 
 
 def _bump(name):
@@ -74,10 +79,19 @@ def _bump(name):
     profiler.update_tune_counters(**{name: 1})
 
 
-def counters():
-    """Snapshot of the process-level dispatch counters."""
+def count_flash_blocks(kernel, block_q, block_k):
+    """One flash-attention launch (``kernel``: fwd / dq / dkv) traced at
+    (block_q, block_k)."""
+    name = "%s %dx%d" % (kernel, block_q, block_k)
     with _counters_lock:
-        return dict(_counters)
+        _flash_blocks[name] = _flash_blocks.get(name, 0) + 1
+
+
+def counters():
+    """Snapshot of the process-level dispatch counters; ``flash_blocks``
+    is the {"<kernel> <block_q>x<block_k>": launches} tally."""
+    with _counters_lock:
+        return dict(_counters, flash_blocks=dict(_flash_blocks))
 
 
 def reset_counters():
@@ -85,6 +99,7 @@ def reset_counters():
     with _counters_lock:
         for k in _counters:
             _counters[k] = 0
+        _flash_blocks.clear()
     profiler.reset_tune_counters()
 
 

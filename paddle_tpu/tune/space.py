@@ -56,6 +56,9 @@ class KernelSpace(object):
 
     name = None
     params = {}
+    # what ``vmem_bytes`` may reach; a space whose reckoning counts the
+    # pipeline's buffers and the compiler's scratch itself states its own
+    vmem_budget = VMEM_BUDGET
 
     # -- to be provided by subclasses ---------------------------------------
     def default_config(self, key):
@@ -93,7 +96,7 @@ class KernelSpace(object):
                 continue
             seen.add(frozen)
             if self.is_valid(cfg, key) \
-                    and self.vmem_bytes(cfg, key) <= VMEM_BUDGET:
+                    and self.vmem_bytes(cfg, key) <= self.vmem_budget:
                 out.append(dict(cfg))
         if budget is not None:
             out = out[:max(int(budget), 0)]
@@ -189,10 +192,15 @@ class FlashAttentionSpace(KernelSpace):
     """Block space of kernels/flash_attention.py.
 
     key: {b, s, h, d, causal, dtype}, and ``dv`` where the v heads' width
-    differs from ``d``. The padded sequence rounds up to
-    the block width, so every block size divides by construction; the
-    constraints are alignment and the VMEM residency of the streamed
-    k/v plus the [block_q, block_k] score tile."""
+    differs from ``d``. The kernels run a sequence at ``padded_len(s)``
+    whatever the blocks, so a block has to divide that; the other
+    constraints are alignment and what the three kernels keep in VMEM —
+    the forward's and dQ's resident k/v and the dK/dV kernel's whole-
+    sequence q, dO and statistics. That reckoning is the kernel's own
+    (``flash_attention.vmem_bytes``: it counts both pipeline buffers and
+    the f32 tiles itself, so it is held to Mosaic's limit, not to the
+    shared ``VMEM_BUDGET``): a winner the backward would refuse cannot be
+    crowned."""
 
     name = "flash_attention"
     params = {
@@ -200,31 +208,51 @@ class FlashAttentionSpace(KernelSpace):
         "block_k": (64, 128, 256, 512),
     }
 
+    @staticmethod
+    def _call(key):
+        """(padded length, D, Dv, dtype) of the call the key describes."""
+        from ..kernels.flash_attention import padded_len
+        return (padded_len(key["s"]), key["d"], key.get("dv", key["d"]),
+                key["dtype"])
+
     def default_config(self, key):
-        from ..kernels.flash_attention import DEFAULT_CONFIG
-        return dict(DEFAULT_CONFIG)
+        """What a tune-cache miss runs: the kernels' own rule for the key.
+        A config is one pair for all three kernels, so where their picks
+        differ (a backward kernel's is narrower where its VMEM is) it is
+        the narrower on each side, which every kernel's reckoning grants."""
+        from ..kernels.flash_attention import KERNELS, default_blocks
+        s, d, dv, dtype = self._call(key)
+        picks = [default_blocks(kernel, s, s, d, dv, dtype,
+                                bool(key.get("causal", False)))
+                 for kernel in KERNELS]
+        return {"block_q": min(bq for bq, _ in picks),
+                "block_k": min(bk for _, bk in picks)}
+
+    @property
+    def vmem_budget(self):
+        from ..kernels.flash_attention import VMEM_LIMIT
+        return VMEM_LIMIT
 
     def is_valid(self, config, key):
         bq, bk = int(config["block_q"]), int(config["block_k"])
-        # q rides the sublane axis of the score tile, k the 128-lane axis
-        if bq < 8 or bq % 8 or bk < 128 or bk % 128:
+        s = self._call(key)[0]
+        # a block wider than the sequence clamps to it; one such size
+        # (128) stands for them all
+        if max(bq, bk) > max(s, 128):
             return False
-        # oversized blocks just pad the (short) sequence to one block;
-        # beyond 4x the real length the padding work dominates — prune
-        return bq <= max(key["s"], 1) * 4 and bk <= max(key["s"], 1) * 4
+        bq, bk = min(bq, s), min(bk, s)
+        # q rides the sublane axis of the score tile, k the 128-lane axis
+        if bq < s and bq % 8 or bk < s and bk % 128:
+            return False
+        return s % bq == 0 and s % bk == 0
 
     def vmem_bytes(self, config, key):
-        it = _itemsize(key["dtype"])
-        bq, bk = int(config["block_q"]), int(config["block_k"])
-        s = max(key["s"], bk)
-        d = key["d"]
-        dv = key.get("dv", d)
-        q_tile = bq * d * it
-        kv = s * (d + dv) * it        # k and v stay resident per q block
-        o_tile = bq * dv * it
-        score = bq * bk * 4           # f32 score/prob tile
-        stats = 3 * bq * 4            # m / num-row / den rows
-        return 2 * (q_tile + o_tile) + kv + score + stats
+        from ..kernels.flash_attention import KERNELS, vmem_bytes
+        s, d, dv, dtype = self._call(key)
+        bq = min(int(config["block_q"]), s)
+        bk = min(int(config["block_k"]), s)
+        return max(vmem_bytes(kernel, bq, bk, s, s, d, dv, _itemsize(dtype))
+                   for kernel in KERNELS)
 
     def make_operands(self, key, seed=0):
         import jax.numpy as jnp

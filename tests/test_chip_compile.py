@@ -114,6 +114,55 @@ def test_flash_attention_narrower_v_heads_compile(one_chip, bh, s, d, dv,
     assert text.count("tpu_custom_call") >= 2
 
 
+def _flash_programs(fa, bh, s, d, dv, config=None):
+    """(fn, shapes) of the forward and of both backward kernels, bf16,
+    causal, at batch x heads ``bh`` (large enough that XLA leaves the
+    operands in HBM, as a training step's are)."""
+    def fwd(q, k, v):
+        return fa._fa_forward(q, k, v, True, d ** -0.5, s, interpret=False,
+                              config=config)
+
+    def bwd(q, k, v, do, lse, delta):
+        return fa._fa_backward(q, k, v, do, lse, delta, True, d ** -0.5, s,
+                               interpret=False, config=config)
+
+    qk, vv, row = ((bh, s, d), jnp.bfloat16), ((bh, s, dv), jnp.bfloat16), \
+        ((bh, s), jnp.float32)
+    return (fwd, (qk, qk, vv)), (bwd, (qk, qk, vv, vv, row, row))
+
+
+@pytest.mark.parametrize("bh,s,d,dv", [(32, 8192, 192, 128),
+                                       (32, 8192, 64, 64),
+                                       (128, 2048, 128, 128)])
+def test_flash_attention_rule_picks_compile(one_chip, bh, s, d, dv):
+    """What ``default_blocks`` picks on a tune-cache miss (512 x 512 where
+    its VMEM reckoning grants it, a size down where 12 MB of whole-sequence
+    operands leave no room) Mosaic compiles, forward and backward."""
+    fa = _kernel_module("flash_attention")
+    for fn, shapes in _flash_programs(fa, bh, s, d, dv):
+        _compile(fn, one_chip, *shapes)
+
+
+def test_flash_attention_vmem_reckoning_refuses_before_mosaic(one_chip,
+                                                              monkeypatch):
+    """8,192 positions of 192 / 128-wide heads at 512 x 512: Mosaic
+    refuses the forward and the dK/dV kernel (scoped vmem; my chip run,
+    PR 33, agrees), and ``vmem_bytes`` says so first, so ``_blocks``
+    degrades such a winner instead of handing it on."""
+    fa = _kernel_module("flash_attention")
+    bh, s, d, dv = 32, 8192, 192, 128
+    for kernel in ("fwd", "dkv"):
+        assert fa.vmem_bytes(kernel, 512, 512, s, s, d, dv, 2) \
+            > fa.VMEM_LIMIT
+    cfg = {"block_q": 512, "block_k": 512}
+    for fn, shapes in _flash_programs(fa, bh, s, d, dv, cfg):
+        _compile(fn, one_chip, *shapes)         # degraded: compiles
+    monkeypatch.setattr(fa, "VMEM_LIMIT", 1 << 40)  # hand it on anyway
+    for fn, shapes in _flash_programs(fa, bh, s, d, dv, cfg):
+        with pytest.raises(Exception, match="vmem"):
+            _compile(fn, one_chip, *shapes)
+
+
 @pytest.mark.parametrize("kernel", ["lstm", "gru"])
 def test_fused_rnn_compiles(one_chip, kernel):
     """The stacked-LSTM cell shape: T100, N64, D512, f32."""

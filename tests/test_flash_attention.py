@@ -1,11 +1,18 @@
 """Pallas flash attention vs dense reference (interpret mode on CPU)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu import tune
 from paddle_tpu.kernels import flash_attention
-from paddle_tpu.kernels.flash_attention import _dense_reference
+from paddle_tpu.kernels.flash_attention import (_dense_reference,
+                                                flash_attention_with_lse)
+
+# the package attribute ``flash_attention`` is the function; this is the module
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
 
 def dense(q, k, v, causal):
@@ -103,8 +110,10 @@ def test_flash_bwd_kernel_grads_match_dense(causal, seq):
 
 def test_flash_bwd_no_quadratic_buffer():
     """The backward jaxpr must not materialise any [S, S] tensor — the
-    whole point of the recompute kernels (VERDICT r1 weak item 6)."""
-    S = 256
+    whole point of the recompute kernels (VERDICT r1 weak item 6). S is
+    longer than the widest tile, which is a [block_q, block_k] VMEM value
+    inside the kernels' own jaxprs."""
+    S = 2048
     q = jnp.zeros((1, S, 2, 32), jnp.float32)
 
     def loss(q, k, v):
@@ -137,8 +146,6 @@ def test_flash_lse_merge_matches_full():
     k = jnp.asarray(rng.randn(B, S, H, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, S, H, D), jnp.float32)
     co = jnp.asarray(rng.randn(B, S, H, D), jnp.float32)
-    from paddle_tpu.kernels.flash_attention import flash_attention_with_lse
-
     q = q[:, :S // 2]        # one device's local q chunk (ring layout)
     co = co[:, :S // 2]
 
@@ -168,14 +175,20 @@ def test_flash_lse_merge_matches_full():
 
 # -- v heads narrower than q/k heads (latent attention), explicit scale ------
 
-def _dense_dv(q, k, v, causal, scale):
-    """The dense composition for q/k [B, S, H, D], v [B, S, H, Dv]."""
+def _dense_lse(q, k, v, causal, scale):
+    """The dense composition for q/k [B, S, H, D], v [B, S, H, Dv]: (out
+    [B, S, H, Dv], lse [B, H, S])."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         S = q.shape[1]
         s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s,
                       -jnp.inf)
-    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v),
+            jax.scipy.special.logsumexp(s, axis=-1))
+
+
+def _dense_dv(q, k, v, causal, scale):
+    return _dense_lse(q, k, v, causal, scale)[0]
 
 
 def _qkv_dv(seed, S, D, Dv):
@@ -231,3 +244,262 @@ def test_flash_attention_layer_passes_scale_and_head_sizes():
     np.testing.assert_allclose(
         got, np.asarray(_dense_dv(qv, qv, vv, True, 0.11)), rtol=2e-4,
         atol=2e-5)
+
+
+# -- how the kernels tile the score matrix (PR 33) ---------------------------
+
+# (Sq, Sk, D, Dv, causal, blocks): blocks None = the rule's own pick. Every
+# shape the rule returns (one short tile, 128, 256, 512 wide), the
+# non-square ones a tune winner may bring, lengths that are and are not a
+# multiple of the tile (300 pads to 384, 640 = 5 x 128), both head shapes,
+# the ring's Sq != Sk chunks
+TILINGS = [
+    (100, 100, 64, 64, True, None),
+    (300, 300, 64, 64, True, None),
+    (300, 300, 192, 128, False, None),
+    (512, 512, 64, 64, True, None),
+    (512, 512, 192, 128, True, None),
+    (512, 512, 64, 64, False, None),
+    (640, 640, 64, 64, True, None),
+    (640, 640, 192, 128, False, None),
+    (1024, 1024, 64, 64, True, None),
+    (1024, 1024, 64, 64, True, (512, 512)),
+    (512, 512, 192, 128, True, (256, 128)),
+    (512, 512, 64, 64, True, (128, 256)),
+    (512, 512, 64, 64, False, (128, 256)),
+    (1024, 1024, 64, 64, True, (512, 256)),
+    (1024, 1024, 64, 64, True, (256, 512)),
+    (300, 300, 64, 64, True, (128, 384)),
+    (300, 300, 64, 64, False, (384, 128)),
+    (256, 384, 64, 64, False, None),
+    (384, 640, 192, 128, False, (128, 128)),
+    (512, 256, 64, 64, False, (256, 128)),
+]
+
+
+@pytest.mark.parametrize("Sq,Sk,D,Dv,causal,blocks", TILINGS)
+def test_every_tiling_matches_dense(Sq, Sk, D, Dv, causal, blocks):
+    """Output, lse and all three gradients (with a cotangent on lse too)
+    against the dense composition."""
+    rng = np.random.RandomState(Sq + Sk + D)
+    B, H = 1, 2
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
+    q, k, v = mk(B, Sq, H, D), mk(B, Sk, H, D), mk(B, Sk, H, Dv)
+    co, cl = mk(B, Sq, H, Dv), mk(B, H, Sq)
+    cfg = blocks and {"block_q": blocks[0], "block_k": blocks[1]}
+    scale = D ** -0.5
+    tune.reset_counters()
+
+    def loss(f):
+        def inner(q, k, v):
+            o, lse = f(q, k, v)
+            return jnp.sum(o * co) + jnp.sum(lse * cl)
+        return inner
+
+    flash = lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal=causal, config=cfg)
+    dense_ = lambda q, k, v: _dense_lse(q, k, v, causal, scale)
+    for name, a, b in zip(("o", "lse"), flash(q, k, v), dense_(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5, err_msg=name)
+    g1 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(dense_), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=2e-4, err_msg="d" + name)
+    if blocks:      # the blocks asked for are the blocks that ran
+        want = "%dx%d" % (min(blocks[0], fa.padded_len(Sq)),
+                          min(blocks[1], fa.padded_len(Sk)))
+        counted = tune.counters()["flash_blocks"]
+        assert {n.split()[1] for n in counted} == {want}, counted
+        assert {n.split()[0] for n in counted} == set(fa.KERNELS)
+
+
+@pytest.mark.parametrize("bq", [128, 256, 512])
+@pytest.mark.parametrize("bk", [128, 256, 512])
+@pytest.mark.parametrize("valid", [1024, 900])
+def test_causal_kernels_visit_the_triangle_and_mask_its_edge(bq, bk, valid):
+    """Whatever the two widths, a causal kernel visits exactly the tiles
+    that hold a position at or under the diagonal, none above it, and
+    masks exactly those the diagonal crosses (forward / dQ: k tiles per q
+    block; dK/dV: q tiles per k block)."""
+    S = 1024
+    reach = lambda qi, ki: (qi + 1) * bq - 1 >= ki * bk      # any q >= k
+    whole = lambda qi, ki: qi * bq >= (ki + 1) * bk - 1      # every q >= k
+    want = {(qi, ki): not whole(qi, ki) for qi in range(S // bq)
+            for ki in range(S // bk) if reach(qi, ki)}
+
+    def visited(segments, pair):
+        got = {}
+        for masks, lo, hi in segments:
+            for t in range(lo, hi):
+                assert pair(t) not in got
+                got[pair(t)] = bool(masks.get("causal"))
+                # the padded-k mask rides only on tiles that hold padding
+                assert (masks.get("valid_len") is not None) <= (
+                    valid < S and bool(masks.get("causal")))
+        return got
+
+    got = {}
+    for qi in range(S // bq):
+        got.update(visited(fa._k_segments(qi * bq, bq, bk, S, valid, True),
+                           lambda ki: (qi, ki)))
+    assert got == want
+    got = {}
+    for ki in range(S // bk):
+        got.update(visited(
+            fa._q_segments(ki * bk, bk, bq, S // bq, True, None),
+            lambda qi: (qi, ki)))
+    assert got == want
+
+
+def test_padded_k_mask_rides_only_on_the_tiles_that_hold_padding():
+    # not causal, 900 of 1,024 positions valid, 256-wide k tiles: tiles
+    # 0-2 are clear, tile 3 ([768, 1024)) masks its padded columns
+    assert fa._k_segments(0, 256, 256, 1024, 900, False) == (
+        ({}, 0, 3), ({"valid_len": 900}, 3, 4))
+    assert fa._k_segments(0, 256, 256, 1024, 1024, False) == (
+        ({}, 0, 4), ({"valid_len": None}, 4, 4))
+    # dK/dV: only the k block that holds padding masks, and then every tile
+    assert fa._q_segments(768, 256, 128, 8, False, 900) == (
+        ({"causal": False, "valid_len": 900}, 0, 8),)
+    assert fa._q_segments(512, 256, 128, 8, False, None) == (
+        ({"causal": True}, 0, 0), ({}, 0, 8))
+
+
+BF16 = jnp.bfloat16
+
+
+@pytest.mark.parametrize("shape,want", [
+    # the benchmark cell: 4,096 positions, 192 / 128-wide heads, bf16
+    ((4096, 4096, 192, 128, BF16, True), (512, 512)),
+    ((4096, 4096, 128, 128, BF16, False), (512, 512)),
+    ((256, 256, 64, 64, BF16, True), (256, 256)),
+    ((768, 768, 64, 64, BF16, True), (256, 256)),
+    # 300 positions run at 384 = 3 x 128: nothing wider divides it
+    ((384, 384, 64, 64, BF16, True), (128, 128)),
+    ((640, 640, 64, 64, jnp.float32, False), (128, 128)),
+    # one short tile is the whole sequence
+    ((100, 100, 64, 64, BF16, True), (100, 100)),
+    ((128, 128, 64, 64, BF16, True), (128, 128)),
+    # the ring's chunks: each side by its own length
+    ((256, 384, 64, 64, BF16, False), (256, 128)),
+])
+def test_default_blocks_table(shape, want):
+    for kernel in fa.KERNELS:
+        got = fa.default_blocks(kernel, *shape)
+        assert got == want, kernel
+        Sq, Sk = shape[:2]
+        assert Sq % got[0] == 0 and Sk % got[1] == 0
+        assert fa.vmem_bytes(kernel, *got, *shape[:4],
+                             jnp.dtype(shape[4]).itemsize) <= fa.VMEM_LIMIT
+
+
+def test_padded_len_never_exceeds_a_round_up_to_128():
+    assert [fa.padded_len(s) for s in (1, 100, 128, 129, 300, 4096)] == [
+        1, 100, 128, 256, 384, 4096]
+
+
+def test_vmem_reckoning_refuses_what_the_chip_refused_and_falls_back():
+    cell = (4096, 4096, 192, 128, 2)
+    # PR 32's chip refused 512 x 512 in the backward: lse and delta rode as
+    # [S, 1] f32 columns, 512 B a position, each held twice
+    columns = 2 * 2 * fa._block_bytes(4096, 1, 4)
+    assert columns == 8 * 2 ** 20
+    assert fa.vmem_bytes("dkv", 512, 512, *cell) + columns > fa.VMEM_LIMIT
+    # as rows they cost 16 KB a sequence and the same shape is granted
+    assert fa.vmem_bytes("dkv", 512, 512, *cell) <= fa.VMEM_LIMIT
+    # where the whole-sequence operands leave no room the rule falls back
+    # a size, first on the side the kernel's grid walks: 8,192 positions of
+    # 192 / 128-wide heads keep 12 MB of k and v (q and dO) resident
+    long = (8192, 8192, 192, 128)
+    picks = {k: fa.default_blocks(k, *long, BF16, True) for k in fa.KERNELS}
+    assert picks == {"fwd": (256, 512), "dq": (256, 256), "dkv": (256, 256)}
+    for kernel, pick in picks.items():
+        assert fa.vmem_bytes(kernel, 512, 512, *long, 2) > fa.VMEM_LIMIT
+        assert fa.vmem_bytes(kernel, *pick, *long, 2) <= fa.VMEM_LIMIT
+    # nothing fits 16,384 positions: the narrowest tile is what is left
+    assert fa.default_blocks("dkv", 16384, 16384, 64, 64, BF16, True) == (
+        128, 128)
+
+
+def _traced_blocks(S, D, Dv, config=None, dtype=BF16):
+    """flash_blocks after TRACING (nothing runs) forward + backward."""
+    tune.reset_counters()
+    q = jax.ShapeDtypeStruct((1, S, 2, D), dtype)
+    v = jax.ShapeDtypeStruct((1, S, 2, Dv), dtype)
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v, causal=True, config=config).astype(jnp.float32)),
+        argnums=(0, 1, 2)), q, q, v)
+    return tune.counters()["flash_blocks"]
+
+
+def test_a_tune_winner_overrides_the_rule_and_a_bad_one_degrades():
+    rule = {k: "%dx%d" % fa.default_blocks(k, 1024, 1024, 64, 64, BF16, True)
+            for k in fa.KERNELS}
+    # a winner that divides the padded length runs as given
+    got = _traced_blocks(1024, 64, 64, {"block_q": 128, "block_k": 512})
+    assert {n.split()[1] for n in got} == {"128x512"}
+    # one that does not divide it (1,024 = 2.67 x 384), or that the VMEM
+    # reckoning refuses, degrades to the rule
+    for cfg, S, D in (({"block_q": 384, "block_k": 128}, 1024, 64),
+                      ({"block_q": 64, "block_k": 100}, 1024, 64)):
+        got = _traced_blocks(S, D, D, cfg)
+        assert {tuple(n.split()) for n in got} == set(rule.items()), got
+    assert fa.vmem_bytes("dq", 512, 512, 8192, 8192, 192, 128, 2) \
+        > fa.VMEM_LIMIT
+    got = _traced_blocks(8192, 192, 128, {"block_q": 512, "block_k": 512})
+    assert "dq 512x512" not in got and any(n.startswith("dq ") for n in got)
+
+
+def _decoder_step_blocks(seq):
+    """flash_blocks after TRACING one training step (forward, recomputed
+    half-layers, backward, Adam) of a small latent-attention / sparse-
+    expert decoder at ``seq`` positions under pure AMP: ``jax.eval_shape``
+    over the main block's ops, nothing runs but the startup program."""
+    import paddle_tpu as pt
+    from paddle_tpu import layers as L
+    from paddle_tpu.core.executor import RngSource, trace_ops
+    from paddle_tpu.models.latent_moe_lm import latent_moe_lm
+    from test_latent_moe_lm import HALVES, tiny
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        tokens = L.data("tokens", shape=[seq], dtype="int64")
+        labels = L.data("labels", shape=[seq], dtype="int64")
+        out = latent_moe_lm(tokens, tiny(True), labels=labels)
+        pt.amp.enable(main, pure=True)
+        pt.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(out["loss"])
+        pt.memory_optimize(main, remat_types=HALVES)
+    exe = pt.Executor(pt.CPUPlace())
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        state = {n: scope.find_var(n) for n in exe._state_inputs(
+            main, scope, {"tokens", "labels"})}
+    feed = {n: jax.ShapeDtypeStruct((2, seq), jnp.int64)
+            for n in ("tokens", "labels")}
+
+    def step(state, feed):
+        env = dict(feed, **state)
+        trace_ops(main.global_block(), env, RngSource(jax.random.PRNGKey(0)))
+        return env[out["loss"].name]
+
+    prev = pt.amp.force(True)
+    tune.reset_counters()
+    try:
+        jax.eval_shape(step, state, feed)
+    finally:
+        pt.amp.force(prev)
+    return tune.counters()["flash_blocks"]
+
+
+def test_flash_blocks_counts_only_the_large_shape_for_the_decoder_at_4096():
+    counted = _decoder_step_blocks(4096)
+    # three layers: the forward, the recomputed forward, dQ and dK/dV of each
+    assert {n.split()[0] for n in counted} == set(fa.KERNELS)
+    assert {n.split()[1] for n in counted} == {"512x512"}, counted
+    assert all(c >= 3 for c in counted.values()), counted
+
+
+def test_flash_blocks_at_128_positions_is_one_tile():
+    counted = _decoder_step_blocks(128)
+    assert {n.split()[1] for n in counted} == {"128x128"}, counted

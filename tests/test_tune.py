@@ -63,6 +63,58 @@ def test_matmul_space_alignment_constraints():
         assert 64 % bm == 0 and 256 % bn == 0 and 256 % bk == 0
 
 
+CELL_ATTN = {"b": 2, "s": 4096, "h": 32, "d": 192, "dv": 128,
+             "causal": True, "dtype": "bfloat16"}
+
+
+@pytest.mark.parametrize("key,want", [
+    (CELL_ATTN, {"block_q": 512, "block_k": 512}),
+    (dict(CELL_ATTN, s=300), {"block_q": 128, "block_k": 128}),
+    (dict(CELL_ATTN, s=100), {"block_q": 100, "block_k": 100}),
+    # the forward's own pick there is 256 x 512; a config is one pair for
+    # all three kernels, so the narrower side of the backward's stands
+    (dict(CELL_ATTN, s=8192), {"block_q": 256, "block_k": 256}),
+])
+def test_flash_space_default_is_the_kernels_own_rule(key, want):
+    from paddle_tpu.kernels.flash_attention import (KERNELS, default_blocks,
+                                                    padded_len)
+    sp = tune.get_space("flash_attention")
+    s = padded_len(key["s"])
+    picks = [default_blocks(k, s, s, key["d"], key["dv"], key["dtype"], True)
+             for k in KERNELS]
+    assert sp.default_config(key) == want == {
+        "block_q": min(p[0] for p in picks),
+        "block_k": min(p[1] for p in picks)}
+    assert sp.candidates(key)[0] == want
+
+
+def test_flash_space_reckons_the_backward_and_the_padded_length():
+    """A candidate has to divide the length the kernels run (300 -> 384)
+    and fit the BACKWARD kernels' residency too: whole-sequence q, dO and
+    the statistics, which the forward-only footprint left out."""
+    from paddle_tpu.kernels.flash_attention import VMEM_LIMIT, vmem_bytes
+    sp = tune.get_space("flash_attention")
+    wide = {"block_q": 512, "block_k": 512}
+    assert sp.vmem_bytes(wide, CELL_ATTN) == max(
+        vmem_bytes(k, 512, 512, 4096, 4096, 192, 128, 2)
+        for k in ("fwd", "dq", "dkv")) <= VMEM_LIMIT
+    assert wide in sp.candidates(CELL_ATTN)
+    # twice the length: 12 MB of resident operands, 512 x 512 cannot be
+    # crowned (Mosaic refuses the forward and dK/dV there)
+    long = dict(CELL_ATTN, s=8192)
+    assert sp.is_valid(wide, long) and sp.vmem_bytes(wide, long) > VMEM_LIMIT
+    assert wide not in sp.candidates(long)
+    # the forward alone would have fitted at 512 x 256: the backward rules
+    assert vmem_bytes("dq", 512, 256, 8192, 8192, 192, 128, 2) > VMEM_LIMIT
+    assert {"block_q": 512, "block_k": 256} not in sp.candidates(long)
+    for cfg in sp.candidates(dict(CELL_ATTN, s=300)):
+        assert 384 % cfg["block_q"] == 0 and 384 % cfg["block_k"] == 0
+        assert cfg["block_k"] % 128 == 0 and cfg["block_q"] % 8 == 0
+    # one short tile: every width clamps to the sequence, one stands for all
+    assert sp.candidates(dict(CELL_ATTN, s=100)) == [
+        {"block_q": 100, "block_k": 100}, {"block_q": 128, "block_k": 128}]
+
+
 # -- loop --------------------------------------------------------------------
 
 def test_autotune_deterministic_winner_and_parity_gate():

@@ -10,7 +10,9 @@ instruction's name: per scope the device self time a step and the number of
 instructions, per instruction its scope, result type, fusion kind and time.
 ``--hlo`` also writes the optimised text of the step's module, which says
 what a ``fusion.N`` reads and writes. The cell's result (every per-layer
-metric of a ``--trace 1`` run) goes into the ``--out`` file beside the table.
+metric of a ``--trace 1`` run) goes into the ``--out`` file beside the table,
+with ``tune.counters()`` (the header's line: dispatch gauges and the blocks
+each flash-attention launch was traced at).
 """
 from __future__ import annotations
 
@@ -85,10 +87,20 @@ def by_kind(rows, steps, scope):
     return sorted(((w, n[w], ms[w]) for w in ms), key=lambda r: -r[2])
 
 
-def report(module, steps, rows):
+def report(module, steps, rows, counters=None):
+    """``counters``: ``tune.counters()`` of the process that traced the
+    step (the dispatch gauges and the flash kernels' blocks), where the
+    table comes from a run and not from a recorded slice."""
     total = sum(ns for _s, _n, ns in rows.values()) / steps / 1e6
     print("%s: %d steps, %d instructions, %.2f ms a step"
           % (module, steps, len(rows), total))
+    if counters:
+        blocks = counters.get("flash_blocks", {})
+        print("tune: %d hits, %d misses, %d fallbacks; flash_blocks: %s"
+              % (counters["tune_hits"], counters["tune_misses"],
+                 counters["tune_fallbacks"],
+                 ", ".join("%s x%d" % kv for kv in sorted(blocks.items()))
+                 or "none"))
     for scope, ms, n in by_scope(rows, steps):
         print("  %-28s %8.3f ms %5d ops" % (scope, ms, n))
     for scope in DETAILED:
@@ -111,7 +123,7 @@ def main(argv=None):
     ap.add_argument("--hlo")
     args = ap.parse_args(argv)
     from chipbench import program_trace
-    result = None
+    result = counters = None
     if args.recorded:
         with open(args.recorded) as f:
             rec = json.load(f)
@@ -121,7 +133,7 @@ def main(argv=None):
         if args.seed is None:
             ap.error("--seed or --recorded")
         from chipbench import harness, run as bench, trace_reduce
-        from paddle_tpu import profiler
+        from paddle_tpu import profiler, tune
         from paddle_tpu.core import executor
         try:
             res = bench.run_cell(args.workload, args.seed, args.seconds, True)
@@ -135,6 +147,7 @@ def main(argv=None):
                                          res["ctx"]["window_ns"])
         result = {k: res[k] for k in ("correct", "attempted", "metrics",
                                       "device", "compared", "breakdown")}
+        counters = result["tune"] = tune.counters()
         if args.hlo:
             for step in executor.compiled_steps():
                 facts = step.facts()
@@ -142,7 +155,7 @@ def main(argv=None):
                     with open(args.hlo, "w") as f:
                         f.write(step.fn.lower(*step._avals).compile()
                                 .as_text())
-    report(module, steps, rows)
+    report(module, steps, rows, counters)
     if args.out:
         with open(args.out, "w") as f:
             json.dump({"module": module, "steps": steps, "rows": rows,
