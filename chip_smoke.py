@@ -72,7 +72,10 @@ REAL = {
     "decoder": {"hidden": 256, "heads": 4, "nope": 128, "rope": 64, "v": 128,
                 "rank": 128, "dense": 512, "expert": 128, "experts": 8,
                 "top_k": 2, "held": [4, 4], "vocab": 512,
-                "vocab_held": [128, 128], "seq": 256, "rows": 2},
+                "vocab_held": [128, 128], "seq": 256, "rows": 2,
+                # the window / full block: 8 q heads on 2 k/v heads of 128,
+                # a window of half the row on the first of its two layers
+                "q_heads": 8, "kv_heads": 2, "head": 128, "window": 128},
 }
 TINY = {
     "train": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
@@ -89,7 +92,8 @@ TINY = {
     "decoder": {"hidden": 64, "heads": 4, "nope": 24, "rope": 8, "v": 16,
                 "rank": 32, "dense": 96, "expert": 32, "experts": 8,
                 "top_k": 2, "held": [4, 4], "vocab": 256,
-                "vocab_held": [64, 64], "seq": 32, "rows": 2},
+                "vocab_held": [64, 64], "seq": 32, "rows": 2,
+                "q_heads": 4, "kv_heads": 2, "head": 16, "window": 16},
 }
 
 
@@ -875,36 +879,23 @@ def phase_dp4(cfg, seed, rehearse):
 # phase: decoder (latent attention + an expert layer that holds a share)
 # ---------------------------------------------------------------------------
 
-def phase_decoder(cfg, seed, rehearse):
-    """``models.latent_moe_lm`` (1 dense + 1 expert layer) trained two
-    steps through Trainer under pure AMP with Adam, its half-layers
-    recomputed; every step's ``Load`` has to count every (token, pick) pair
-    and ``RowsHeld`` the pairs on the held experts."""
+def _two_decoder_steps(model, config, cfg, seed):
+    """``model(tokens, config, labels=)`` trained two steps through Trainer
+    under pure AMP with Adam, its half-layers recomputed; every step's
+    ``Load`` has to count every (token, pick) pair and ``RowsHeld`` the
+    pairs on the held experts. Returns (losses, loads, rows held)."""
     import math
 
     import numpy as np
 
     import paddle_tpu as pt
     from paddle_tpu import layers
-    from paddle_tpu.models.latent_moe_lm import latent_moe_lm
 
-    dev = _device(rehearse, 1)
-    config = dict(
-        hidden_size=cfg["hidden"], num_attention_heads=cfg["heads"],
-        num_key_value_heads=cfg["heads"], qk_nope_head_dim=cfg["nope"],
-        qk_rope_head_dim=cfg["rope"], qk_head_dim=cfg["nope"] + cfg["rope"],
-        v_head_dim=cfg["v"], kv_lora_rank=cfg["rank"],
-        intermediate_size=cfg["dense"], moe_intermediate_size=cfg["expert"],
-        n_routed_experts=cfg["experts"], num_experts_per_tok=cfg["top_k"],
-        n_shared_experts=2, routed_scaling_factor=2.448,
-        first_k_dense_replace=1, num_hidden_layers=2, rms_norm_eps=1e-6,
-        rope_theta=1e6, vocab_size=cfg["vocab"], experts_held=cfg["held"],
-        vocab_held=cfg["vocab_held"])
     main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
     with pt.program_guard(main, startup):
         tokens = layers.data("tokens", shape=[cfg["seq"]], dtype="int64")
         labels = layers.data("labels", shape=[cfg["seq"]], dtype="int64")
-        out = latent_moe_lm(tokens, config, labels=labels)
+        out = model(tokens, config, labels=labels)
         pt.amp.enable(main, pure=True)
         trainer = pt.Trainer(
             cost=out["loss"], optimizer=pt.optimizer.AdamOptimizer(
@@ -945,6 +936,57 @@ def phase_decoder(cfg, seed, rehearse):
     _check(stats["compiles"] == 2 and stats["eager_runs"] == 0,
            "expected 1 startup + 1 step compile, got %r"
            % {k: stats[k] for k in ("compiles", "jit_runs", "eager_runs")})
+    return losses, loads, held
+
+
+def phase_decoder(cfg, seed, rehearse):
+    """Two steps each (``_two_decoder_steps``) of ``models.latent_moe_lm``
+    and of ``models.window_moe_lm`` (grouped k/v heads, a window on the
+    first layer and none on the second), 1 dense + 1 expert layer each;
+    the banded launches have to visit fewer tiles than the square."""
+    from paddle_tpu import layers, tune
+    from paddle_tpu.models.latent_moe_lm import latent_moe_lm
+    from paddle_tpu.models.window_moe_lm import window_moe_lm
+
+    dev = _device(rehearse, 1)
+    shared = dict(
+        hidden_size=cfg["hidden"], intermediate_size=cfg["dense"],
+        moe_intermediate_size=cfg["expert"],
+        num_experts_per_tok=cfg["top_k"], num_hidden_layers=2,
+        vocab_size=cfg["vocab"], experts_held=cfg["held"],
+        vocab_held=cfg["vocab_held"])
+    latent = dict(
+        shared, num_attention_heads=cfg["heads"],
+        num_key_value_heads=cfg["heads"], qk_nope_head_dim=cfg["nope"],
+        qk_rope_head_dim=cfg["rope"], qk_head_dim=cfg["nope"] + cfg["rope"],
+        v_head_dim=cfg["v"], kv_lora_rank=cfg["rank"],
+        n_routed_experts=cfg["experts"], n_shared_experts=2,
+        routed_scaling_factor=2.448, first_k_dense_replace=1,
+        rms_norm_eps=1e-6, rope_theta=1e6)
+    window = dict(
+        shared, num_attention_heads=cfg["q_heads"],
+        num_key_value_heads=cfg["kv_heads"], head_dim=cfg["head"],
+        layer_types=["sliding_attention", "full_attention"],
+        sliding_window=cfg["window"], num_experts=cfg["experts"],
+        num_shared_experts=1, route_scale=2.826, route_norm=True,
+        num_dense_layers=1, rms_norm_eps=1e-5, rope_theta=10000,
+        mup_enabled=True)
+    losses, loads, held = _two_decoder_steps(latent_moe_lm, latent, cfg, seed)
+    tune.reset_counters()
+    w_losses, w_loads, w_held = _two_decoder_steps(window_moe_lm, window,
+                                                   cfg, seed + 1)
+    tiles = tune.counters()["flash_tiles"]
+    banded = {k: t for k, t in tiles.items()
+              if " w%d" % cfg["window"] in k}
+    group = " g%d" % (cfg["q_heads"] // cfg["kv_heads"])
+    _check(banded and all(group in k for k in tiles),
+           "no banded, grouped flash launch was traced: %r" % tiles)
+    share = lambda t: t["visited"] / float(t["square"])
+    _check(all(share(t) <= min(share(u) for k, u in tiles.items()
+                               if k not in banded)
+               for t in banded.values()),
+           "a banded launch visits a larger share of its square than a "
+           "full one: %r" % tiles)
     rec = {"phase": "decoder", "passed": True, "device": dev,
            "model": "latent_moe_lm d%d heads %dx(%d+%d/%d) experts %d top %d"
                     % (cfg["hidden"], cfg["heads"], cfg["nope"], cfg["rope"],
@@ -952,7 +994,12 @@ def phase_decoder(cfg, seed, rehearse):
            "losses": [round(x, 5) for x in losses], "loads": loads,
            "rows_held": held,
            "moe_max_over_mean_load": layers.moe_load_stats(
-               loads[-1], held[-1])["moe_max_over_mean_load"]}
+               loads[-1], held[-1])["moe_max_over_mean_load"],
+           "window_model": "window_moe_lm d%d heads %d/%dx%d window %d of %d"
+                           % (cfg["hidden"], cfg["q_heads"], cfg["kv_heads"],
+                              cfg["head"], cfg["window"], cfg["seq"]),
+           "window_losses": [round(x, 5) for x in w_losses],
+           "window_rows_held": w_held, "flash_tiles": tiles}
     rec.update(_audit("decoder"))
     return rec
 

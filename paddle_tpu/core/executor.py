@@ -897,7 +897,10 @@ class Executor(object):
         # happens at trace time, so they move once per compile — the
         # snapshot refreshes at the end of every run(); flash_blocks is
         # the same snapshot's tally of the blocks each flash-attention
-        # launch was traced at ({"fwd 512x512": n, ...})
+        # launch was traced at ({"fwd 512x512": n, ...}; grouped heads and
+        # a window ride in the name, "fwd 512x512 g8 w2048") and
+        # flash_tiles the score tiles each such launch visits / masks /
+        # has in its square
         # comm_path says HOW the last compiled program's DP grads sync:
         # "explicit" = routed through the paddle_tpu.comm collectives
         # (comm_* stats measured from the traced plan), "model" = GSPMD
@@ -917,6 +920,7 @@ class Executor(object):
                       "comm_path": "",
                       "tune_hits": 0, "tune_misses": 0,
                       "tune_fallbacks": 0, "flash_blocks": {},
+                      "flash_tiles": {},
                       "elastic_resizes": 0, "elastic_lost_ranks": 0,
                       "elastic_requeued_tasks": 0,
                       "elastic_resume_ms": 0.0,

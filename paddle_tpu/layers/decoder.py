@@ -1,11 +1,13 @@
 """Layers of a modern decoder block: ``flash_attention``, ``rms_norm``,
-``rotary_embedding`` and the half-layers ``latent_attention``, ``gated_ffn``
-and ``moe_ffn`` (ops/decoder_ops.py; doc/decoder_layers.md).
+``rotary_embedding`` and the half-layers ``latent_attention``,
+``grouped_attention``, ``gated_ffn`` and ``moe_ffn`` (ops/decoder_ops.py;
+doc/decoder_layers.md).
 
-The three half-layers take the residual stream ``x`` [batch, seq, hidden]
-and return ``x + f(rms_norm(x))``: norm, products and residual add are one
-op each, so that ``memory_optimize`` can recompute a whole half-layer in the
-backward pass.
+The half-layers take the residual stream ``x`` [batch, seq, hidden] and
+return ``x + f(rms_norm(x))`` (with ``post_norm`` ``x + rms_norm(f(
+rms_norm(x)))``, the sandwich norm): norms, products and residual add are
+one op each, so that ``memory_optimize`` can recompute a whole half-layer in
+the backward pass.
 """
 from __future__ import annotations
 
@@ -16,7 +18,8 @@ from ..param_attr import ParamAttr
 from .layer_helper import LayerHelper
 
 __all__ = ["flash_attention", "rms_norm", "rotary_embedding",
-           "latent_attention", "gated_ffn", "moe_ffn", "moe_load_stats"]
+           "latent_attention", "grouped_attention", "gated_ffn", "moe_ffn",
+           "moe_load_stats"]
 
 
 def _named(prefix, name):
@@ -29,16 +32,21 @@ def _out_like(helper, x):
     return out
 
 
-def flash_attention(q, k, v, causal=False, scale=None):
-    """q/k [batch, seq, heads, D], v [batch, seq, heads, Dv] ->
-    [batch, seq, heads, Dv] through the ``flash_attention`` op. ``scale``
-    multiplies the scores (None: D ** -0.5)."""
+def flash_attention(q, k, v, causal=False, scale=None, window=None):
+    """q [batch, seq, heads, D], k [batch, seq, kv heads, D], v [batch,
+    seq, kv heads, Dv] -> [batch, seq, heads, Dv] through the
+    ``flash_attention`` op (``heads`` a multiple of ``kv heads``: q head j
+    reads k/v head j // (heads / kv heads)). ``scale`` multiplies the
+    scores (None: D ** -0.5); ``window``: a causal call sees only that
+    many keys back, itself included."""
     helper = LayerHelper("flash_attention")
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     out.shape = tuple(q.shape[:3]) + (v.shape[3],)
     attrs = {"causal": bool(causal)}
     if scale is not None:
         attrs["scale"] = float(scale)
+    if window is not None:
+        attrs["window"] = int(window)
     helper.append_op(type="flash_attention",
                      inputs={"Q": [q], "K": [k], "V": [v]},
                      outputs={"Out": [out]}, attrs=attrs)
@@ -58,13 +66,15 @@ def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, theta=10000.0):
-    """x [batch, seq, heads, size]: each pair (2i, 2i+1) of row s turned by
-    s * theta^(-2i/size) (interleaved layout, positions 0..seq-1)."""
+def rotary_embedding(x, theta=10000.0, layout="interleaved"):
+    """x [batch, seq, heads, size]: pair i of row s turned by
+    s * theta^(-2i/size), positions 0..seq-1; the pair is (2i, 2i+1)
+    (``"interleaved"``) or (i, i + size/2) (``"half"``)."""
     helper = LayerHelper("rotary_embedding")
     out = _out_like(helper, x)
     helper.append_op(type="rotary_embedding", inputs={"X": [x]},
-                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+                     outputs={"Out": [out]},
+                     attrs={"theta": float(theta), "layout": layout})
     return out
 
 
@@ -105,14 +115,58 @@ def latent_attention(x, num_heads, nope_dim, rope_dim, v_dim, kv_rank,
     return out
 
 
-def gated_ffn(x, size, epsilon=1e-6, prefix=None):
+def _post_norm(post_norm, d):
+    """The slot of a half-layer's sandwich norm, where it has one."""
+    return {"PostNormScale": ("post_norm", (d,))} if post_norm else {}
+
+
+def grouped_attention(x, num_heads, num_kv_heads, head_dim, window=None,
+                      rotary=True, theta=10000.0, epsilon=1e-6,
+                      post_norm=False, prefix=None):
+    """x + [rms_norm] W_o (attn(q, k, v) * sigmoid(h W_g)) of h =
+    rms_norm(x): q = h W_q (``num_heads`` heads), k = h W_k and v = h W_v
+    (``num_kv_heads`` heads, each read by num_heads / num_kv_heads q
+    heads, never repeated); q and k RMS-normed over ``head_dim`` with a
+    learned scale each; with ``rotary`` the whole heads turned by their
+    positions, pairs (i, i + head_dim/2); causal softmax(q k^T
+    head_dim^-1/2) v, with ``window`` over the last ``window`` keys only
+    (itself included). Parameters ``<prefix>.{norm,wq,wk,wv,q_norm,
+    k_norm,wg,wo}`` and, with ``post_norm``, ``post_norm``."""
+    if num_heads % num_kv_heads:
+        raise ValueError("%d query heads cannot share %d key and value "
+                         "heads" % (num_heads, num_kv_heads))
+    helper = LayerHelper("grouped_attention")
+    d = x.shape[-1]
+    inputs = _params(helper, prefix, x.dtype, dict({
+        "NormScale": ("norm", (d,)),
+        "WQ": ("wq", (d, num_heads * head_dim)),
+        "WK": ("wk", (d, num_kv_heads * head_dim)),
+        "WV": ("wv", (d, num_kv_heads * head_dim)),
+        "QNormScale": ("q_norm", (head_dim,)),
+        "KNormScale": ("k_norm", (head_dim,)),
+        "WG": ("wg", (d, num_heads * head_dim)),
+        "WO": ("wo", (num_heads * head_dim, d))}, **_post_norm(post_norm, d)))
+    inputs["X"] = [x]
+    out = _out_like(helper, x)
+    helper.append_op(
+        type="grouped_attention", inputs=inputs, outputs={"Out": [out]},
+        attrs={"heads": num_heads, "kv_heads": num_kv_heads,
+               "head_dim": head_dim, "window": int(window or 0),
+               "rotary": bool(rotary), "theta": float(theta),
+               "epsilon": epsilon})
+    return out
+
+
+def gated_ffn(x, size, epsilon=1e-6, post_norm=False, prefix=None):
     """x + (silu(h W_gate) * (h W_up)) W_down, h = rms_norm(x), ``size``
-    wide. Parameters ``<prefix>.{norm,gate,up,down}``."""
+    wide; with ``post_norm`` the products' result is normed before the
+    add. Parameters ``<prefix>.{norm,gate,up,down}`` (and ``post_norm``)."""
     helper = LayerHelper("gated_ffn")
     d = x.shape[-1]
-    inputs = _params(helper, prefix, x.dtype, {
+    inputs = _params(helper, prefix, x.dtype, dict({
         "NormScale": ("norm", (d,)), "WGate": ("gate", (d, size)),
-        "WUp": ("up", (d, size)), "WDown": ("down", (size, d))})
+        "WUp": ("up", (d, size)), "WDown": ("down", (size, d))},
+        **_post_norm(post_norm, d)))
     inputs["X"] = [x]
     out = _out_like(helper, x)
     helper.append_op(type="gated_ffn", inputs=inputs,
@@ -121,7 +175,8 @@ def gated_ffn(x, size, epsilon=1e-6, prefix=None):
 
 
 def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
-            experts_held=None, scaling=1.0, epsilon=1e-6, prefix=None):
+            experts_held=None, scaling=1.0, epsilon=1e-6, post_norm=False,
+            prefix=None):
     """x + shared(h) + the held experts' part of the routed sum, h =
     rms_norm(x). The router scores ALL ``num_experts`` with a sigmoid and
     picks the ``top_k`` largest of score + bias (the bias is a persistable
@@ -134,7 +189,10 @@ def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
     nothing is dropped and no capacity is set. Returns ``(out, load,
     rows_held)``: ``load`` int32[num_experts] counts this step's picks per
     expert, ``rows_held`` int32[1] the (token, pick) pairs on held
-    experts. Parameters ``<prefix>.{norm,router,router_bias,expert_gate,
+    experts. With ``post_norm`` shared + held part is normed before the
+    add (``<prefix>.post_norm``); the norm of a share's partial sum is no
+    part of the whole layer's norm, so shares that are to add up leave it
+    off. Parameters ``<prefix>.{norm,router,router_bias,expert_gate,
     expert_up,expert_down,shared_gate,shared_up,shared_down}``."""
     first, count = experts_held or (0, num_experts)
     if not (0 <= first and count >= 1 and first + count <= num_experts):
@@ -142,7 +200,7 @@ def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
                          % (experts_held, num_experts))
     helper = LayerHelper("moe_ffn")
     d = x.shape[-1]
-    inputs = _params(helper, prefix, x.dtype, {
+    inputs = _params(helper, prefix, x.dtype, dict({
         "NormScale": ("norm", (d,)),
         "WRouter": ("router", (d, num_experts)),
         "ExpertGate": ("expert_gate", (count, d, expert_size)),
@@ -150,7 +208,8 @@ def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
         "ExpertDown": ("expert_down", (count, expert_size, d)),
         "SharedGate": ("shared_gate", (d, shared_size)),
         "SharedUp": ("shared_up", (d, shared_size)),
-        "SharedDown": ("shared_down", (shared_size, d))})
+        "SharedDown": ("shared_down", (shared_size, d))},
+        **_post_norm(post_norm, d)))
     bias_attr = _named(prefix, "router_bias")
     bias_attr.trainable = False
     bias = helper.create_parameter(

@@ -84,7 +84,7 @@ DEFAULT_REMAT_TYPES = frozenset((
     "dynamic_gru", "sequence_conv", "flash_attention", "mdlstm",
     # whole half-layers of a decoder block (ops/decoder_ops.py): kept as
     # their [tokens, hidden] input, recomputed in the backward pass
-    "latent_attention", "gated_ffn", "moe_ffn"))
+    "latent_attention", "grouped_attention", "gated_ffn", "moe_ffn"))
 
 
 def memory_optimize(input_program: ir.Program, print_log=False, level=0,

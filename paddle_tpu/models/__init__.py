@@ -17,3 +17,4 @@ from .transformer import (  # noqa: F401
 )
 from .ctr import wide_deep, deepfm, synthetic_click_batch  # noqa: F401
 from .latent_moe_lm import latent_moe_lm  # noqa: F401
+from .window_moe_lm import window_moe_lm  # noqa: F401

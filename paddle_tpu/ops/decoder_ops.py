@@ -1,17 +1,19 @@
-"""Ops of a decoder block with latent attention and sparse experts (the
-DeepSeek-V3 block, arXiv:2412.19437 section 2.1): ``rms_norm``,
-``rotary_embedding``, and the three half-layers ``latent_attention``,
-``gated_ffn`` and ``moe_ffn``.
+"""Ops of a modern decoder block: ``rms_norm``, ``rotary_embedding``, and
+the half-layers ``latent_attention`` (the DeepSeek-V3 block's, arXiv:
+2412.19437 section 2.1), ``grouped_attention`` (grouped-query heads with
+QK norms, a sliding window, an output gate), ``gated_ffn`` and ``moe_ffn``.
 
 No 2018 reference equivalent. Each half-layer (pre-norm, the products, the
-residual add) is ONE op whose lowering is a pure jax function, and all three
-are in ``memory_optimize``'s default ``remat_types``: ``generic_grad`` then
-recomputes the half-layer under ``jax.checkpoint`` and a layer keeps its two
-``[tokens, hidden]`` inputs for the backward pass instead of every product's
-operands. Inside a lowering ``profiler.part_scope`` names the parts
-(``proj``, ``rope``, ``attn``, ``route``, ``experts``, ``shared``), and
-``profiler.device_scopes()`` keeps that second level
-(``forward/latent_attention/attn``).
+residual add, and where its ``PostNormScale`` is given a norm of the
+products' result BEFORE the add: the sandwich norm) is ONE op whose lowering
+is a pure jax function, and all four are in ``memory_optimize``'s default
+``remat_types``: ``generic_grad`` then recomputes the half-layer under
+``jax.checkpoint`` and a layer keeps its two ``[tokens, hidden]`` inputs for
+the backward pass instead of every product's operands. Inside a lowering
+``profiler.part_scope`` names the parts (``proj``, ``rope``, ``attn``,
+``attn_window``, ``attn_full``, ``gate``, ``route``, ``experts``,
+``shared``, ``post_norm``), and ``profiler.device_scopes()`` keeps that
+second level (``forward/latent_attention/attn``).
 
 Precision: under AMP the matrix products take bf16 operands and accumulate
 in f32; under pure AMP the residual stream and what a half-layer hands on
@@ -51,18 +53,29 @@ def rms_norm(x, w, eps, out_dtype=None):
     return (y * w.astype(jnp.float32)).astype(out_dtype or x.dtype)
 
 
-def rotary(x, theta):
-    """x [B, S, H, R]: the pair (2i, 2i+1) of row s turned by the angle
-    s * theta^(-2i/R) (the interleaved layout; positions 0..S-1)."""
+def rotary(x, theta, layout="interleaved"):
+    """x [B, S, H, R]: pair i of row s turned by the angle
+    s * theta^(-2i/R), positions 0..S-1. ``layout`` says which two
+    elements are pair i: ``"interleaved"`` (2i, 2i+1), ``"half"``
+    (i, i + R/2: HF ``rotate_half``)."""
     B, S, H, R = x.shape
     inv = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
     ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
-    xf = x.astype(jnp.float32).reshape(B, S, H, R // 2, 2)
-    a, b = xf[..., 0], xf[..., 1]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
-    return out.reshape(B, S, H, R).astype(x.dtype)
+    xf = x.astype(jnp.float32)
+    if layout == "half":
+        a, b = xf[..., :R // 2], xf[..., R // 2:]
+        out = jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                              axis=-1)
+    elif layout == "interleaved":
+        xf = xf.reshape(B, S, H, R // 2, 2)
+        a, b = xf[..., 0], xf[..., 1]
+        out = jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                        axis=-1).reshape(B, S, H, R)
+    else:
+        raise ValueError("no rotary layout %r" % (layout,))
+    return out.astype(x.dtype)
 
 
 def gated(h, w_gate, w_up, w_down, operand):
@@ -90,9 +103,10 @@ def rms_norm_op(ctx):
 @register_op("rotary_embedding", infer_shape=_infer_out_like_x)
 def rotary_embedding_op(ctx):
     """X [batch, seq, heads, rotary size] -> the same, rows turned by
-    their positions 0..seq-1."""
+    their positions 0..seq-1; attr ``layout`` as ``rotary`` has it."""
     ctx.set_output("Out", rotary(raw_data(ctx.input("X")),
-                                 float(ctx.attr("theta", 10000.0))))
+                                 float(ctx.attr("theta", 10000.0)),
+                                 ctx.attr("layout", "interleaved")))
 
 
 @register_op("latent_attention", infer_shape=_infer_out_like_x)
@@ -133,16 +147,69 @@ def latent_attention_op(ctx):
         ctx.set_output("Out", (x.astype(jnp.float32) + a).astype(stream))
 
 
+def _residual(ctx, x, y, stream):
+    """x + y, or x + RMSNorm(y) where the op was given ``PostNormScale``
+    (the sandwich norm: applied to the half-layer's result BEFORE the
+    add); y f32, the sum in f32, handed on in the stream's dtype."""
+    if ctx.has_input("PostNormScale"):
+        with part_scope("post_norm"):
+            y = rms_norm(y, raw_data(ctx.input("PostNormScale")),
+                         ctx.attr("epsilon"))
+    return (x.astype(jnp.float32) + y).astype(stream)
+
+
+@register_op("grouped_attention", infer_shape=_infer_out_like_x)
+def grouped_attention_op(ctx):
+    """Out = X + N_post(W_o (o * sigmoid(h W_g))), h = RMSNorm(X): q = h
+    W_q as ``heads`` heads, k = h W_k and v = h W_v as ``kv_heads`` heads
+    (q head j reads k/v head j // (heads / kv_heads)), q and k each
+    RMS-normed over the head with a learned scale; with ``rotary`` their
+    whole heads turned by their positions (pairs (i, i + head_dim / 2));
+    causal softmax(q k^T head_dim^-1/2) v, with ``window`` > 0 over the
+    last ``window`` keys only (itself included). The attention kernels run
+    under the part scope ``attn_window`` (a window) or ``attn_full``."""
+    x = raw_data(ctx.input("X"))
+    operand, stream = _dtypes(ctx, x)
+    w_in, wq, wk, wv, w_qn, w_kn, wg, wo = (
+        raw_data(ctx.input(s)) for s in
+        ("NormScale", "WQ", "WK", "WV", "QNormScale", "KNormScale", "WG",
+         "WO"))
+    H, Hkv, D = (int(ctx.attr(a)) for a in ("heads", "kv_heads", "head_dim"))
+    window = int(ctx.attr("window", 0)) or None
+    eps = ctx.attr("epsilon")
+    B, S, _d = x.shape
+    x = x.astype(stream)
+    with part_scope("proj"):
+        h = rms_norm(x, w_in, eps)
+        normed = lambda w, n, scale: rms_norm(
+            _mm(h, w, operand).reshape(B, S, n, D), scale, eps, stream)
+        q, k = normed(wq, H, w_qn), normed(wk, Hkv, w_kn)
+        v = _mm(h, wv, operand).astype(stream).reshape(B, S, Hkv, D)
+    if ctx.attr("rotary", True):
+        with part_scope("rope"):
+            theta = float(ctx.attr("theta"))
+            q, k = rotary(q, theta, "half"), rotary(k, theta, "half")
+    with part_scope("attn_window" if window else "attn_full"):
+        o = attention(q, k, v, causal=True, scale=D ** -0.5, window=window)
+    with part_scope("gate"):
+        gate = jax.nn.sigmoid(_mm(h, wg, operand))
+        o = (o.reshape(B, S, H * D).astype(jnp.float32) * gate).astype(stream)
+    with part_scope("proj"):
+        a = _mm(o, wo, operand)
+    ctx.set_output("Out", _residual(ctx, x, a, stream))
+
+
 @register_op("gated_ffn", infer_shape=_infer_out_like_x)
 def gated_ffn_op(ctx):
-    """Out = X + (silu(h W_gate) * (h W_up)) W_down, h = RMSNorm(X)."""
+    """Out = X + (silu(h W_gate) * (h W_up)) W_down, h = RMSNorm(X); with
+    ``PostNormScale`` the products' result is normed before the add."""
     x = raw_data(ctx.input("X"))
     operand, stream = _dtypes(ctx, x)
     x = x.astype(stream)
     h = rms_norm(x, raw_data(ctx.input("NormScale")), ctx.attr("epsilon"))
     y = gated(h, raw_data(ctx.input("WGate")), raw_data(ctx.input("WUp")),
               raw_data(ctx.input("WDown")), operand)
-    ctx.set_output("Out", (x.astype(jnp.float32) + y).astype(stream))
+    ctx.set_output("Out", _residual(ctx, x, y, stream))
 
 
 def route(h, w_router, bias, top_k, scaling):
@@ -205,7 +272,10 @@ def moe_ffn_op(ctx):
     ``[count, ...]``) and leaves out what the others would add: g stays
     normalised over all the picks, no token is dropped, no capacity is
     set. The (token, pick) pairs are sorted by expert and the held ones
-    go through grouped products (``jax.lax.ragged_dot``).
+    go through grouped products (``jax.lax.ragged_dot``). With
+    ``PostNormScale`` the sum shared + held part is normed before the add:
+    the norm of a partial sum is no part of the whole layer's, so shares
+    that are to add up leave it off and norm their sum.
     ``Load`` int32[n_experts]: picks per expert this step; ``RowsHeld``
     int32[1]: pairs that fell on held experts."""
     x = raw_data(ctx.input("X"))
@@ -250,7 +320,7 @@ def moe_ffn_op(ctx):
         routed = combine(ys, order, inv).astype(jnp.float32).sum(axis=1)
     with part_scope("shared"):
         shared = gated(h, sg, su, sd, operand)
-    out = x.astype(jnp.float32) + (shared + routed).reshape(B, S, d)
-    ctx.set_output("Out", out.astype(stream))
+    ctx.set_output("Out", _residual(
+        ctx, x, (shared + routed).reshape(B, S, d), stream))
     ctx.set_output("Load", load)
     ctx.set_output("RowsHeld", jnp.sum(sizes).reshape(1))

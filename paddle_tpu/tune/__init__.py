@@ -68,8 +68,12 @@ _counters_lock = threading.Lock()
 _counters = {"tune_hits": 0, "tune_misses": 0, "tune_fallbacks": 0}
 # {"fwd 256x256": launches traced}: the blocks each flash-attention kernel
 # launch was built with (kernels/flash_attention.py: _blocks), whoever
-# chose them — a cached winner or the kernel's own rule
+# chose them — a cached winner or the kernel's own rule; grouped k/v heads
+# and a window ride in the name ("fwd 512x512 g8 w2048")
 _flash_blocks = {}
+# the same names -> {"visited", "masked", "square"}: the score tiles one
+# head's launch visits, those that carry a mask, and the whole square's
+_flash_tiles = {}
 
 
 def _bump(name):
@@ -79,19 +83,31 @@ def _bump(name):
     profiler.update_tune_counters(**{name: 1})
 
 
-def count_flash_blocks(kernel, block_q, block_k):
+def count_flash_blocks(kernel, block_q, block_k, group=1, window=None,
+                       tiles=None):
     """One flash-attention launch (``kernel``: fwd / dq / dkv) traced at
-    (block_q, block_k)."""
+    (block_q, block_k), ``group`` q heads to a k/v head, under ``window``;
+    ``tiles``: what the launch visits (``flash_attention.tile_counts``)."""
     name = "%s %dx%d" % (kernel, block_q, block_k)
+    if group > 1:
+        name += " g%d" % group
+    if window is not None:
+        name += " w%d" % window
     with _counters_lock:
         _flash_blocks[name] = _flash_blocks.get(name, 0) + 1
+        if tiles is not None:
+            _flash_tiles[name] = dict(tiles)
 
 
 def counters():
     """Snapshot of the process-level dispatch counters; ``flash_blocks``
-    is the {"<kernel> <block_q>x<block_k>": launches} tally."""
+    is the {"<kernel> <block_q>x<block_k>[ g<group>][ w<window>]":
+    launches} tally and ``flash_tiles`` each name's {"visited", "masked",
+    "square"} score tiles a head (of the launch traced last under it)."""
     with _counters_lock:
-        return dict(_counters, flash_blocks=dict(_flash_blocks))
+        return dict(_counters, flash_blocks=dict(_flash_blocks),
+                    flash_tiles={k: dict(v)
+                                 for k, v in _flash_tiles.items()})
 
 
 def reset_counters():
@@ -100,6 +116,7 @@ def reset_counters():
         for k in _counters:
             _counters[k] = 0
         _flash_blocks.clear()
+        _flash_tiles.clear()
     profiler.reset_tune_counters()
 
 

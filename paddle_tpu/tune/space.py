@@ -192,7 +192,9 @@ class FlashAttentionSpace(KernelSpace):
     """Block space of kernels/flash_attention.py.
 
     key: {b, s, h, d, causal, dtype}, and ``dv`` where the v heads' width
-    differs from ``d``. The kernels run a sequence at ``padded_len(s)``
+    differs from ``d``, ``hkv`` where k and v have fewer heads than q (q
+    head j reads k/v head j // (h / hkv)), ``window`` where a causal call
+    sees only that many keys back. The kernels run a sequence at ``padded_len(s)``
     whatever the blocks, so a block has to divide that; the other
     constraints are alignment and what the three kernels keep in VMEM —
     the forward's and dQ's resident k/v and the dK/dV kernel's whole-
@@ -215,6 +217,10 @@ class FlashAttentionSpace(KernelSpace):
         return (padded_len(key["s"]), key["d"], key.get("dv", key["d"]),
                 key["dtype"])
 
+    @staticmethod
+    def _group(key):
+        return key["h"] // key.get("hkv", key["h"])
+
     def default_config(self, key):
         """What a tune-cache miss runs: the kernels' own rule for the key.
         A config is one pair for all three kernels, so where their picks
@@ -223,7 +229,8 @@ class FlashAttentionSpace(KernelSpace):
         from ..kernels.flash_attention import KERNELS, default_blocks
         s, d, dv, dtype = self._call(key)
         picks = [default_blocks(kernel, s, s, d, dv, dtype,
-                                bool(key.get("causal", False)))
+                                bool(key.get("causal", False)),
+                                self._group(key), key.get("window"))
                  for kernel in KERNELS]
         return {"block_q": min(bq for bq, _ in picks),
                 "block_k": min(bk for _, bk in picks)}
@@ -251,16 +258,18 @@ class FlashAttentionSpace(KernelSpace):
         s, d, dv, dtype = self._call(key)
         bq = min(int(config["block_q"]), s)
         bk = min(int(config["block_k"]), s)
-        return max(vmem_bytes(kernel, bq, bk, s, s, d, dv, _itemsize(dtype))
+        return max(vmem_bytes(kernel, bq, bk, s, s, d, dv, _itemsize(dtype),
+                              self._group(key))
                    for kernel in KERNELS)
 
     def make_operands(self, key, seed=0):
         import jax.numpy as jnp
         rng = np.random.RandomState(seed)
-        shape = (key["b"], key["s"], key["h"], key["d"])
-        q = jnp.asarray(rng.randn(*shape), key["dtype"])
-        k = jnp.asarray(rng.randn(*shape), key["dtype"])
-        v = jnp.asarray(rng.randn(*shape[:3], key.get("dv", key["d"])),
+        b, s, h, d = key["b"], key["s"], key["h"], key["d"]
+        hkv = key.get("hkv", h)
+        q = jnp.asarray(rng.randn(b, s, h, d), key["dtype"])
+        k = jnp.asarray(rng.randn(b, s, hkv, d), key["dtype"])
+        v = jnp.asarray(rng.randn(b, s, hkv, key.get("dv", d)),
                         key["dtype"])
         return (q, k, v)
 
@@ -268,25 +277,26 @@ class FlashAttentionSpace(KernelSpace):
         import jax
         from ..kernels.flash_attention import flash_attention
         causal = bool(key.get("causal", False))
-        cfg = dict(config)
+        cfg, window = dict(config), key.get("window")
 
         @jax.jit
         def fn(q, k, v):
-            return flash_attention(q, k, v, causal=causal, config=cfg)
+            return flash_attention(q, k, v, causal=causal, config=cfg,
+                                   window=window)
 
         return fn
 
     def reference(self, key):
         import jax
         from ..kernels.flash_attention import _dense_reference
-        causal = bool(key.get("causal", False))
+        causal, window = bool(key.get("causal", False)), key.get("window")
 
         @jax.jit
         def fn(q, k, v):
             B, S, H, D = q.shape
             t = lambda a: a.transpose(0, 2, 1, 3).reshape(
-                B * H, S, a.shape[3])
-            o = _dense_reference(t(q), t(k), t(v), causal, D ** -0.5)
+                B * a.shape[2], S, a.shape[3])
+            o = _dense_reference(t(q), t(k), t(v), causal, D ** -0.5, window)
             return o.reshape(B, H, S, v.shape[3]).transpose(0, 2, 1, 3)
 
         return fn

@@ -143,6 +143,41 @@ def test_flash_attention_rule_picks_compile(one_chip, bh, s, d, dv):
         _compile(fn, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["w2048", "full"])
+@pytest.mark.parametrize("kernels", ["fwd", "bwd"])
+def test_flash_attention_grouped_heads_and_window_compile(one_chip, kernels,
+                                                          window):
+    """The three kernels at the window / full cell's own shape: 32 query
+    heads on 4 key/value heads of 128, 8,192 positions, bf16, under a
+    2,048-key window and under none. k and v enter with 4 heads (nothing
+    repeats them), the rule's blocks are 512 x 512, and the dK/dV kernel
+    leaves a q head's part in f32."""
+    fa = _kernel_module("flash_attention")
+    h, hkv, s, d = 32, 4, 8192, 128
+    for kernel in fa.KERNELS:
+        assert fa.default_blocks(kernel, s, s, d, d, jnp.bfloat16, True,
+                                 h // hkv, window) == (512, 512)
+        assert fa.vmem_bytes(kernel, 512, 512, s, s, d, d, 2,
+                             h // hkv) <= fa.VMEM_LIMIT
+
+    def fwd(q, k, v):
+        return fa._fa_forward(q, k, v, True, d ** -0.5, s, interpret=False,
+                              window=window)
+
+    def bwd(q, k, v, do, lse, delta):
+        return fa._fa_backward(q, k, v, do, lse, delta, True, d ** -0.5, s,
+                               interpret=False, window=window)
+
+    q, kv = ((h, s, d), jnp.bfloat16), ((hkv, s, d), jnp.bfloat16)
+    row = ((h, s), jnp.float32)
+    if kernels == "fwd":
+        text = _compile(fwd, one_chip, q, kv, kv)
+    else:
+        text = _compile(bwd, one_chip, q, kv, kv, q, row, row)
+        assert text.count("tpu_custom_call") >= 2
+        assert "f32[%d,%d,%d]" % (h, s, d) in text      # per q head, f32
+
+
 def test_flash_attention_vmem_reckoning_refuses_before_mosaic(one_chip,
                                                               monkeypatch):
     """8,192 positions of 192 / 128-wide heads at 512 x 512: Mosaic

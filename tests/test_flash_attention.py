@@ -503,3 +503,139 @@ def test_flash_blocks_counts_only_the_large_shape_for_the_decoder_at_4096():
 def test_flash_blocks_at_128_positions_is_one_tile():
     counted = _decoder_step_blocks(128)
     assert {n.split()[1] for n in counted} == {"128x128"}, counted
+
+
+# ---------------------------------------------------------------------------
+# grouped k/v heads and a sliding window
+
+
+def _needs(S, bq, bk, window):
+    """{(q tile, k tile): whether it has to be masked} of the tiles that
+    hold a seen pair (k <= q, q - k < window), from the positions."""
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    seen = (j <= i) & ((i - j < window) if window else True)
+    out = {}
+    for qi in range(S // bq):
+        for ki in range(S // bk):
+            tile = seen[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            if tile.any():
+                out[qi, ki] = not tile.all()
+    return out
+
+
+@pytest.mark.parametrize("bk", [128, 256, 512])
+@pytest.mark.parametrize("bq", [128, 256, 512])
+@pytest.mark.parametrize("window", [1, 100, 128, 129, 300, 512, 1022, 1023,
+                                    2048, 5000])
+def test_banded_kernels_visit_the_band_and_mask_its_edges(bq, bk, window):
+    """Whatever the widths and the window: a banded kernel visits exactly
+    the tiles that hold a seen pair, none under the band's lower edge and
+    none above the diagonal; it masks exactly those an edge crosses, with
+    the mask of THAT edge; and ``flash_tiles`` counts the same tiles
+    (forward / dQ by q block, dK/dV by k block)."""
+    S = 2048
+    want = _needs(S, bq, bk, window)
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+
+    def edges(qi, ki):
+        """(the diagonal crosses the tile, the band's lower edge does)."""
+        d = (i - j)[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+        return bool((d < 0).any()), bool((d >= window).any())
+
+    def visited(segments, pair):
+        got = {}
+        for masks, lo, hi in segments:
+            for t in range(lo, hi):
+                assert pair(t) not in got
+                assert (bool(masks.get("causal")),
+                        masks.get("window") is not None) == edges(*pair(t))
+                assert masks.get("window") in (None, window)
+                got[pair(t)] = bool(masks.get("causal")
+                                    or masks.get("window"))
+        return got
+
+    by_q, by_k = {}, {}
+    for qi in range(S // bq):
+        by_q.update(visited(
+            fa._k_segments(qi * bq, bq, bk, S, S, True, window),
+            lambda ki: (qi, ki)))
+    for ki in range(S // bk):
+        by_k.update(visited(
+            fa._q_segments(ki * bk, bk, bq, S // bq, True, None, window),
+            lambda qi: (qi, ki)))
+    assert by_q == want and by_k == want
+    for kernel in fa.KERNELS:
+        assert fa.tile_counts(kernel, bq, bk, S, S, S, True, window) == {
+            "visited": len(want), "masked": sum(want.values()),
+            "square": (S // bq) * (S // bk)}
+
+
+def test_the_cells_banded_launch_visits_70_of_256_tiles():
+    """8,192 positions, a 2,048-key window, 512 x 512: 70 tiles a head
+    (28 of them masked) where the triangle has 136 (16 masked)."""
+    for kernel in fa.KERNELS:
+        assert fa.tile_counts(kernel, 512, 512, 8192, 8192, 8192, True,
+                              2048) == {"visited": 70, "masked": 28,
+                                        "square": 256}
+        assert fa.tile_counts(kernel, 512, 512, 8192, 8192, 8192, True) == {
+            "visited": 136, "masked": 16, "square": 256}
+
+
+def test_a_window_narrower_than_its_tiles_narrows_them():
+    """The rule under a window: no block wider than the window rounded up
+    to 128; a 2,048-key window leaves it as it is; the group changes the
+    dK/dV kernel's result blocks (f32) and nothing else."""
+    args = (8192, 8192, 128, 128, BF16, True)
+    for kernel in fa.KERNELS:
+        assert fa.default_blocks(kernel, *args, 8, 2048) == (512, 512)
+        assert fa.default_blocks(kernel, *args, 8, 200) == (256, 256)
+        assert fa.default_blocks(kernel, *args, 1, 64) == (128, 128)
+    assert fa.vmem_bytes("dkv", 512, 512, 8192, 8192, 128, 128, 2, 8) \
+        - fa.vmem_bytes("dkv", 512, 512, 8192, 8192, 128, 128, 2) \
+        == 2 * 2 * 512 * 128 * (4 - 2)
+    for kernel in ("fwd", "dq"):
+        assert fa.vmem_bytes(kernel, 512, 512, 8192, 8192, 128, 128, 2, 8) \
+            == fa.vmem_bytes(kernel, 512, 512, 8192, 8192, 128, 128, 2)
+
+
+@pytest.mark.parametrize("bad", [
+    dict(window=0), dict(window=16, causal=False), dict(hkv=3)])
+def test_a_call_the_kernels_cannot_honour_raises(bad):
+    q = jnp.zeros((1, 64, 4, 16))
+    kv = jnp.zeros((1, 64, bad.get("hkv", 2), 16))
+    with pytest.raises(ValueError):
+        fa.flash_attention(q, kv, kv, causal=bad.get("causal", True),
+                           window=bad.get("window"))
+
+
+def test_the_attention_op_takes_grouped_heads_and_a_window():
+    """Through the layers DSL: the ``flash_attention`` op with 4 q heads on
+    2 k/v heads and a window of 24, against the dense composition; the
+    tune key carries ``hkv`` and ``window``."""
+    import paddle_tpu as pt
+    from paddle_tpu import layers as L
+    from test_flash_attention_grouped import _dense_grouped
+    S, D = 96, 16
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        q = L.data("q", shape=[S, 4, D], dtype="float32")
+        k = L.data("k", shape=[S, 2, D], dtype="float32")
+        v = L.data("v", shape=[S, 2, D], dtype="float32")
+        out = L.flash_attention(q, k, v, causal=True, window=24)
+    rng = np.random.RandomState(3)
+    feed = {n: rng.randn(2, S, h, D).astype(np.float32)
+            for n, h in (("q", 4), ("k", 2), ("v", 2))}
+    keys = []
+    real = tune.lookup
+    try:
+        tune.lookup = lambda name, key, **kw: (keys.append(key),
+                                               real(name, key, **kw))[1]
+        with pt.scope_guard(scope):
+            got, = pt.Executor(pt.CPUPlace()).run(main, feed=feed,
+                                                  fetch_list=[out])
+    finally:
+        tune.lookup = real
+    want, _lse = _dense_grouped(*(jnp.asarray(feed[n]) for n in "qkv"), 24)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-4, atol=2e-5)
+    assert keys and keys[0]["hkv"] == 2 and keys[0]["window"] == 24
+    assert keys[0]["h"] == 4

@@ -101,6 +101,9 @@ def report(module, steps, rows, counters=None):
                  counters["tune_fallbacks"],
                  ", ".join("%s x%d" % kv for kv in sorted(blocks.items()))
                  or "none"))
+        for name, t in sorted(counters.get("flash_tiles", {}).items()):
+            print("flash_tiles %s: %d visited, %d masked, %d in the square"
+                  % (name, t["visited"], t["masked"], t["square"]))
     for scope, ms, n in by_scope(rows, steps):
         print("  %-28s %8.3f ms %5d ops" % (scope, ms, n))
     for scope in DETAILED:
