@@ -776,16 +776,21 @@ def _step_name(program, feed_template, fetch_names, repeat, dist):
 
 def _held_step_compiles_and_fits(fn, args, limit):
     """``_held_step_fits`` of the held step ``fn`` compiled for ``args``
-    (state, feed, key, spare). Two sets of state that alone exceed the
-    device's limit need no compile to say no; and where XLA itself refuses
-    the step for the device's memory (XLA:TPU raises RESOURCE_EXHAUSTED at
-    compile time, it reports no analysis), that is a no as well, not an
-    error: the loop then dispatches from donated state."""
+    (state, feed, key, spare). The rule asks for the step's arguments,
+    which hold both sets of state, and one more copy of its outputs,
+    which hold the new state: where three times the state alone exceeds
+    the device's limit no compile can say yes, and none is made (a step
+    that XLA compiles only to be told no costs every run that compile,
+    which no cache keeps). And where XLA itself refuses the step for the
+    device's memory (XLA:TPU raises RESOURCE_EXHAUSTED at compile time, it
+    reports no analysis), that is a no as well, not an error: the loop
+    then dispatches from donated state."""
     if limit is not None:
-        held = sum(int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
-                   for tree in (args[0], args[3])
-                   for v in jax.tree_util.tree_leaves(tree))
-        if held > limit:
+        state, spare = (
+            sum(int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
+                for v in jax.tree_util.tree_leaves(tree))
+            for tree in (args[0], args[3]))
+        if state + spare + state > limit:
             return False
     try:
         mem = fn.memory(*args)
@@ -900,7 +905,9 @@ class Executor(object):
         # launch was traced at ({"fwd 512x512": n, ...}; grouped heads and
         # a window ride in the name, "fwd 512x512 g8 w2048") and
         # flash_tiles the score tiles each such launch visits / masks /
-        # has in its square
+        # has in its square; moe_rungs the tally of the row counts each
+        # expert layer's held part was traced at ({"49152 ->
+        # 12288/24576/49152": n}; ops/decoder_ops.py: held_rungs)
         # comm_path says HOW the last compiled program's DP grads sync:
         # "explicit" = routed through the paddle_tpu.comm collectives
         # (comm_* stats measured from the traced plan), "model" = GSPMD
@@ -920,7 +927,7 @@ class Executor(object):
                       "comm_path": "",
                       "tune_hits": 0, "tune_misses": 0,
                       "tune_fallbacks": 0, "flash_blocks": {},
-                      "flash_tiles": {},
+                      "flash_tiles": {}, "moe_rungs": {},
                       "elastic_resizes": 0, "elastic_lost_ranks": 0,
                       "elastic_requeued_tasks": 0,
                       "elastic_resume_ms": 0.0,

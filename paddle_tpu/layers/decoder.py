@@ -19,7 +19,7 @@ from .layer_helper import LayerHelper
 
 __all__ = ["flash_attention", "rms_norm", "rotary_embedding",
            "latent_attention", "grouped_attention", "gated_ffn", "moe_ffn",
-           "moe_load_stats"]
+           "moe_load_stats", "moe_rows_moved"]
 
 
 def _named(prefix, name):
@@ -186,10 +186,15 @@ def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
     ``experts_held = (first, count)`` says which experts THIS program
     holds (None: all): their weights are three stacked parameters
     ``[count, ...]``, and what the absent experts would add is left out;
-    nothing is dropped and no capacity is set. Returns ``(out, load,
-    rows_held)``: ``load`` int32[num_experts] counts this step's picks per
-    expert, ``rows_held`` int32[1] the (token, pick) pairs on held
-    experts. With ``post_norm`` shared + held part is normed before the
+    no capacity is set, nothing is dropped; the held part runs over the
+    smallest rung that holds the step's ``RowsHeld``: the held pairs are a
+    prefix of the pairs sorted by expert, and the gathers and grouped
+    products run over the shortest of a few static lengths that holds this
+    step's prefix (``ops/decoder_ops.py: held_rungs``: 2x and 4x the
+    expected share, then all the pairs), picked on the device each step.
+    Returns ``(out, load, rows_held)``: ``load`` int32[num_experts] counts
+    this step's picks per expert, ``rows_held`` int32[1] the (token, pick)
+    pairs on held experts. With ``post_norm`` shared + held part is normed before the
     add (``<prefix>.post_norm``); the norm of a share's partial sum is no
     part of the whole layer's norm, so shares that are to add up leave it
     off. Parameters ``<prefix>.{norm,router,router_bias,expert_gate,
@@ -239,3 +244,14 @@ def moe_load_stats(load, rows_held):
     return {"moe_rows_held": int(np.asarray(rows_held).sum()),
             "moe_max_over_mean_load": float(load.max()
                                             / max(load.mean(), 1e-30))}
+
+
+def moe_rows_moved(rows_held, pairs, count, num_experts):
+    """The rows the held part of a ``moe_ffn`` over ``pairs`` (token, pick)
+    pairs that holds ``count`` of ``num_experts`` moved in a step whose
+    fetched ``rows_held`` this is: the first of ``held_rungs`` that holds
+    them, as the step's own ``lax.switch`` picked it."""
+    from ..ops.decoder_ops import held_rungs
+    held = int(np.asarray(rows_held).sum())
+    return next(r for r in held_rungs(pairs, count, num_experts)
+                if r >= held)

@@ -23,8 +23,12 @@ always.
 """
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.executor import raw_data
 from ..core.registry import register_op
@@ -147,15 +151,22 @@ def latent_attention_op(ctx):
         ctx.set_output("Out", (x.astype(jnp.float32) + a).astype(stream))
 
 
-def _residual(ctx, x, y, stream):
-    """x + y, or x + RMSNorm(y) where the op was given ``PostNormScale``
-    (the sandwich norm: applied to the half-layer's result BEFORE the
-    add); y f32, the sum in f32, handed on in the stream's dtype."""
-    if ctx.has_input("PostNormScale"):
+def _add_normed(x, y, stream, eps, scale=None):
+    """x + y, or x + RMSNorm(y) under ``scale`` (the sandwich norm: applied
+    to the half-layer's result BEFORE the add); y f32, the sum in f32,
+    handed on in the stream's dtype."""
+    if scale is not None:
         with part_scope("post_norm"):
-            y = rms_norm(y, raw_data(ctx.input("PostNormScale")),
-                         ctx.attr("epsilon"))
+            y = rms_norm(y, scale, eps)
     return (x.astype(jnp.float32) + y).astype(stream)
+
+
+def _residual(ctx, x, y, stream):
+    """``_add_normed`` with the norm where the op was given
+    ``PostNormScale``."""
+    scale = raw_data(ctx.input("PostNormScale")) \
+        if ctx.has_input("PostNormScale") else None
+    return _add_normed(x, y, stream, ctx.attr("epsilon"), scale)
 
 
 @register_op("grouped_attention", infer_shape=_infer_out_like_x)
@@ -226,42 +237,157 @@ def route(h, w_router, bias, top_k, scaling):
     return idx, g
 
 
-def _dispatch_combine(k):
-    """The two row movements of an expert layer over the T*k (token, pick)
-    pairs sorted by expert, each a gather in both directions: ``order`` is
-    a permutation of the pairs and ``inv`` its inverse, which autodiff
-    cannot know (it would scatter-add). ``live`` marks the sorted rows that
-    belong to a held expert: the grouped products neither read nor write
-    the others, so what comes back for them is dropped, not summed."""
+def held_rungs(pairs, count, n_experts):
+    """The static row counts an expert layer that holds ``count`` of
+    ``n_experts`` compiles its held part at, ascending: 2x and 4x the
+    expected held share of the ``pairs`` (token, pick) pairs, each rounded
+    up to a multiple of 512 and capped at ``pairs``, then ``pairs`` itself
+    (so no pair is ever left out), duplicates dropped. A layer that holds
+    every expert has one rung."""
+    expected = pairs * count / float(n_experts)
+    rungs = [min(pairs, -(-int(math.ceil(expected * f)) // 512) * 512)
+             for f in (2, 4)] + [pairs]
+    return tuple(sorted(set(rungs)))
+
+
+def _held_rung(rows, k, operand, stream):
+    """The held experts' part of the routed sum over the first ``rows`` of
+    the sorted (token, pick) pairs: ``fn(h, g, eg, eu, ed, order, inv,
+    sizes) -> [T, d] f32``. Right whenever ``sum(sizes) <= rows``. Its two
+    row movements are gathers in both directions (``inv`` is the inverse
+    of the permutation ``order``, which autodiff cannot know: it would
+    scatter-add): a token's picks read row ``inv`` of the ``rows``
+    results, and a pick whose row lies past them (it is not held) reads
+    the zero it added when every pair moved. (A ``rows``-row scatter-add
+    into [T, d] was timed beside it, ``PERF.md`` section 6, PR 35: 1.5 ms
+    a layer ahead at the first rung, behind at the last, and XLA:TPU adds
+    its f32 rows at bf16's precision, which is another sum.)"""
+
+    def summed_picks(a, inv):    # sorted [rows, d] -> [T, d] f32
+        # (indexed [T, k] at once: a [T * k, d] result reshaped to
+        # [T, k, d] is a copy on the chip where k is no multiple of 8)
+        inv = inv.reshape(-1, k)
+        got = a[jnp.minimum(inv, rows - 1)]
+        if rows < inv.size:
+            got = jnp.where((inv < rows)[..., None], got, 0)
+        return got.astype(jnp.float32).sum(axis=1)
 
     @jax.custom_vjp
-    def dispatch(h, order, inv, live):      # [T, d] -> [T*k, d], sorted
-        return h[order // k]
+    def dispatch(h, o, inv, live):          # [T, d] -> [rows, d], sorted
+        return h[o // k]
 
-    def dispatch_fwd(h, order, inv, live):
-        return dispatch(h, order, inv, live), (inv, live)
+    def dispatch_fwd(h, o, inv, live):
+        return dispatch(h, o, inv, live), (inv, live)
 
-    def dispatch_bwd(res, g):
+    def dispatch_bwd(res, ct):
         inv, live = res
-        g = jnp.where(live[:, None], g, 0)
-        back = g[inv].reshape(-1, k, g.shape[1]).astype(jnp.float32)
-        return back.sum(axis=1).astype(g.dtype), None, None, None
+        back = summed_picks(jnp.where(live, ct, 0), inv)
+        return back.astype(ct.dtype), None, None, None
 
     dispatch.defvjp(dispatch_fwd, dispatch_bwd)
 
     @jax.custom_vjp
-    def combine(ys, order, inv):            # sorted [T*k, d] -> [T, k, d]
-        return ys[inv].reshape(-1, k, ys.shape[1])
+    def combine(ys, o, inv):                # sorted [rows, d] -> [T, d] f32
+        return summed_picks(ys, inv)
 
-    def combine_fwd(ys, order, inv):
-        return combine(ys, order, inv), (order, inv)
+    def combine_fwd(ys, o, inv):
+        return combine(ys, o, inv), o
 
-    def combine_bwd(res, g):
-        order, _inv = res
-        return g.reshape(-1, g.shape[2])[order], None, None
+    def combine_bwd(o, ct):
+        # row j of the sorted pairs is pick o[j] % k of token o[j] // k, and
+        # every pick of a token has the token's cotangent; ``ys`` is of the
+        # stream's dtype
+        return ct[o // k].astype(stream), None, None
 
     combine.defvjp(combine_fwd, combine_bwd)
-    return dispatch, combine
+
+    def fn(h, g, eg, eu, ed, order, inv, sizes):
+        o = order[:rows]
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        with part_scope("route"):
+            xs = dispatch(h, o, inv, live)
+        # (XLA:TPU makes ``ragged-dot-*`` kernels of the ragged dots under
+        # an ``op_name`` of its own: the scope table calls those unscoped)
+        with part_scope("experts"):
+            # rows past the held pairs belong to no group and a grouped
+            # product leaves there whatever the buffer held (NaN on the
+            # chip): every result is SELECTED by ``live`` before anything
+            # multiplies it, so that neither the values nor their gradients
+            # meet the garbage
+            rd = lambda a, w: jnp.where(live, jax.lax.ragged_dot(
+                a.astype(operand), w.astype(operand), sizes,
+                preferred_element_type=jnp.float32), 0.0)
+            ys = rd(jax.nn.silu(rd(xs, eg)) * rd(xs, eu), ed)
+        with part_scope("route"):
+            ys = (ys * g.reshape(-1)[o][:, None]).astype(stream)
+            return combine(ys, o, inv)
+
+    return fn
+
+
+@functools.lru_cache(maxsize=None)
+def _held_part(rungs, k, operand, stream, epsilon):
+    """``fn(moved, order, inv, sizes)`` -> the layer's result [B, S, d],
+    where ``moved = (h, g, eg, eu, ed, x, shared[, post norm scale])`` are
+    the operands a gradient flows to: ``x + N(shared + routed)`` with
+    ``routed`` [T, d] f32 ``_held_rung`` at the smallest of ``rungs``
+    (ascending, the last one all the pairs) that holds the step's
+    ``sum(sizes)``, chosen on the device.
+    One rung: that rung, no conditional. More: ONE ``custom_vjp`` whose
+    forward is a ``lax.switch`` and whose backward is a second one, each
+    branch taking ``jax.vjp`` of its own rung on the operands, which are
+    the only residuals. (Left to autodiff, ``cond``'s partial evaluation
+    makes every branch return the union of all branches' residuals,
+    zero-filled for the others: the small rung would write the large
+    rungs' arrays.) The layer's sum, post norm and residual add sit inside
+    the branches so that nothing after the switch needs the forward's
+    result in the backward pass: the recomputed forward's switch is dead
+    there and the backward runs a rung's forward once.
+    A function of arrays only, kept one per key, each rung and each
+    rung's gradient an inlined ``jax.jit``: jax's own tracing cache then
+    answers every further layer of these shapes, the recomputed forward
+    and the backward's ``jax.vjp`` from the FIRST trace of the rung's
+    Python in the step's trace context and the first under
+    ``jax.checkpoint``'s (four body traces a layer otherwise, and a program
+    has several such layers). ``inline=True`` leaves no call in the lowered module:
+    every layer's and both phases' instructions keep their own scope."""
+
+    def whole(rows):
+        rung = _held_rung(rows, k, operand, stream)
+
+        def fn(moved, order, inv, sizes):
+            x, shared = moved[5:7]
+            routed = rung(*moved[:5], order, inv, sizes)
+            return _add_normed(x, (shared + routed).reshape(x.shape), stream,
+                               epsilon, *moved[7:])
+        return jax.jit(fn, inline=True)
+    fns = [whole(rows) for rows in rungs]
+    if len(fns) == 1:
+        return fns[0]
+    steps = np.asarray(rungs[:-1], np.int32)
+    rung_of = lambda sizes: jnp.sum(jnp.sum(sizes) > steps)
+
+    def grads(fn):
+        def branch(moved, order, inv, sizes, ct):
+            _out, vjp = jax.vjp(
+                lambda moved: fn(moved, order, inv, sizes), moved)
+            return vjp(ct)[0]
+        return jax.jit(branch, inline=True)
+    bwds = [grads(fn) for fn in fns]
+
+    @jax.custom_vjp
+    def held(moved, order, inv, sizes):
+        return jax.lax.switch(rung_of(sizes), fns, moved, order, inv, sizes)
+
+    def held_fwd(*args):
+        return held(*args), args
+
+    def held_bwd(args, ct):
+        return jax.lax.switch(rung_of(args[-1]), bwds, *args,
+                              ct), None, None, None
+
+    held.defvjp(held_fwd, held_bwd)
+    return held
 
 
 @register_op("moe_ffn", infer_shape=_infer_out_like_x)
@@ -270,12 +396,20 @@ def moe_ffn_op(ctx):
     of g_k E_k(h), h = RMSNorm(X). The router scores all ``n_experts``;
     this op holds the experts ``[first, first + count)`` (stacked weights
     ``[count, ...]``) and leaves out what the others would add: g stays
-    normalised over all the picks, no token is dropped, no capacity is
-    set. The (token, pick) pairs are sorted by expert and the held ones
-    go through grouped products (``jax.lax.ragged_dot``). With
-    ``PostNormScale`` the sum shared + held part is normed before the add:
-    the norm of a partial sum is no part of the whole layer's, so shares
-    that are to add up leave it off and norm their sum.
+    normalised over all the picks; no capacity is set, nothing is dropped;
+    the held part runs over the smallest rung that holds the step's
+    ``RowsHeld``. The (token, pick) pairs are sorted by expert, held
+    experts first, so the held ones are a prefix of the sorted order, and
+    only a prefix moves: the gathers, the grouped products
+    (``jax.lax.ragged_dot``) and the weighted sum back run over the first
+    ``C`` sorted pairs, ``C`` the smallest of ``held_rungs`` (static sizes,
+    the last one every pair) that holds this step's ``RowsHeld``, picked on
+    the device by a ``lax.switch`` (``_held_part``, which every expert
+    layer of one shape shares: a rung's Python is traced once, not once a
+    layer and pass). A layer that holds every expert has one rung and no
+    switch. With ``PostNormScale`` the sum shared + held part is normed
+    before the add: the norm of a partial sum is no part of the whole
+    layer's, so shares that are to add up leave it off and norm their sum.
     ``Load`` int32[n_experts]: picks per expert this step; ``RowsHeld``
     int32[1]: pairs that fell on held experts."""
     x = raw_data(ctx.input("X"))
@@ -290,7 +424,9 @@ def moe_ffn_op(ctx):
     T = B * S
     x = x.astype(stream)
     h = rms_norm(x, w_post, ctx.attr("epsilon")).reshape(T, d)
-    dispatch, combine = _dispatch_combine(k)
+    from .. import tune
+    rungs = held_rungs(T * k, count, n_experts)
+    tune.count_moe_rungs(T * k, rungs)
     with part_scope("route"):
         idx, g = route(h, w_router, bias, k, ctx.attr("scaling"))
         flat = idx.reshape(T * k)
@@ -302,25 +438,12 @@ def moe_ffn_op(ctx):
                             stable=True)
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(T * k, dtype=order.dtype))
-        live = held[order]
-        xs = dispatch(h, order, inv, live)
-    # (XLA:TPU makes ``ragged-dot-*`` kernels of the ragged dots under an
-    # ``op_name`` of its own: the scope table calls those unscoped)
-    with part_scope("experts"):
-        # rows past the held pairs belong to no group and a grouped product
-        # leaves there whatever the buffer held (NaN on the chip): every
-        # result is SELECTED by ``live`` before anything multiplies it, so
-        # that neither the values nor their gradients meet the garbage
-        rd = lambda a, w: jnp.where(live[:, None], jax.lax.ragged_dot(
-            a.astype(operand), w.astype(operand), sizes,
-            preferred_element_type=jnp.float32), 0.0)
-        ys = rd(jax.nn.silu(rd(xs, eg)) * rd(xs, eu), ed)
-    with part_scope("route"):
-        ys = (ys * g.reshape(T * k)[order][:, None]).astype(stream)
-        routed = combine(ys, order, inv).astype(jnp.float32).sum(axis=1)
     with part_scope("shared"):
         shared = gated(h, sg, su, sd, operand)
-    ctx.set_output("Out", _residual(
-        ctx, x, (shared + routed).reshape(B, S, d), stream))
+    post = (raw_data(ctx.input("PostNormScale")),) \
+        if ctx.has_input("PostNormScale") else ()
+    ctx.set_output("Out", _held_part(
+        rungs, k, operand, stream, float(ctx.attr("epsilon")))(
+        (h, g, eg, eu, ed, x, shared) + post, order, inv, sizes))
     ctx.set_output("Load", load)
     ctx.set_output("RowsHeld", jnp.sum(sizes).reshape(1))

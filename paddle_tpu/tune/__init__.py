@@ -55,7 +55,7 @@ __all__ = [
     "wall_timer", "model_timer", "table_timer", "time_best",
     "parity_ok", "parity_report",
     "lookup", "record_fallback", "counters", "reset_counters",
-    "count_flash_blocks",
+    "count_flash_blocks", "count_moe_rungs",
 ]
 
 # -- dispatch counters --------------------------------------------------------
@@ -74,6 +74,11 @@ _flash_blocks = {}
 # the same names -> {"visited", "masked", "square"}: the score tiles one
 # head's launch visits, those that carry a mask, and the whole square's
 _flash_tiles = {}
+# {"49152 -> 12288/24576/49152": launches traced}: the (token, pick) pairs
+# of each expert layer traced and the static row counts its held part was
+# compiled at (ops/decoder_ops.py: held_rungs); one rung: the layer holds
+# every expert and has no conditional
+_moe_rungs = {}
 
 
 def _bump(name):
@@ -99,15 +104,26 @@ def count_flash_blocks(kernel, block_q, block_k, group=1, window=None,
             _flash_tiles[name] = dict(tiles)
 
 
+def count_moe_rungs(pairs, rungs):
+    """One expert layer traced over ``pairs`` (token, pick) pairs with its
+    held part compiled at the row counts ``rungs``."""
+    name = "%d -> %s" % (pairs, "/".join("%d" % r for r in rungs))
+    with _counters_lock:
+        _moe_rungs[name] = _moe_rungs.get(name, 0) + 1
+
+
 def counters():
     """Snapshot of the process-level dispatch counters; ``flash_blocks``
     is the {"<kernel> <block_q>x<block_k>[ g<group>][ w<window>]":
-    launches} tally and ``flash_tiles`` each name's {"visited", "masked",
-    "square"} score tiles a head (of the launch traced last under it)."""
+    launches} tally, ``flash_tiles`` each name's {"visited", "masked",
+    "square"} score tiles a head (of the launch traced last under it) and
+    ``moe_rungs`` the {"<pairs> -> <rung>/<rung>/...": launches} tally of
+    the expert layers traced."""
     with _counters_lock:
         return dict(_counters, flash_blocks=dict(_flash_blocks),
                     flash_tiles={k: dict(v)
-                                 for k, v in _flash_tiles.items()})
+                                 for k, v in _flash_tiles.items()},
+                    moe_rungs=dict(_moe_rungs))
 
 
 def reset_counters():
@@ -117,6 +133,7 @@ def reset_counters():
             _counters[k] = 0
         _flash_blocks.clear()
         _flash_tiles.clear()
+        _moe_rungs.clear()
     profiler.reset_tune_counters()
 
 

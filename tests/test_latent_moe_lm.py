@@ -4,8 +4,10 @@ experts it holds) against the plain reference
 ``chipbench/reference/latent_moe_lm.py`` on seeded weights, CPU, float32,
 at a small size; whole and as one chip's share of an expert-parallel
 group."""
+import collections
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -315,6 +317,17 @@ def test_a_compiled_step_has_the_part_scopes_and_load_counts_every_pick():
                  "backward/moe_ffn/experts", "backward/moe_ffn/shared",
                  "forward/gated_ffn", "backward/gated_ffn", "update/adam"):
         assert want in scopes, (want, sorted(scopes))
+    # and where the held part is a switch over rungs, on the instructions
+    # INSIDE its branches, forward and backward
+    text = held_layer(True)["text"]
+    _module, table = profiler.scopes_of_module(text)
+    inside = set()
+    for branch in _branch_computations(text):
+        inside |= {table[i] for i in _instructions_of(text, branch)
+                   if i in table}
+    for want in ("forward/moe_ffn/route", "forward/moe_ffn/experts",
+                 "backward/moe_ffn/route", "backward/moe_ffn/experts"):
+        assert want in inside, (want, sorted(inside))
 
 
 def test_pure_amp_keeps_the_stream_bf16_and_the_masters_f32():
@@ -408,12 +421,15 @@ def test_a_compiler_made_kernel_stays_unscoped_beside_the_part_scopes():
         assert table[made_by_the_compiler] == "unscoped"
 
 
+@pytest.mark.parametrize("where", ["model", "rung"])
 def test_rows_of_no_group_never_reach_the_result_or_the_gradients(
-        monkeypatch):
+        monkeypatch, where):
     """On the chip a grouped product leaves whatever the buffer held (NaN,
     seen by ``chip_smoke.py``) in the rows past the held pairs, forward and
     in the gradient of its left operand. With NaN planted there the loss
-    and every gradient are what they are without."""
+    and every gradient are what they are without: in the small model (one
+    rung: all the pairs) and in a held share's rungs, whose rows past
+    ``RowsHeld`` are such rows too."""
     config = tiny(True)
     batch = batches(config, 17, 1)[0]
     real = jax.lax.ragged_dot
@@ -439,15 +455,345 @@ def test_rows_of_no_group_never_reach_the_result_or_the_gradients(
         return dead_rows_nan(d_lhs, sizes), d_rhs, None
 
     planted.defvjp(fwd, bwd)
-    got = []
+    got, traced = [], []
+
+    def plant_it(a, b, s, **_kw):
+        traced.append(a.shape)
+        return planted(a, b, s)
     for plant in (False, True):
+        # the held part keeps its traced rungs (``_held_part``): what was
+        # traced without the plant must not answer for the run with it
+        decoder_ops._held_part.cache_clear()
         if plant:
-            monkeypatch.setattr(jax.lax, "ragged_dot",
-                                lambda a, b, s, **_kw: planted(a, b, s))
+            monkeypatch.setattr(jax.lax, "ragged_dot", plant_it)
+        if where == "rung":
+            # 0, ~1.5x and ~3x the expected pairs held: the first rung
+            # twice and the second, each with dead rows inside it
+            layer = held_layer(True, cached=False)
+            runs = [layer["run"](b) for b in HELD_BIAS[:3]]
+            assert [r[1] for r in runs] == [layer["rungs"][0]] * 2 \
+                + [layer["rungs"][1]]
+            assert all(r[1] > int(r[0][1].sum()) for r in runs)
+            got.append([a for r in runs for a in r[0]])
+            names = ["loss", "rows_held", "x"] + layer["names"]
+            names = [n + "/%d" % i for i in range(3) for n in names]
+            continue
         exe, main, scope, out, names = program(config, 18)
         with pt.scope_guard(scope):
             got.append(exe.run(main, feed=feed_of(batch), fetch_list=[
                 out["loss"]] + [n + "@GRAD" for n in names]))
-    for name, a, b in zip(["loss"] + names, *got):
+        names = ["loss"] + names
+    decoder_ops._held_part.cache_clear()
+    assert traced
+    for name, a, b in zip(names, *got):
         assert np.isfinite(b).all(), name
         np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-8, err_msg=name)
+
+
+# -- the rungs of a held share ------------------------------------------------
+# 768 tokens x 4 picks over 32 experts of which the layer holds 4: 3,072
+# pairs, 384 of them expected on held experts, rungs 1,024 / 1,536 / 3,072.
+H_ROWS, H_SEQ, H_D, H_EXPERTS, H_K, H_HELD, H_SIZE = 2, 384, 64, 32, 4, \
+    (6, 4), 32
+H_PAIRS = H_ROWS * H_SEQ * H_K
+# what the router's bias adds on the held experts: no pick on them, ~1.5x
+# and ~3x the expected share, every pick
+HELD_BIAS = (-10.0, 0.05, 0.3, 10.0)
+H_PARAMS = ("norm", "router", "expert_gate", "expert_up", "expert_down",
+            "shared_gate", "shared_up", "shared_down")
+_held_layers = {}
+
+
+def _held_leaves():
+    rng = np.random.default_rng(23)
+    d, e, m, c = H_D, H_EXPERTS, H_SIZE, H_HELD[1]
+    shapes = [(d,), (d, e), (c, d, m), (c, d, m), (c, m, d), (d, 64), (d, 64),
+              (64, d)]
+    return [(1.0 + 0.1 * rng.standard_normal(sh) if len(sh) == 1
+             else 0.4 * rng.standard_normal(sh)).astype(np.float32)
+            for sh in shapes]
+
+
+def _held_x():
+    return np.random.default_rng(29).standard_normal(
+        (H_ROWS, H_SEQ, H_D)).astype(np.float32)
+
+
+def held_layer(remat, one_rung=False, cached=True):
+    """One ``moe_ffn`` that holds 4 of 32 experts under the loss mean(out^2),
+    with SGD at rate 0 (so that every gradient can be fetched): ``run(bias
+    on the held experts)`` -> ([loss, rows_held, d loss / d x, every
+    parameter's gradient], the rung the step ran at); ``text`` the
+    compiled step's text. ``one_rung``: lowered with ``held_rungs``
+    answering all the pairs, which is the program this layer had before it
+    had rungs."""
+    key = (remat, one_rung)
+    if cached and key in _held_layers:
+        return _held_layers[key]
+    from paddle_tpu.core import executor
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        x = L.data("x", shape=[H_SEQ, H_D], dtype="float32")
+        x.stop_gradient = False
+        out, _load, held = L.moe_ffn(
+            x, H_EXPERTS, H_K, H_SIZE, 64, experts_held=H_HELD,
+            scaling=2.448, prefix="m")
+        loss = L.mean(out * out)
+        pt.optimizer.SGDOptimizer(learning_rate=0.0).minimize(loss)
+        if remat:
+            pt.memory_optimize(main, remat_types=("moe_ffn",))
+    exe = pt.Executor(pt.CPUPlace())
+    names = ["m." + n for n in H_PARAMS]
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        for name, leaf in zip(names, _held_leaves()):
+            scope.set_var(name, leaf)
+    rungs = decoder_ops.held_rungs(H_PAIRS, H_HELD[1], H_EXPERTS)
+    fetch = [loss, held, "x@GRAD"] + [n + "@GRAD" for n in names]
+
+    def run(bias):
+        b = np.zeros(H_EXPERTS, np.float32)
+        b[H_HELD[0]:H_HELD[0] + H_HELD[1]] = bias
+        real = decoder_ops.held_rungs
+        if one_rung:
+            decoder_ops.held_rungs = lambda pairs, _c, _n: (pairs,)
+        try:
+            with pt.scope_guard(scope):
+                scope.set_var("m.router_bias", b)
+                got = exe.run(main, feed={"x": _held_x()}, fetch_list=fetch)
+        finally:
+            decoder_ops.held_rungs = real
+        return got, L.moe_rows_moved(got[1], H_PAIRS, H_HELD[1], H_EXPERTS)
+
+    layer = {"run": run, "names": names, "rungs": rungs, "exe": exe}
+    run(0.0)                    # traced and compiled here, under this key
+    step = list(executor.compiled_steps())[-1]
+    layer["text"] = step.fn.lower(*step._avals).compile().as_text()
+    if cached:
+        _held_layers[key] = layer
+    return layer
+
+
+def _branch_computations(text):
+    out = []
+    for line in text.splitlines():
+        if " conditional(" in line:
+            out += re.search(r"branch_computations=\{([^}]*)\}",
+                             line).group(1).replace("%", "").split(", ")
+    return out
+
+
+def _instructions_of(text, computation):
+    """Names of the instructions in ``computation``'s body."""
+    names, inside = [], False
+    for line in text.splitlines():
+        if not inside:
+            inside = re.match(r"\s*%?" + re.escape(computation) + r"\s*\(",
+                              line) is not None and line.rstrip().endswith("{")
+        elif line.strip() == "}":
+            break
+        else:
+            m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s", line)
+            if m:
+                names.append(m.group(1))
+    return names
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
+@pytest.mark.parametrize("bias", HELD_BIAS,
+                         ids=["none", "x1.5", "x3", "every_pick"])
+def test_every_rung_gives_the_loss_and_gradients_of_the_one_rung_lowering(
+        remat, bias):
+    """The rung follows the DATA (the router's bias on the held experts),
+    and at each the step is the step of the layer lowered with one rung."""
+    layer, plain = held_layer(remat), held_layer(remat, one_rung=True)
+    assert layer["rungs"] == (1024, 1536, 3072)
+    (got, rung), (want, _all) = layer["run"](bias), plain["run"](bias)
+    held = int(got[1].sum())
+    assert held == int(want[1].sum())
+    expected = H_PAIRS * H_HELD[1] / H_EXPERTS
+    lo, hi, at = {-10.0: (0, 0, 1024), 0.05: (1.3, 1.8, 1024),
+                  0.3: (2.7, 3.6, 1536), 10.0: (8, 8, 3072)}[bias]
+    assert lo * expected <= held <= hi * expected, held
+    assert rung == at
+    for name, a, b in zip(["loss", "rows_held", "x"] + layer["names"], got,
+                          want):
+        scale = float(np.abs(b).max())
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6 * scale,
+                                   err_msg=name)
+
+
+def test_at_the_top_rung_no_pick_is_dropped_and_the_layer_is_the_dense_one():
+    """Every pick pushed onto the 4 held experts (k = 4): all 3,072 pairs
+    are held, the step runs at the last rung, and result and gradients are
+    the plain reference's, which loops over the experts and sorts nothing."""
+    layer = held_layer(True)
+    got, rung = layer["run"](10.0)
+    assert int(got[1].sum()) == min(H_K, H_HELD[1]) * H_ROWS * H_SEQ \
+        == H_PAIRS == rung == layer["rungs"][-1]
+    config = dict(n_routed_experts=H_EXPERTS, experts_held=list(H_HELD),
+                  num_experts_per_tok=H_K, routed_scaling_factor=2.448,
+                  rms_norm_eps=1e-6)
+    bias = jnp.zeros(H_EXPERTS).at[H_HELD[0]:sum(H_HELD)].set(10.0)
+
+    def loss_fn(x, leaves):
+        out, _picks = ref.expert_ffn(x.reshape(-1, H_D), leaves, bias,
+                                     config, "f32", None)
+        return jnp.mean(out * out)
+    leaves = [jnp.asarray(l) for l in _held_leaves()]
+    loss, (dx, dleaves) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
+        jnp.asarray(_held_x()), leaves)
+    np.testing.assert_allclose(float(got[0].reshape(())), float(loss),
+                               rtol=2e-6)
+    for name, a, b in zip(["x"] + layer["names"], got[2:],
+                          [dx.reshape(got[2].shape)] + list(dleaves)):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, np.asarray(b), rtol=2e-4,
+                                   atol=2e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("pairs,count,experts,want", [
+    (8192 * 6, 16, 128, (12288, 24576, 49152)),         # K's expert layers
+    (8192 * 8, 16, 128, (16384, 32768, 65536)),         # T's
+    (8192 * 6, 128, 128, (49152,)),                     # every expert held
+    (128, 4, 8, (128,)),                                # the small models
+    (3072, 16, 32, (3072,)),                            # 2x is all the pairs
+    (3072, 8, 32, (1536, 3072)),                        # 4x is
+    (3000, 2, 16, (1024, 1536, 3000)),                  # rounded up to 512s
+    (3072, 4, 32, (1024, 1536, 3072))])
+def test_held_rungs(pairs, count, experts, want):
+    rungs = decoder_ops.held_rungs(pairs, count, experts)
+    assert rungs == want
+    assert rungs[-1] == pairs and list(rungs) == sorted(set(rungs))
+    # the host's reading of a fetched RowsHeld: the first rung that holds it
+    for held in (0, 1, rungs[0], min(rungs[0] + 1, pairs), pairs):
+        moved = L.moe_rows_moved(np.array([held], np.int32), pairs, count,
+                                 experts)
+        assert moved == min(r for r in rungs if r >= held)
+
+
+def test_a_layer_that_holds_every_expert_has_no_conditional():
+    """``experts_held=None``: one rung, and the compiled step (forward,
+    recomputed forward, backward) has no ``conditional`` at all."""
+    from paddle_tpu.core import executor
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        x = L.data("x", shape=[H_SEQ, H_D], dtype="float32")
+        out, _load, _held = L.moe_ffn(x, 8, 2, H_SIZE, 64, prefix="w")
+        pt.optimizer.SGDOptimizer(learning_rate=0.1).minimize(
+            L.mean(out * out))
+        pt.memory_optimize(main, remat_types=("moe_ffn",))
+    exe = pt.Executor(pt.CPUPlace())
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed={"x": _held_x()}, fetch_list=[out])
+    step = list(executor.compiled_steps())[-1]
+    text = step.fn.lower(*step._avals).compile().as_text()
+    assert "moe_ffn" in text and " conditional(" not in text
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
+def test_no_conditional_of_the_gradient_returns_a_rungs_arrays(remat):
+    """Autodiff of a bare ``lax.switch`` would make every branch return all
+    branches' residuals (``[rung, .]`` arrays, zero-filled for the rungs not
+    taken). The held part is one ``custom_vjp``: one conditional forward,
+    one backward (the recomputed forward's is dead), and what they return
+    is the layer's result and the operands' gradients."""
+    layer = held_layer(remat)
+    conds = [l for l in layer["text"].splitlines() if " conditional(" in l]
+    assert len(conds) == 2, conds
+    for line in conds:
+        result = line.split(" conditional(")[0].split("=", 1)[1]
+        dims = {int(n) for shape in re.findall(r"\[([\d,]*)\]", result)
+                for n in shape.split(",") if n}
+        assert dims and not dims & set(layer["rungs"]), line
+        assert len(_branch_computations(line)) == len(layer["rungs"])
+
+
+def test_the_rungs_are_counted_where_the_flash_blocks_are():
+    from paddle_tpu import tune
+    tune.reset_counters()
+    layer = held_layer(True, cached=False)
+    name = "%d -> 1024/1536/3072" % H_PAIRS
+    counted = tune.counters()["moe_rungs"]
+    # forward, and the backward pass's recomputed forward
+    assert counted == {name: 2}
+    assert layer["exe"].stats["moe_rungs"] == counted
+    held_layer(True, one_rung=True, cached=False)
+    assert tune.counters()["moe_rungs"] == {name: 2,
+                                            "%d -> %d" % (H_PAIRS,
+                                                          H_PAIRS): 2}
+    tune.reset_counters()
+    assert tune.counters()["moe_rungs"] == {}
+
+
+def _step_of_expert_layers(monkeypatch, n_layers, shared):
+    """A recomputed step of ``n_layers`` ``moe_ffn`` of one shape, each
+    holding 4 of 32 experts: ({rung: times its body's Python ran},
+    {scope: instructions of the compiled step}). ``shared=False``: as it
+    was traced before the rungs were kept, every layer and pass its own
+    closures (``_held_part`` not kept, no inlined ``jit`` around a rung)."""
+    from paddle_tpu import profiler
+    from paddle_tpu.core import executor
+    body_runs = collections.Counter()
+    real_rung, real_jit = decoder_ops._held_rung, jax.jit
+
+    def counted(rows, *args):
+        rung = real_rung(rows, *args)
+
+        def body(*operands):
+            body_runs[rows] += 1
+            return rung(*operands)
+        return body
+    monkeypatch.setattr(decoder_ops, "_held_rung", counted)
+    decoder_ops._held_part.cache_clear()
+    if not shared:
+        monkeypatch.setattr(decoder_ops, "_held_part",
+                            decoder_ops._held_part.__wrapped__)
+        monkeypatch.setattr(jax, "jit", lambda f, **kw: f if "inline" in kw
+                            else real_jit(f, **kw))
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        x = L.data("x", shape=[H_SEQ, H_D], dtype="float32")
+        out = x
+        for i in range(n_layers):
+            out, _load, _held = L.moe_ffn(
+                out, H_EXPERTS, H_K, H_SIZE, 64, experts_held=H_HELD,
+                scaling=2.448, prefix="m%d" % i)
+        pt.optimizer.SGDOptimizer(learning_rate=0.1).minimize(
+            L.mean(out * out))
+        pt.memory_optimize(main, remat_types=("moe_ffn",))
+    exe = pt.Executor(pt.CPUPlace())
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed={"x": _held_x()}, fetch_list=[out])
+    step = list(executor.compiled_steps())[-1]
+    _module, table = profiler.scopes_of_module(
+        step.fn.lower(*step._avals).compile().as_text())
+    monkeypatch.undo()
+    decoder_ops._held_part.cache_clear()
+    return dict(body_runs), collections.Counter(table.values())
+
+
+def test_a_rung_is_traced_once_for_all_layers_and_every_scope_stays(
+        monkeypatch):
+    """A program of several expert layers of one shape runs the Python of
+    each rung's body once in the step's own trace and once under
+    ``jax.checkpoint`` (another trace context to jax's tracing cache),
+    however many layers it has; traced layer by layer and pass by pass, as
+    it was, every layer runs it four times (its forward, its recomputed
+    forward and that one's differentiation, the backward's ``jax.vjp``).
+    And sharing the trace moves no
+    instruction from one scope to another: the compiled step has the same
+    instructions under every ``<phase>/moe_ffn/<part>`` either way (a
+    shared LOWERING would give every call site the first caller's name)."""
+    rungs = decoder_ops.held_rungs(H_PAIRS, H_HELD[1], H_EXPERTS)
+    one, _scopes = _step_of_expert_layers(monkeypatch, 1, True)
+    two, scopes = _step_of_expert_layers(monkeypatch, 2, True)
+    own, own_scopes = _step_of_expert_layers(monkeypatch, 2, False)
+    assert one == two == {rows: 2 for rows in rungs}
+    assert own == {rows: 4 * 2 for rows in rungs}
+    assert scopes == own_scopes
+    for phase in ("forward", "backward"):
+        for part in ("route", "experts", "shared"):
+            assert scopes["%s/moe_ffn/%s" % (phase, part)] > 0

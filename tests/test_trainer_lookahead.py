@@ -566,23 +566,35 @@ def _args(n):
 
 @pytest.mark.parametrize("case,fits,compiles", [
     ("two_sets_exceed_the_limit", False, 0),
+    ("two_sets_and_the_new_state_exceed_the_limit", False, 0),
     ("the_compiler_runs_out_of_device_memory", False, 1),
     ("it_fits", True, 1)])
 def test_a_held_step_the_device_cannot_hold_is_a_no_not_an_error(
         case, fits, compiles):
     """XLA:TPU raises RESOURCE_EXHAUSTED at COMPILE time for a step that
     exceeds the device's memory (it reports no analysis): the loop then
-    dispatches from donated state, it does not die. Two sets of state that
-    alone exceed the limit are not compiled at all."""
+    dispatches from donated state, it does not die. Two sets of state
+    that, with the new state the rule wants beside them, exceed the limit
+    are not compiled at all: the rule could only say no (800 B of state:
+    two sets 1,600 of 2,000, three 2,400)."""
     import jax
     oom = jax.errors.JaxRuntimeError(
         "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
         "memory in memory space hbm. Used 16.80G of 15.75G hbm.")
     held = _Held(oom if case.startswith("the_compiler") else None)
-    n = 1000 if case.startswith("two_sets") else 10
+    n = {"two_sets_exceed_the_limit": 1000,
+         "two_sets_and_the_new_state_exceed_the_limit": 200}.get(case, 10)
     assert executor_mod._held_step_compiles_and_fits(
         held, _args(n), 2000) is fits
     assert held.compiles == compiles
+    if not compiles:
+        # what the compile would have been asked: the rule's own answer for
+        # the least such a step can take
+        class _Least(object):
+            argument_size_in_bytes = 2 * 4 * n
+            output_size_in_bytes = 4 * n
+            temp_size_in_bytes = alias_size_in_bytes = 0
+        assert executor_mod._held_step_fits(_Least(), 2000) is False
 
 
 def test_another_compile_error_of_the_held_step_is_raised():

@@ -11,8 +11,12 @@ instructions, per instruction its scope, result type, fusion kind and time.
 ``--hlo`` also writes the optimised text of the step's module, which says
 what a ``fusion.N`` reads and writes. The cell's result (every per-layer
 metric of a ``--trace 1`` run) goes into the ``--out`` file beside the table,
-with ``tune.counters()`` (the header's line: dispatch gauges and the blocks
-each flash-attention launch was traced at).
+with ``tune.counters()`` (the header's lines: dispatch gauges, the blocks
+each flash-attention launch was traced at, the row counts each expert
+layer's held part was compiled at) and, where the cell's program has expert
+layers whose ``Load`` / ``RowsHeld`` its Trainer fetches, the rung every
+(layer, step) of the window ran at (``layers.moe_rows_moved`` over the
+fetched ``RowsHeld``: a listener of this tool's own on ``EndIteration``).
 """
 from __future__ import annotations
 
@@ -87,9 +91,56 @@ def by_kind(rows, steps, scope):
     return sorted(((w, n[w], ms[w]) for w in ms), key=lambda r: -r[2])
 
 
-def report(module, steps, rows, counters=None):
+def listen_to_expert_layers(heard):
+    """From here on every ``Trainer.train`` of this process hands each
+    ``EndIteration``'s fetches to ``heard["steps"]`` too, and says in
+    ``heard["layers"]`` which of them are an expert layer's: [(index of
+    its ``Load``, index of its ``RowsHeld``, experts held, experts)], read
+    off the ``moe_ffn`` ops of the Trainer's program. One ``append`` a step
+    inside the loop; everything else waits for ``rung_shares``."""
+    from paddle_tpu import trainer
+    train = trainer.Trainer.train
+
+    def listening(self, *args, event_handler=None, **kw):
+        block = self.main_program.global_block()
+        at = {v.name: i for i, v in enumerate(self.fetch_list[1:])}
+        heard["layers"] = [
+            (at[op.output("Load")[0]], at[op.output("RowsHeld")[0]],
+             block._find_var_recursive(op.input("ExpertGate")[0]).shape[0],
+             block._find_var_recursive(op.input("WRouter")[0]).shape[1])
+            for op in block.ops if op.type == "moe_ffn"
+            and op.output("Load")[0] in at and op.output("RowsHeld")[0] in at]
+        heard["steps"] = steps = []
+
+        def both(e):
+            if isinstance(e, trainer.EndIteration):
+                steps.append(e.metrics.get("fetches", ()))
+            if event_handler is not None:
+                event_handler(e)
+        return train(self, *args, event_handler=both, **kw)
+    trainer.Trainer.train = listening
+
+
+def rung_shares(heard, steps):
+    """Of the last ``steps`` steps heard (the window's): per expert layer
+    {"rows_held": [least, mean, most], "rungs": {rows moved: steps}}."""
+    from paddle_tpu import layers
+    out = []
+    for load, held, count, experts in heard.get("layers", ()):
+        rows, rungs = [], collections.Counter()
+        for fetches in heard["steps"][-steps:]:
+            rows.append(int(fetches[held].sum()))
+            rungs[layers.moe_rows_moved(
+                fetches[held], int(fetches[load].sum()), count, experts)] += 1
+        out.append({"rows_held": [min(rows), sum(rows) / float(len(rows)),
+                                  max(rows)], "rungs": dict(rungs)})
+    return out
+
+
+def report(module, steps, rows, counters=None, rungs=None):
     """``counters``: ``tune.counters()`` of the process that traced the
-    step (the dispatch gauges and the flash kernels' blocks), where the
+    step (the dispatch gauges, the flash kernels' blocks, the expert
+    layers' rungs) and ``rungs``: ``rung_shares`` of the window, where the
     table comes from a run and not from a recorded slice."""
     total = sum(ns for _s, _n, ns in rows.values()) / steps / 1e6
     print("%s: %d steps, %d instructions, %.2f ms a step"
@@ -104,6 +155,20 @@ def report(module, steps, rows, counters=None):
         for name, t in sorted(counters.get("flash_tiles", {}).items()):
             print("flash_tiles %s: %d visited, %d masked, %d in the square"
                   % (name, t["visited"], t["masked"], t["square"]))
+        if counters.get("moe_rungs"):
+            print("moe_rungs: %s" % ", ".join(
+                "%s x%d" % kv for kv in sorted(counters["moe_rungs"].items())))
+    if rungs:
+        tally = collections.Counter()
+        for i, layer in enumerate(rungs):
+            tally.update(layer["rungs"])
+            print("expert layer %d: rows_held %d / %.0f / %d (least / mean /"
+                  " most a step); steps at rung %s"
+                  % ((i,) + tuple(layer["rows_held"]) + (", ".join(
+                      "%d: %d" % kv for kv in sorted(layer["rungs"].items())),)))
+        print("rung shares of the window's (layer, step)s: %s" % ", ".join(
+            "%d: %.1f%%" % (r, 100.0 * n / sum(tally.values()))
+            for r, n in sorted(tally.items())))
     for scope, ms, n in by_scope(rows, steps):
         print("  %-28s %8.3f ms %5d ops" % (scope, ms, n))
     for scope in DETAILED:
@@ -126,7 +191,7 @@ def main(argv=None):
     ap.add_argument("--hlo")
     args = ap.parse_args(argv)
     from chipbench import program_trace
-    result = counters = None
+    result = counters = rungs = None
     if args.recorded:
         with open(args.recorded) as f:
             rec = json.load(f)
@@ -138,6 +203,8 @@ def main(argv=None):
         from chipbench import harness, run as bench, trace_reduce
         from paddle_tpu import profiler, tune
         from paddle_tpu.core import executor
+        heard = {}
+        listen_to_expert_layers(heard)
         try:
             res = bench.run_cell(args.workload, args.seed, args.seconds, True)
         except harness.NoChip as e:
@@ -151,6 +218,8 @@ def main(argv=None):
         result = {k: res[k] for k in ("correct", "attempted", "metrics",
                                       "device", "compared", "breakdown")}
         counters = result["tune"] = tune.counters()
+        rungs = result["moe_rungs_in_window"] = rung_shares(
+            heard, res["attempted"])
         if args.hlo:
             for step in executor.compiled_steps():
                 facts = step.facts()
@@ -158,7 +227,7 @@ def main(argv=None):
                     with open(args.hlo, "w") as f:
                         f.write(step.fn.lower(*step._avals).compile()
                                 .as_text())
-    report(module, steps, rows, counters)
+    report(module, steps, rows, counters, rungs)
     if args.out:
         with open(args.out, "w") as f:
             json.dump({"module": module, "steps": steps, "rows": rows,
