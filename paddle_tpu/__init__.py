@@ -9,6 +9,10 @@ distribution is pjit sharding over device meshes instead of parameter servers
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_START = _time.monotonic()   # the ``import`` set-up span starts here
+
 # ops must register before anything builds programs
 from . import ops  # noqa: F401
 
@@ -81,3 +85,5 @@ from .optimizer import (  # noqa: F401
 )
 
 __version__ = "0.1.0"
+
+profiler.record_setup_span("import", _IMPORT_START)

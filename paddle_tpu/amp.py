@@ -14,6 +14,7 @@ import contextlib
 
 import jax.numpy as jnp
 
+from . import profiler
 from .core import ir
 from .place import on_tpu
 
@@ -21,6 +22,7 @@ __all__ = ["enable", "disable", "amp_guard", "cast_inputs", "force",
            "active", "keep_bf16"]
 
 
+@profiler.setup_span("build")
 def enable(program=None, pure=False):
     """``pure=True`` additionally keeps matmul/conv OUTPUTS in bf16, so
     the whole activation stream (the dominant HBM traffic) is half-width

@@ -692,7 +692,11 @@ class _TracedOnce(object):
     """Compiled-step wrapper that serializes the tracing first call, and
     keeps that call's abstract values so that the profiler can ask for
     the facts of the executable that runs (``facts``) without a second,
-    profiled executable in its place."""
+    profiled executable in its place. Every call that traces or compiles
+    is inside a ``compile`` set-up span (``profiler.setup_span``):
+    ``memory()`` (``why="fit_check"``) and the first call
+    (``why="first_call"``), which after a ``memory()`` finds trace and
+    executable in jax's caches, or does not."""
 
     __slots__ = ("fn", "_ready", "_span_args", "_mesh_devices", "_avals",
                  "_facts")
@@ -701,7 +705,8 @@ class _TracedOnce(object):
         self.fn = fn
         self._ready = threading.Event()
         self._span_args = {"program": program._uid,
-                           "version": program._version}
+                           "version": program._version,
+                           "module": "jit_" + fn.__name__}
         self._mesh_devices = mesh_devices
         self._avals = None
         self._facts = None
@@ -712,7 +717,9 @@ class _TracedOnce(object):
         with _FIRST_TRACE_LOCK:
             if self._ready.is_set():    # another thread's first call won
                 return self.fn(*args)
-            return self._trace(args, lambda: self.fn(*args))
+            out = self._trace(args, lambda: self.fn(*args), "first_call")
+            self._ready.set()
+            return out
 
     def memory(self, *args):
         """``memory_analysis()`` of the step compiled for ``args`` in
@@ -721,20 +728,18 @@ class _TracedOnce(object):
         and compiled once. None where the backend gives none."""
         with _FIRST_TRACE_LOCK:
             return self._trace(args, lambda: self.fn.lower(
-                *self._avals).compile().memory_analysis())
+                *self._avals).compile().memory_analysis(), "fit_check")
 
-    def _trace(self, args, first):
+    def _trace(self, args, first, why):
         from .. import profiler as _prof
         # before the call: it may donate the state's buffers
         self._avals = jax.tree_util.tree_map(_abstract, args)
         _prof.set_phase("trace")        # per-op spans now time the lowering
         try:
-            with _prof.span("compile", **self._span_args):
-                out = first()
+            with _prof.setup_span("compile", why=why, **self._span_args):
+                return first()
         finally:
             _prof.set_phase("eager")
-        self._ready.set()
-        return out
 
     def facts(self):
         """``{"module", "scopes", "analysis"}`` of the compiled step (see

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Set, Tuple
 
+from . import profiler
 from .core import ir
 
 __all__ = ["memory_optimize", "release_memory", "ControlFlowGraph"]
@@ -87,6 +88,7 @@ DEFAULT_REMAT_TYPES = frozenset((
     "latent_attention", "grouped_attention", "gated_ffn", "moe_ffn"))
 
 
+@profiler.setup_span("build")
 def memory_optimize(input_program: ir.Program, print_log=False, level=0,
                     remat_types=None):
     """Enable rematerialisation for the program and report the reuse the

@@ -10,25 +10,31 @@ per program, and the device timeline comes from jax.profiler's xplane trace
 
 The program's own spans (``span`` / ``step_span``) are events of that same
 trace, on its clock: they are always in the code and record only while a
-jax.profiler session is open (``xla_trace``). ``device_scopes`` gives the
-table that names each device op of a compiled step by its forward /
-backward / update scope (doc/diagnostics.md).
+jax.profiler session is open (``xla_trace``). Set-up spans (``setup_span``:
+import, build, init, compile) are such spans that are also always kept in
+memory, with jax's own compile phases as their children (``setup_spans``).
+``device_scopes`` gives the table that names each device op of a compiled
+step by its forward / backward / update scope (doc/diagnostics.md).
 """
 from __future__ import annotations
 
 import contextlib
 import re
+import threading as _threading
 import time
 from collections import defaultdict
 
 import jax
+import jax.monitoring
 
 __all__ = ["timer", "stat_summary", "print_stats", "reset_stats",
            "BarrierStat",
            "span", "step_span", "device_scopes",
            "start_profiler", "stop_profiler", "reset_profiler", "profiler",
            "cuda_profiler", "xla_trace", "profiler_enabled", "record_run",
-           "record_op_event", "record_program_analysis", "write_timeline",
+           "record_op_event", "write_timeline",
+           "setup_span", "record_setup_span", "setup_spans",
+           "reset_setup_spans",
            "update_pipeline_counters", "pipeline_counters", "part_scope",
            "reset_pipeline_counters",
            "update_serving_counters", "serving_counters",
@@ -490,17 +496,11 @@ _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
                 "collective-permute", "all-to-all")
 
 
-def record_program_analysis(label, compiled, mesh_devices=1):
-    """XLA's compiled cost analysis + a census of the collectives GSPMD
-    inserted — the mesh 'barrier stat': every collective is a cross-device
-    sync point (reference: platform/device_tracer.h timeline +
-    profiler.proto role, in compiled-program form)."""
-    _program_analyses[label] = analyse_compiled(compiled, mesh_devices)
-
-
 def analyse_compiled(compiled, mesh_devices=1):
-    """The ``programs`` entry of one compiled program (see
-    record_program_analysis)."""
+    """The ``programs`` entry of one compiled program: XLA's compiled cost
+    analysis + a census of the collectives GSPMD inserted (the mesh
+    'barrier stat': every collective is a cross-device sync point;
+    reference: platform/device_tracer.h timeline + profiler.proto role)."""
     entry = {"mesh_devices": int(mesh_devices)}
     try:
         ca = compiled.cost_analysis()
@@ -679,6 +679,9 @@ def write_timeline(path):
       batch skips and rewinds, watchdog step_hung firings, elastic
       lease commits, truncated preemptions — the survival evidence
       for the elastic Trainer worker).
+    - ``setup``: the set-up record (``setup_spans()``: import, build,
+      init, each step's compile and jax's trace / lower / compile /
+      cache-read inside them), one entry per span.
     """
     import json
     _resolve_profiled_steps()
@@ -708,6 +711,8 @@ def write_timeline(path):
         "memory": dict(_memory_counters),
         "trainer": dict(_trainer_counters),
         "grayfail": dict(_grayfail_counters),
+        "setup": [{"name": n, "start_s": s, "end_s": e, "parent": p,
+                   "args": a} for n, s, e, p, a in setup_spans()],
     }
     with open(path, "w") as f:
         json.dump(artifact, f, indent=1)
@@ -787,6 +792,117 @@ def step_span(n, **args):
                                             step_num=n, **args)
 
 
+# ---------------------------------------------------------------------------
+# Set-up spans: ``span`` with a memory. Set-up (import, build, init, the
+# first call of each compiled step) happens before anyone opens a profiler
+# session, so these are also kept in a list, always, on the clock of
+# ``time.monotonic()``; jax's own compile phases become their children.
+# They open a handful of times per process, never per step.
+
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "xla.compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache.read",
+}
+_setup = []         # [name, start, end (None while open), parent record, args]
+_setup_lock = _threading.Lock()
+_setup_open = _threading.local()    # .stack: this thread's open records
+
+
+def _open_stack():
+    stack = getattr(_setup_open, "stack", None)
+    if stack is None:
+        stack = _setup_open.stack = []
+    return stack
+
+
+@contextlib.contextmanager
+def setup_span(name, **args):
+    """A set-up phase: the ``span`` ``paddle_tpu/<name>`` (in a session's
+    trace, on the device lines' clock) that is also always recorded in
+    ``setup_spans()``. For work done once (import, build, init, a step's
+    compile), never per step."""
+    with span(name, **args):
+        stack = _open_stack()
+        rec = [name, time.monotonic(), None, stack[-1] if stack else None,
+               args]
+        with _setup_lock:
+            _setup.append(rec)
+        stack.append(rec)
+        try:
+            yield
+        finally:
+            rec[2] = time.monotonic()
+            stack.remove(rec)
+
+
+def record_setup_span(name, start, end=None, **args):
+    """Record a set-up span that is over: ``[start, end]`` (``end``: now)
+    under the innermost set-up span open on this thread. The spans of that
+    parent that lie inside the interval become its children, so spans
+    recorded after the fact nest as if they had been open (jax's phases:
+    ``cache.read`` inside ``xla.compile``; ``import``). A child of the same
+    name (the trace of a jit nested in the one being traced) is merged into
+    it: the record keeps one span per outermost phase."""
+    end = time.monotonic() if end is None else end
+    stack = _open_stack()
+    parent = stack[-1] if stack else None
+    if parent is not None:
+        start = max(start, parent[1])
+    new = [name, start, end, parent, args]
+    with _setup_lock:
+        merged = set()
+        j = len(_setup) - 1
+        while j >= 0 and _setup[j] is not parent:
+            rec = _setup[j]
+            if rec[3] is parent:
+                # the parent's children run one after another: the first
+                # that ended before ``start`` ends the search
+                if rec[2] is None or rec[2] <= start or rec[1] >= end:
+                    break
+                rec[1], rec[2], rec[3] = max(rec[1], start), min(rec[2],
+                                                                 end), new
+                if rec[0] == name:
+                    merged.add(id(rec))
+                    del _setup[j]
+            elif id(rec[3]) in merged:
+                rec[3] = new
+            j -= 1
+        _setup.append(new)
+
+
+def _on_jax_event(event, duration, **kw):
+    """jax.monitoring listener: a compile phase of jax's, ending now."""
+    name = _JAX_PHASES.get(event)
+    if name is not None:
+        end = time.monotonic()
+        args = {"fun": kw["fun_name"]} if "fun_name" in kw else {}
+        record_setup_span(name, end - duration, end, **args)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+
+
+def setup_spans():
+    """The set-up record: ``[(name, start, end, parent, args)]`` in the
+    order the spans opened (jax's phases: in the order they ended), times
+    in ``time.monotonic()`` seconds, ``parent`` the index of the enclosing
+    record or None; ``end`` is None while a span is open. Names:
+    ``import``, ``build``, ``init``, ``compile`` (``setup_span``) and
+    jax's ``jax.trace``, ``jax.lower``, ``xla.compile``, ``cache.read``;
+    a jax phase with no set-up span open around it has no parent."""
+    with _setup_lock:
+        at = {id(rec): i for i, rec in enumerate(_setup)}
+        return [(n, s, e, None if p is None else at.get(id(p)), a)
+                for n, s, e, p, a in _setup]
+
+
+def reset_setup_spans():
+    with _setup_lock:
+        del _setup[:]
+
+
 @contextlib.contextmanager
 def xla_trace(logdir):
     """jax.profiler trace — kernel timeline, HBM usage, per-fusion costs
@@ -815,8 +931,6 @@ def record_event(name):
 # scopes accumulate (count/total/max) per dotted path; `print_stats` renders
 # the tree; `BarrierStat.observe` records per-member arrival times of a
 # collective/barrier and reports the straggler gap.
-
-import threading as _threading
 
 _stat_state = _threading.local()
 

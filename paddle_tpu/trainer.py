@@ -136,16 +136,18 @@ class Trainer(object):
     def __init__(self, cost, optimizer, feed_list, place=None,
                  fetch_list=None, main_program=None, startup_program=None,
                  checkpoint_dir=None, dist_context=None):
+        from . import profiler as _prof
         self.cost = cost
         self.main_program = main_program or ir.default_main_program()
         self.startup_program = startup_program or \
             ir.default_startup_program()
         self.optimizer = optimizer
-        with ir.program_guard(self.main_program, self.startup_program):
-            optimizer.minimize(cost)
-        self.exe = Executor(place, dist_context=dist_context)
-        self.feeder = DataFeeder(feed_list, place=place,
-                                 program=self.main_program)
+        with _prof.setup_span("build"):
+            with ir.program_guard(self.main_program, self.startup_program):
+                optimizer.minimize(cost)
+            self.exe = Executor(place, dist_context=dist_context)
+            self.feeder = DataFeeder(feed_list, place=place,
+                                     program=self.main_program)
         self.fetch_list = [cost] + list(fetch_list or [])
         self.checkpoint_dir = checkpoint_dir
         self._initialized = False
@@ -163,9 +165,11 @@ class Trainer(object):
         one)."""
         if self._initialized:
             return
-        self.exe.run(self.startup_program)
-        if load:
-            self._load_checkpoint_state()
+        from . import profiler as _prof
+        with _prof.setup_span("init"):
+            self.exe.run(self.startup_program)
+            if load:
+                self._load_checkpoint_state()
         self._initialized = True
 
     def _load_checkpoint_state(self):
@@ -408,7 +412,11 @@ class Trainer(object):
                 worker.close()
                 raise
         self._maybe_init()
-        handler = event_handler or (lambda e: None)
+        user_handler = event_handler or (lambda e: None)
+
+        def handler(event):
+            with _prof.span("handler"):
+                user_handler(event)
         log_period = FLAGS.log_period
         # the master answers "wait" while any lease is pending, and the
         # lease of batch n is pending until commit(n): asked for batch n+1

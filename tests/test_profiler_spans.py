@@ -4,6 +4,8 @@ update marks on the ops and their named scopes on the device ops
 (profiler.device_scopes), and one executable whether profiling is on or off.
 jax.profiler records the host's ``python`` line on the CPU too."""
 import glob
+import importlib.util
+import json
 import os
 import re
 
@@ -164,7 +166,7 @@ def test_prepare_feed_holds_the_upload_span_and_run_opens_none(tmp_path):
 @pytest.mark.parametrize("name,count", [
     ("train_step", STEPS), ("feed", STEPS), ("run", STEPS),
     ("upload", STEPS), ("dispatch", STEPS), ("fetch", STEPS),
-    ("compile", 1)])
+    ("compile", 2), ("handler", 2 * STEPS + 2)])
 def test_span_counts(traced, name, count):
     spans = traced["spans"]
     got = _train_steps(spans) if name == "train_step" else [
@@ -172,15 +174,31 @@ def test_span_counts(traced, name, count):
     assert len(got) == count
 
 
-def test_the_one_compile_span_is_in_step_0_inside_dispatch(traced):
+def test_the_compile_spans_are_in_step_0_inside_dispatch(traced):
+    """The held step is compiled for its memory check, then its first call
+    (which finds it in jax's caches) is a compile span of its own."""
     spans = traced["spans"]
-    (compile_,) = [s for s in spans if s[0] == "compile"]
-    assert compile_[3] == {"program": traced["main"]._uid,
-                           "version": traced["main"]._version}
+    compiles = [s for s in spans if s[0] == "compile"]
+    main = traced["main"]
+    assert [c[3] for c in compiles] == [
+        {"program": main._uid, "version": main._version,
+         "module": "jit_" + traced["step"].fn.__name__, "why": why}
+        for why in ("fit_check", "first_call")]
     step0 = _train_steps(spans)[0]
     # step 0's own dispatch; step 1's, ahead, finds the step compiled
     dispatch, _ahead = _inside(spans, step0, "dispatch")
-    assert dispatch[1] <= compile_[1] and compile_[2] <= dispatch[2]
+    for compile_ in compiles:
+        assert dispatch[1] <= compile_[1] and compile_[2] <= dispatch[2]
+
+
+def test_every_handler_call_is_a_span_of_its_own(traced):
+    """BeginPass, then per step BeginIteration and EndIteration inside its
+    train_step span, then EndPass."""
+    spans = traced["spans"]
+    handlers = [s for s in spans if s[0] == "handler"]
+    for step in _train_steps(spans):
+        assert len(_inside(spans, step, "handler")) == 2
+    assert {h[4] for h in handlers} == {_train_steps(spans)[0][4]}
 
 
 def test_the_call_that_finds_the_reader_exhausted_is_marked(traced):
@@ -341,3 +359,47 @@ def test_scopes_of_module_reads_fusions_and_leaves_the_rest_unscoped():
                      "x.1": "unscoped", "copy-start": "unscoped",
                      "fusion.7": "backward/while_grad",   # the outermost
                      "sub.2": "update/momentum"}
+
+
+def _scope_table_tool():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "scope_table", os.path.join(root, "tools", "scope_table.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+def test_scope_table_splits_the_idle_device_by_the_innermost_host_span():
+    """tools/scope_table.py: the device's idle time by the span open on the
+    host, innermost first; the parts add up to the idle time."""
+    tool = _scope_table_tool()
+    spans = [("train_step", 0, 100, {}), ("feed", 10, 10, {}),
+             ("run", 25, 20, {}), ("dispatch", 30, 10, {}),
+             ("fetch", 50, 30, {}), ("handler", 85, 10, {}),
+             ("handler", 120, 5, {})]                 # outside the window
+    got = tool.idle_by_host_span(spans, [(0, 5), (32, 38), (60, 70)],
+                                 (0, 110))
+    assert got == {"train_step": 25, "feed": 10, "run": 10, "dispatch": 4,
+                   "fetch": 20, "handler": 10, "none": 10}
+
+
+def test_scope_table_idle_split_of_a_recorded_step():
+    """On the kept slice of a chip trace (R, before the handler span): one
+    whole train_step's idle device time, all of it under a program span."""
+    from chipbench import program_trace as pt_trace, trace_reduce
+    tool = _scope_table_tool()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "testdata",
+                           "train_program_trace.json")) as f:
+        rec = json.load(f)
+    spans = [tuple(s) for s in rec["spans"]]
+    busy = trace_reduce.reduce_planes(rec["planes"])["devices"][0]["busy"]
+    step = next(s for s in spans if s[0] == "train_step"
+                and any(o[0] == "fetch" and o[1] >= s[1] for o in spans))
+    window = (step[1], step[1] + step[2])
+    got = tool.idle_by_host_span(spans, busy, window)
+    idle = sum(e - s for s, e in pt_trace.subtract([window], busy))
+    assert sum(got.values()) == idle > 0
+    assert "none" not in got and set(got) <= {
+        "train_step", "feed", "run", "upload", "dispatch", "fetch"}

@@ -17,6 +17,10 @@ layer's held part was compiled at) and, where the cell's program has expert
 layers whose ``Load`` / ``RowsHeld`` its Trainer fetches, the rung every
 (layer, step) of the window ran at (``layers.moe_rows_moved`` over the
 fetched ``RowsHeld``: a listener of this tool's own on ``EndIteration``).
+A run also prints the device's idle time a step by the innermost program
+span open on the host (``fetch``, ``handler``, ``dispatch``, ``upload``,
+``feed``, ``run``, ``train_step``, none), and the program's set-up record
+(``profiler.setup_spans()``) up to the window as a tree with self times.
 """
 from __future__ import annotations
 
@@ -137,6 +141,86 @@ def rung_shares(heard, steps):
     return out
 
 
+def idle_by_host_span(spans, busy, window_ns):
+    """{span: ns} of the device's idle time inside ``window_ns`` by the
+    name of the innermost program span open on the host at the time
+    (``fetch``, ``handler``, ``dispatch``, ``upload``, ``feed``; ``run``
+    outside those, ``train_step``'s own loop), ``none`` where none is.
+    ``spans``: ``program_trace.read_xplane``'s; ``busy``: the device's
+    merged busy intervals."""
+    from chipbench import program_trace, trace_reduce
+    w0, w1 = window_ns
+    inside = sorted((max(s, w0), min(s + d, w1), n)
+                    for n, s, d, _a in spans if s < w1 and s + d > w0)
+    cuts = sorted({w0, w1} | {x for s, e, _n in inside for x in (s, e)})
+    parts, active, k = collections.defaultdict(list), [], 0
+    for a, b in zip(cuts, cuts[1:]):
+        while k < len(inside) and inside[k][0] <= a:
+            active.append(inside[k])
+            k += 1
+        active = [sp for sp in active if sp[1] > a]
+        parts[max(active)[2] if active else "none"].append((a, b))
+    idle = program_trace.subtract([(w0, w1)], busy)
+    return {name: program_trace.overlap(idle, trace_reduce.merge(segs))
+            for name, segs in parts.items()}
+
+
+def setup_tree(records, t0):
+    """Lines of the program's set-up record (``profiler.setup_spans()``)
+    that ended by ``t0``: each program span with its self time and the
+    self time of jax's phases under it (to the next program span), by
+    kind; then jax's phases under no program span."""
+    from chipbench import setup_trace
+    own = setup_trace.self_times(records, t0)
+    kids = collections.defaultdict(list)
+    for i in own:
+        if records[i][3] is not None:
+            kids[records[i][3]].append(i)
+    lines = []
+
+    def tally(i, into):
+        """Record i and the jax phases under it into {name: [count, s]}."""
+        n_s = into.setdefault(records[i][0], [0, 0.0])
+        n_s[0] += 1
+        n_s[1] += own[i]
+        for j in kids[i]:
+            if records[j][0] not in setup_trace.PROGRAM:
+                tally(j, into)
+
+    def show(by):
+        return ", ".join("%s %.3f x%d" % (k, v[1], v[0])
+                         for k, v in sorted(by.items()))
+
+    def walk(i, depth):
+        name, _s, _e, _p, args = records[i]
+        by = {}
+        for j in kids[i]:
+            if records[j][0] not in setup_trace.PROGRAM:
+                tally(j, by)
+        label = " ".join([name] + ["%s=%s" % (k, args[k]) for k in
+                                   ("why", "module") if k in args])
+        lines.append("%s%-64s %8.3f s self  %s"
+                     % ("  " * depth, label, own[i], show(by)))
+        for j in kids[i]:
+            if records[j][0] in setup_trace.PROGRAM:
+                walk(j, depth + 1)
+
+    outside = {}
+    for i in sorted(own):
+        if records[i][3] is None:
+            if records[i][0] in setup_trace.PROGRAM:
+                walk(i, 1)
+            else:
+                tally(i, outside)
+    view = setup_trace.reduce_setup(records, t0)
+    lines.append("  under no program span (the drivers' jits): %s"
+                 % (show(outside) or "nothing"))
+    lines.insert(0, "set-up record: %.3f s in program spans, %d compile "
+                 "spans (s, self time; jax's phases under each, by kind):"
+                 % (view["program_s"], view["executables"]))
+    return lines
+
+
 def report(module, steps, rows, counters=None, rungs=None):
     """``counters``: ``tune.counters()`` of the process that traced the
     step (the dispatch gauges, the flash kernels' blocks, the expert
@@ -220,6 +304,20 @@ def main(argv=None):
         counters = result["tune"] = tune.counters()
         rungs = result["moe_rungs_in_window"] = rung_shares(
             heard, res["attempted"])
+        ctx = res["ctx"]
+        spans = program_trace.read_xplane(program_trace.find_xplane())[0]
+        devs = ctx["reduction"]["devices"]
+        idle = result["idle_by_host_span_ms_a_step"] = {
+            k: ns / 1e6 / ctx["steps"] for k, ns in idle_by_host_span(
+                spans, devs[0]["busy"] if devs else [],
+                ctx["window_ns"]).items()}
+        record = profiler.setup_spans()
+        t0, t1 = ctx["step_ends"][0], ctx["step_ends"][-1]
+        result["setup_spans"] = record
+        result["setup_entries_in_window"] = len(
+            [r for r in record if t0 < r[1] < t1
+             or (r[2] is not None and t0 < r[2] < t1)])
+        setup = setup_tree(record, t0)
         if args.hlo:
             for step in executor.compiled_steps():
                 facts = step.facts()
@@ -228,6 +326,14 @@ def main(argv=None):
                         f.write(step.fn.lower(*step._avals).compile()
                                 .as_text())
     report(module, steps, rows, counters, rungs)
+    if result is not None:
+        print("device idle a step by the innermost program span open on "
+              "the host (ms): %s" % ", ".join(
+                  "%s %.3f" % kv for kv in sorted(
+                      idle.items(), key=lambda kv: -kv[1])))
+        print("set-up entries recorded inside the window: %d"
+              % result["setup_entries_in_window"])
+        print("\n".join(setup))
     if args.out:
         with open(args.out, "w") as f:
             json.dump({"module": module, "steps": steps, "rows": rows,
