@@ -910,8 +910,10 @@ class Executor(object):
         # launch was traced at ({"fwd 512x512": n, ...}; grouped heads and
         # a window ride in the name, "fwd 512x512 g8 w2048") and
         # flash_tiles the score tiles each such launch visits / masks /
-        # has in its square; moe_rungs the tally of the row counts each
-        # expert layer's held part was traced at ({"49152 ->
+        # has in its square; flash_kept the tally of the differentiated
+        # launches whose backward reads the o and lse their forward kept
+        # (flash_kept_bytes of them); moe_rungs the tally of the row
+        # counts each expert layer's held part was traced at ({"49152 ->
         # 12288/24576/49152": n}; ops/decoder_ops.py: held_rungs)
         # comm_path says HOW the last compiled program's DP grads sync:
         # "explicit" = routed through the paddle_tpu.comm collectives
@@ -932,7 +934,8 @@ class Executor(object):
                       "comm_path": "",
                       "tune_hits": 0, "tune_misses": 0,
                       "tune_fallbacks": 0, "flash_blocks": {},
-                      "flash_tiles": {}, "moe_rungs": {},
+                      "flash_tiles": {}, "flash_kept": {},
+                      "flash_kept_bytes": 0, "moe_rungs": {},
                       "elastic_resizes": 0, "elastic_lost_ranks": 0,
                       "elastic_requeued_tasks": 0,
                       "elastic_resume_ms": 0.0,

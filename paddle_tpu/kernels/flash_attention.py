@@ -44,6 +44,12 @@ flash calls with the numerically exact merge
 ``o = (o_a * exp(lse_a - lse) + o_b * exp(lse_b - lse))`` — gradients flow
 through both o and lse (the dlse term folds into the backward's delta).
 
+A differentiated call's o and lse are the backward kernels' residuals,
+named (``KEPT``) so that a checkpoint that recomputes the rest of a layer
+keeps them (ops/generic_grad.py); the forward kernel of one shape is traced
+once (``_forward_launch``), so the launch a gradient replays is the forward
+op's own to XLA, which merges the two.
+
 No reference equivalent (attention postdates the 2018 codebase); this is a
 capability the TPU build adds, used by nets.scaled_dot_product_attention.
 """
@@ -53,12 +59,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..place import on_tpu
 
 LANE = 128
 NEG_INF = -1e30
 KERNELS = ("fwd", "dq", "dkv")
+# the names (jax.ad_checkpoint.checkpoint_name) of a differentiated call's
+# o and lse: what its backward kernels read of the forward
+KEPT = ("flash_o", "flash_lse")
 BLOCK_WIDTHS = (512, 256, 128)
 # what Mosaic grants one kernel on a v5e unless told otherwise (its
 # "scoped vmem limit"); the reckoning keeps a shape under it
@@ -159,14 +169,11 @@ def default_blocks(kernel, Sq, Sk, D, Dv, dtype, causal, group=1,
             li += 1
 
 
-def _blocks(kernel, config, q3, k3, v3, causal, valid_len, window):
+def _chosen_blocks(kernel, config, q3, k3, v3, causal, window):
     """The blocks ``kernel`` runs this call at: a paddle_tpu.tune
     "flash_attention" winner ({block_q, block_k}, clamped to the lengths)
     where it divides the padded lengths and fits VMEM, else the rule — a
-    stale or refused cache entry degrades, it never fails. Counts the
-    choice and the tiles it makes the launch visit
-    (``tune.counters()["flash_blocks"]`` / ``["flash_tiles"]``)."""
-    from .. import tune
+    stale or refused cache entry degrades, it never fails."""
     (BH, Sq, D), Sk, Dv = q3.shape, k3.shape[1], v3.shape[2]
     group = BH // k3.shape[0]
     cfg = dict(config) if config else {}
@@ -176,9 +183,19 @@ def _blocks(kernel, config, q3, k3, v3, causal, valid_len, window):
             kernel, bq, bk, Sq, Sk, D, Dv, q3.dtype.itemsize, group):
         bq, bk = default_blocks(kernel, Sq, Sk, D, Dv, q3.dtype, causal,
                                 group, window)
+    return bq, bk
+
+
+def _blocks(kernel, config, q3, k3, v3, causal, valid_len, window):
+    """``_chosen_blocks``, counting the choice and the tiles it makes the
+    launch visit (``tune.counters()["flash_blocks"]`` /
+    ``["flash_tiles"]``)."""
+    from .. import tune
+    bq, bk = _chosen_blocks(kernel, config, q3, k3, v3, causal, window)
     tune.count_flash_blocks(
-        kernel, bq, bk, group, window,
-        tile_counts(kernel, bq, bk, Sq, Sk, valid_len, causal, window))
+        kernel, bq, bk, q3.shape[0] // k3.shape[0], window,
+        tile_counts(kernel, bq, bk, q3.shape[1], k3.shape[1], valid_len,
+                    causal, window))
     return bq, bk
 
 
@@ -396,17 +413,35 @@ def _fa_forward(q3, k3, v3, causal, scale, valid_len, interpret,
     block's last two dims divisible by (8, 128) or equal to the array's,
     and (block_q, 1) over [Sq, 1] is — the kernel's running statistics
     are [block_q, 1] columns already."""
-    from jax.experimental import pallas as pl
-
-    BH, Sq, D = q3.shape
-    Sk, Dv = k3.shape[1], v3.shape[2]
     block_q, block_k = _blocks("fwd", config, q3, k3, v3, causal, valid_len,
                                window)
-    kv = _kv_head(BH // k3.shape[0])
+    launch = _forward_launch(tuple((a.shape, a.dtype) for a in (q3, k3, v3)),
+                             causal, scale, valid_len, interpret, block_q,
+                             block_k, window)
+    o, lse = launch(q3, k3, v3)
+    return o, lse[:, :, 0]
+
+
+@functools.lru_cache(maxsize=64)
+def _forward_launch(shapes, causal, scale, valid_len, interpret, block_q,
+                    block_k, window):
+    """The forward kernel's launch at these (shape, dtype)s of q3 / k3 / v3
+    and these statics, traced ONCE: ``fn(q3, k3, v3) -> (o, lse [BH, Sq,
+    1])``. A Pallas kernel carries the source locations of the trace that
+    built it, and XLA merges two launches on the same operands only where
+    their kernels are the same text. Built once, the forward op's launch
+    and the one that a recomputing op's gradient replays beside it
+    (``ops/generic_grad.py``) are one launch on the device, whose ``o``
+    and ``lse`` the backward kernels read."""
+    from jax.experimental import pallas as pl
+    import jax.extend
+
+    ((BH, Sq, D), dtype), ((BHkv, Sk, _D), _), ((_, _, Dv), _) = shapes
+    kv = _kv_head(BH // BHkv)
     kernel = functools.partial(_fa_kernel, causal=causal, scale=scale,
                                block_k=block_k, kv_len=Sk,
                                valid_len=valid_len, window=window)
-    o, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(BH, Sq // block_q),
         in_specs=[
@@ -419,12 +454,14 @@ def _fa_forward(q3, k3, v3, causal, scale, valid_len, interpret,
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Sq, Dv), q3.dtype),
+            jax.ShapeDtypeStruct((BH, Sq, Dv), dtype),
             jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q3, k3, v3)
-    return o, lse[:, :, 0]
+    )
+    traced = jax.make_jaxpr(call)(
+        *(jax.ShapeDtypeStruct(s, d) for s, d in shapes))
+    return jax.extend.core.jaxpr_as_fun(traced)
 
 
 # ---------------------------------------------------------------------------
@@ -598,7 +635,18 @@ def _flash(q3, k3, v3, causal, scale, valid_len, config=None, window=None):
 
 def _flash_fwd(q3, k3, v3, causal, scale, valid_len, config=None,
                window=None):
+    """The differentiated call: its ``o`` and ``lse`` are the residuals
+    the backward kernels read, named (``KEPT``) so that a checkpoint's
+    policy can keep them where it recomputes the rest
+    (ops/generic_grad.py), and counted (``tune.counters()["flash_kept"]``
+    / ``["flash_kept_bytes"]``)."""
+    from .. import tune
     o, lse = _flash(q3, k3, v3, causal, scale, valid_len, config, window)
+    o, lse = checkpoint_name(o, KEPT[0]), checkpoint_name(lse, KEPT[1])
+    bq, bk = _chosen_blocks("fwd", config, q3, k3, v3, causal, window)
+    tune.count_flash_kept(bq, bk, q3.shape[0] // k3.shape[0], window,
+                          o.size * o.dtype.itemsize
+                          + lse.size * lse.dtype.itemsize)
     return (o, lse), (q3, k3, v3, o, lse)
 
 

@@ -9,7 +9,11 @@ products' result BEFORE the add: the sandwich norm) is ONE op whose lowering
 is a pure jax function, and all four are in ``memory_optimize``'s default
 ``remat_types``: ``generic_grad`` then recomputes the half-layer under
 ``jax.checkpoint`` and a layer keeps its two ``[tokens, hidden]`` inputs for
-the backward pass instead of every product's operands. Inside a lowering
+the backward pass instead of every product's operands; an attention
+half-layer keeps its flash call's ``o`` and ``lse`` too (``[rows x heads,
+tokens, v head]`` and ``[rows x heads, tokens]`` f32), which the backward
+kernels read, so that its backward re-runs the norms, products, rotary
+positions and gate but not the attention kernel. Inside a lowering
 ``profiler.part_scope`` names the parts (``proj``, ``rope``, ``attn``,
 ``attn_window``, ``attn_full``, ``gate``, ``route``, ``experts``,
 ``shared``, ``post_norm``), and ``profiler.device_scopes()`` keeps that

@@ -14,6 +14,10 @@ import numpy as np
 
 from ..core import registry
 from ..core.executor import FunctionalContext, raw_data
+from ..kernels.flash_attention import KEPT
+
+# what a recomputed op's checkpoint keeps beside its inputs
+_KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT)
 
 
 def _zeros_like(v):
@@ -84,8 +88,13 @@ def generic_grad(ctx):
         # memory_optimize'd program: recompute this op's forward during
         # the backward instead of keeping residuals (jax.checkpoint) —
         # selective by op type so only activation-heavy layers pay the
-        # recompute (VERDICT r1 weak 12)
-        fwd_fn = jax.checkpoint(fwd_fn)
+        # recompute. It keeps the op's inputs and, of each flash-attention
+        # call its lowering makes, the o and lse the backward kernels read:
+        # the backward re-runs the norms and products, not the attention
+        # kernel, and the launch that made them is the forward op's own
+        # (kernels/flash_attention.py: _forward_launch). An op that makes
+        # no such call keeps its inputs alone.
+        fwd_fn = jax.checkpoint(fwd_fn, policy=_KEEP)
     outs, vjp = jax.vjp(fwd_fn, *primals)
 
     # cotangents from the incoming Out@GRAD slots ('' names -> zero)

@@ -55,7 +55,7 @@ __all__ = [
     "wall_timer", "model_timer", "table_timer", "time_best",
     "parity_ok", "parity_report",
     "lookup", "record_fallback", "counters", "reset_counters",
-    "count_flash_blocks", "count_moe_rungs",
+    "count_flash_blocks", "count_flash_kept", "count_moe_rungs",
 ]
 
 # -- dispatch counters --------------------------------------------------------
@@ -65,7 +65,8 @@ __all__ = [
 # profiler's `tune` timeline section mirrors it.
 
 _counters_lock = threading.Lock()
-_counters = {"tune_hits": 0, "tune_misses": 0, "tune_fallbacks": 0}
+_counters = {"tune_hits": 0, "tune_misses": 0, "tune_fallbacks": 0,
+             "flash_kept_bytes": 0}
 # {"fwd 256x256": launches traced}: the blocks each flash-attention kernel
 # launch was built with (kernels/flash_attention.py: _blocks), whoever
 # chose them — a cached winner or the kernel's own rule; grouped k/v heads
@@ -74,6 +75,11 @@ _flash_blocks = {}
 # the same names -> {"visited", "masked", "square"}: the score tiles one
 # head's launch visits, those that carry a mask, and the whole square's
 _flash_tiles = {}
+# {"fwd 512x512": differentiated launches traced}: the flash-attention calls
+# whose backward reads the o and lse their forward kept
+# (kernels/flash_attention.py: _flash_fwd); _counters["flash_kept_bytes"]
+# the bytes of those o and lse
+_flash_kept = {}
 # {"49152 -> 12288/24576/49152": launches traced}: the (token, pick) pairs
 # of each expert layer traced and the static row counts its held part was
 # compiled at (ops/decoder_ops.py: held_rungs); one rung: the layer holds
@@ -88,20 +94,35 @@ def _bump(name):
     profiler.update_tune_counters(**{name: 1})
 
 
-def count_flash_blocks(kernel, block_q, block_k, group=1, window=None,
-                       tiles=None):
-    """One flash-attention launch (``kernel``: fwd / dq / dkv) traced at
-    (block_q, block_k), ``group`` q heads to a k/v head, under ``window``;
-    ``tiles``: what the launch visits (``flash_attention.tile_counts``)."""
+def _launch_name(kernel, block_q, block_k, group, window):
     name = "%s %dx%d" % (kernel, block_q, block_k)
     if group > 1:
         name += " g%d" % group
     if window is not None:
         name += " w%d" % window
+    return name
+
+
+def count_flash_blocks(kernel, block_q, block_k, group=1, window=None,
+                       tiles=None):
+    """One flash-attention launch (``kernel``: fwd / dq / dkv) traced at
+    (block_q, block_k), ``group`` q heads to a k/v head, under ``window``;
+    ``tiles``: what the launch visits (``flash_attention.tile_counts``)."""
+    name = _launch_name(kernel, block_q, block_k, group, window)
     with _counters_lock:
         _flash_blocks[name] = _flash_blocks.get(name, 0) + 1
         if tiles is not None:
             _flash_tiles[name] = dict(tiles)
+
+
+def count_flash_kept(block_q, block_k, group, window, nbytes):
+    """One differentiated flash-attention call traced whose forward
+    launch (at block_q x block_k) kept ``nbytes`` of o and lse for its
+    backward."""
+    name = _launch_name("fwd", block_q, block_k, group, window)
+    with _counters_lock:
+        _flash_kept[name] = _flash_kept.get(name, 0) + 1
+        _counters["flash_kept_bytes"] += int(nbytes)
 
 
 def count_moe_rungs(pairs, rungs):
@@ -116,13 +137,17 @@ def counters():
     """Snapshot of the process-level dispatch counters; ``flash_blocks``
     is the {"<kernel> <block_q>x<block_k>[ g<group>][ w<window>]":
     launches} tally, ``flash_tiles`` each name's {"visited", "masked",
-    "square"} score tiles a head (of the launch traced last under it) and
-    ``moe_rungs`` the {"<pairs> -> <rung>/<rung>/...": launches} tally of
-    the expert layers traced."""
+    "square"} score tiles a head (of the launch traced last under it),
+    ``flash_kept`` the tally of the differentiated calls by their forward
+    launch's name, whose backward reads the o and lse that launch kept
+    (``flash_kept_bytes`` of them in all), and ``moe_rungs`` the
+    {"<pairs> -> <rung>/<rung>/...": launches} tally of the expert layers
+    traced."""
     with _counters_lock:
         return dict(_counters, flash_blocks=dict(_flash_blocks),
                     flash_tiles={k: dict(v)
                                  for k, v in _flash_tiles.items()},
+                    flash_kept=dict(_flash_kept),
                     moe_rungs=dict(_moe_rungs))
 
 
@@ -133,6 +158,7 @@ def reset_counters():
             _counters[k] = 0
         _flash_blocks.clear()
         _flash_tiles.clear()
+        _flash_kept.clear()
         _moe_rungs.clear()
     profiler.reset_tune_counters()
 

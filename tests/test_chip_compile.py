@@ -10,6 +10,7 @@ worker that is handed this file loads libtpu, and it compiles in its own
 process. Keep every described-topology compile in THIS file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -264,3 +265,67 @@ def test_matmul_compiles(one_chip, n, config):
         return _matmul_fwd(x, w, interpret=False, config=config)
 
     _compile(fwd, one_chip, ((n, n), jnp.bfloat16), ((n, n), jnp.bfloat16))
+
+
+def _forward_kernels(text):
+    """Forward flash launches of a compiled module: the Pallas calls whose
+    result is (o, lse as a [BH, S, 1] f32 column)."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line and re.search(
+                r"= \(\w+\[[\d,]+\]\S*, f32\[\d+,\d+,1\]", line)]
+
+
+@pytest.mark.parametrize("keep", [True, False], ids=["kept", "recomputed"])
+@pytest.mark.parametrize("model", ["latent", "window"])
+def test_a_recomputed_layer_shares_the_forward_op_s_flash_launch(
+        one_chip, monkeypatch, model, keep):
+    """A tiny decoder's whole training step (latent attention; grouped
+    heads on two window layers and a full one) under pure AMP and
+    ``memory_optimize``: with its o and lse kept, each of the 3 layers has
+    ONE forward launch after XLA (the forward op's and the one its
+    gradient replays are the same kernel text, and XLA merges them);
+    recomputing everything, two."""
+    import paddle_tpu as pt
+    from paddle_tpu import layers as L
+    from paddle_tpu.core.executor import RngSource, trace_ops
+    from paddle_tpu.models.latent_moe_lm import latent_moe_lm
+    from paddle_tpu.models.window_moe_lm import window_moe_lm
+    from paddle_tpu.ops import generic_grad
+    import test_latent_moe_lm
+    import test_window_moe_lm
+    build, tests = {"latent": (latent_moe_lm, test_latent_moe_lm),
+                    "window": (window_moe_lm, test_window_moe_lm)}[model]
+    fa = _kernel_module("flash_attention")
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    if not keep:
+        monkeypatch.setattr(generic_grad, "_KEEP", None)
+    seq = 256
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        tokens = L.data("tokens", shape=[seq], dtype="int64")
+        labels = L.data("labels", shape=[seq], dtype="int64")
+        out = build(tokens, tests.tiny(False), labels=labels)
+        pt.amp.enable(main, pure=True)
+        pt.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(out["loss"])
+        pt.memory_optimize(main, remat_types=tests.HALVES)
+    exe = pt.Executor(pt.CPUPlace())
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        state = {n: jax.ShapeDtypeStruct(
+            scope.find_var(n).shape, scope.find_var(n).dtype,
+            sharding=one_chip) for n in exe._state_inputs(
+                main, scope, {"tokens", "labels"})}
+    feed = {n: jax.ShapeDtypeStruct((2, seq), jnp.int32, sharding=one_chip)
+            for n in ("tokens", "labels")}
+
+    def step(state, feed):
+        env = dict(feed, **state)
+        trace_ops(main.global_block(), env, RngSource(jax.random.PRNGKey(0)))
+        return {n: env[n] for n in state}
+
+    prev = pt.amp.force(True)
+    try:
+        text = jax.jit(step).lower(state, feed).compile().as_text()
+    finally:
+        pt.amp.force(prev)
+    assert len(_forward_kernels(text)) == (3 if keep else 6)

@@ -494,7 +494,8 @@ def _decoder_step_blocks(seq):
 
 def test_flash_blocks_counts_only_the_large_shape_for_the_decoder_at_4096():
     counted = _decoder_step_blocks(4096)
-    # three layers: the forward, the recomputed forward, dQ and dK/dV of each
+    # three layers: the forward, the forward the gradient replays (one
+    # launch after XLA), dQ and dK/dV of each
     assert {n.split()[0] for n in counted} == set(fa.KERNELS)
     assert {n.split()[1] for n in counted} == {"512x512"}, counted
     assert all(c >= 3 for c in counted.values()), counted

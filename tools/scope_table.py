@@ -12,7 +12,8 @@ instructions, per instruction its scope, result type, fusion kind and time.
 what a ``fusion.N`` reads and writes. The cell's result (every per-layer
 metric of a ``--trace 1`` run) goes into the ``--out`` file beside the table,
 with ``tune.counters()`` (the header's lines: dispatch gauges, the blocks
-each flash-attention launch was traced at, the row counts each expert
+each flash-attention launch was traced at, the differentiated launches
+whose o and lse the backward reads kept, the row counts each expert
 layer's held part was compiled at) and, where the cell's program has expert
 layers whose ``Load`` / ``RowsHeld`` its Trainer fetches, the rung every
 (layer, step) of the window ran at (``layers.moe_rows_moved`` over the
@@ -239,6 +240,10 @@ def report(module, steps, rows, counters=None, rungs=None):
         for name, t in sorted(counters.get("flash_tiles", {}).items()):
             print("flash_tiles %s: %d visited, %d masked, %d in the square"
                   % (name, t["visited"], t["masked"], t["square"]))
+        kept = counters.get("flash_kept", {})
+        print("flash_kept: %s; flash_kept_bytes %d"
+              % (", ".join("%s x%d" % kv for kv in sorted(kept.items()))
+                 or "none", counters.get("flash_kept_bytes", 0)))
         if counters.get("moe_rungs"):
             print("moe_rungs: %s" % ", ".join(
                 "%s x%d" % kv for kv in sorted(counters["moe_rungs"].items())))
