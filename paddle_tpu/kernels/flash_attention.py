@@ -73,6 +73,10 @@ BLOCK_WIDTHS = (512, 256, 128)
 # what Mosaic grants one kernel on a v5e unless told otherwise (its
 # "scoped vmem limit"); the reckoning keeps a shape under it
 VMEM_LIMIT = 16 * 1024 * 1024
+# the most a launch asks Mosaic for (half a v5e core's 128 MiB of VMEM),
+# where no blocks fit the shape under VMEM_LIMIT: whole-sequence k and v of
+# 16,384 positions of 128-wide heads are 16 MiB twice over alone
+VMEM_MOST = 64 * 1024 * 1024
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b^T: contract both minor dims
 
@@ -127,9 +131,42 @@ def vmem_bytes(kernel, bq, bk, Sq, Sk, D, Dv, itemsize, group=1):
     return 2 * piped + wide + tiles * f32(bq, bk)
 
 
+def vmem_budget(kernel, Sq, Sk, D, Dv, itemsize, group=1):
+    """What ``kernel``'s blocks may keep in VMEM at these lengths and
+    widths: VMEM_LIMIT where its narrowest blocks fit under it (every
+    shape of 8,192 positions or fewer the cells run), else VMEM_MOST
+    (rows of 16,384: the whole-sequence operands alone exceed the
+    default)."""
+    narrow = [min([w for w in BLOCK_WIDTHS if S % w == 0] or [S])
+              for S in (Sq, Sk)]
+    fits = vmem_bytes(kernel, *narrow, Sq, Sk, D, Dv, itemsize,
+                      group) <= VMEM_LIMIT
+    return VMEM_LIMIT if fits else VMEM_MOST
+
+
+def vmem_limit(kernel, bq, bk, Sq, Sk, D, Dv, itemsize, group=1):
+    """The scoped VMEM a launch at blocks (bq, bk) asks Mosaic for: None
+    (Mosaic's default, VMEM_LIMIT) where ``vmem_bytes`` fits under it,
+    else the reckoning rounded up to a MiB."""
+    need = vmem_bytes(kernel, bq, bk, Sq, Sk, D, Dv, itemsize, group)
+    return None if need <= VMEM_LIMIT else -(-need // 2 ** 20) * 2 ** 20
+
+
 def _fits(kernel, bq, bk, Sq, Sk, D, Dv, itemsize, group=1):
     return vmem_bytes(kernel, bq, bk, Sq, Sk, D, Dv, itemsize,
-                      group) <= VMEM_LIMIT
+                      group) <= vmem_budget(kernel, Sq, Sk, D, Dv, itemsize,
+                                            group)
+
+
+def _compiler_params(kernel, bq, bk, Sq, Sk, D, Dv, itemsize, group):
+    """``pallas_call``'s ``compiler_params`` of a launch: the raised scoped
+    VMEM where ``vmem_limit`` asks for it, else none (the launch is built
+    as it was before any was raised)."""
+    limit = vmem_limit(kernel, bq, bk, Sq, Sk, D, Dv, itemsize, group)
+    if limit is None:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
 def default_blocks(kernel, Sq, Sk, D, Dv, dtype, causal, group=1,
@@ -145,7 +182,10 @@ def default_blocks(kernel, Sq, Sk, D, Dv, dtype, causal, group=1,
     dK/dV) and keeping wide the side its inner loop walks, whose trips
     are what a tile's fixed cost is paid on. Under a ``window`` no block is
     wider than the window rounded up to 128: a band narrower than its
-    tiles is mostly mask (a 2,048-key window leaves the rule as it is)."""
+    tiles is mostly mask (a 2,048-key window leaves the rule as it is).
+    "Fits" is under ``vmem_budget``: where no blocks fit VMEM_LIMIT (rows
+    of 16,384) the walk runs under VMEM_MOST and the launch asks for what
+    its blocks need."""
     del causal  # the sweep ranks the shapes alike causal and not
     itemsize = jnp.dtype(dtype).itemsize
     cap = max(BLOCK_WIDTHS) if window is None else max(
@@ -438,6 +478,8 @@ def _forward_launch(shapes, causal, scale, valid_len, interpret, block_q,
 
     ((BH, Sq, D), dtype), ((BHkv, Sk, _D), _), ((_, _, Dv), _) = shapes
     kv = _kv_head(BH // BHkv)
+    params = _compiler_params("fwd", block_q, block_k, Sq, Sk, D, Dv,
+                              jnp.dtype(dtype).itemsize, BH // BHkv)
     kernel = functools.partial(_fa_kernel, causal=causal, scale=scale,
                                block_k=block_k, kv_len=Sk,
                                valid_len=valid_len, window=window)
@@ -458,7 +500,7 @@ def _forward_launch(shapes, causal, scale, valid_len, interpret, block_q,
             jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )
+        **params)
     traced = jax.make_jaxpr(call)(
         *(jax.ShapeDtypeStruct(s, d) for s, d in shapes))
     return jax.extend.core.jaxpr_as_fun(traced)
@@ -570,6 +612,7 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
     kv = _kv_head(group)
     block_q, block_k = _blocks("dkv", config, q3, k3, v3, causal, valid_len,
                                window)
+    itemsize = q3.dtype.itemsize
     rows = (BH, Sq // block_q, block_q)
     row_spec = pl.BlockSpec((1,) + rows[1:], lambda b, i: (b, 0, 0))
     part = jnp.float32 if group > 1 else None
@@ -595,6 +638,8 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
             jax.ShapeDtypeStruct((BH, Sk, Dv), part or v3.dtype),
         ],
         interpret=interpret,
+        **_compiler_params("dkv", block_q, block_k, Sq, Sk, D, Dv, itemsize,
+                           group),
     )(q3, k3, v3, do3, lse.reshape(rows), delta.reshape(rows))
     if group > 1:
         dkv = [a.reshape(BHkv, group, Sk, a.shape[2]).sum(axis=1).astype(
@@ -618,6 +663,8 @@ def _fa_backward(q3, k3, v3, do3, lse, delta, causal, scale, valid_len,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
         interpret=interpret,
+        **_compiler_params("dq", block_q, block_k, Sq, Sk, D, Dv, itemsize,
+                           group),
     )(q3, k3, v3, do3, lse[:, :, None], delta[:, :, None])
     return dq, dkv[0], dkv[1]
 

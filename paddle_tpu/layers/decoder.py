@@ -122,30 +122,35 @@ def _post_norm(post_norm, d):
 
 def grouped_attention(x, num_heads, num_kv_heads, head_dim, window=None,
                       rotary=True, theta=10000.0, epsilon=1e-6,
-                      post_norm=False, prefix=None):
+                      post_norm=False, qk_norm=True, gate=True, prefix=None):
     """x + [rms_norm] W_o (attn(q, k, v) * sigmoid(h W_g)) of h =
     rms_norm(x): q = h W_q (``num_heads`` heads), k = h W_k and v = h W_v
     (``num_kv_heads`` heads, each read by num_heads / num_kv_heads q
-    heads, never repeated); q and k RMS-normed over ``head_dim`` with a
-    learned scale each; with ``rotary`` the whole heads turned by their
-    positions, pairs (i, i + head_dim/2); causal softmax(q k^T
-    head_dim^-1/2) v, with ``window`` over the last ``window`` keys only
-    (itself included). Parameters ``<prefix>.{norm,wq,wk,wv,q_norm,
-    k_norm,wg,wo}`` and, with ``post_norm``, ``post_norm``."""
+    heads, never repeated); with ``qk_norm`` q and k RMS-normed over
+    ``head_dim`` with a learned scale each; with ``rotary`` the whole heads
+    turned by their positions, pairs (i, i + head_dim/2); causal
+    softmax(q k^T head_dim^-1/2) v, with ``window`` over the last
+    ``window`` keys only (itself included); the sigmoid gate only with
+    ``gate``. Parameters ``<prefix>.{norm,wq,wk,wv,q_norm,k_norm,wg,wo}``
+    (``q_norm`` / ``k_norm`` only with ``qk_norm``, ``wg`` only with
+    ``gate``) and, with ``post_norm``, ``post_norm``."""
     if num_heads % num_kv_heads:
         raise ValueError("%d query heads cannot share %d key and value "
                          "heads" % (num_heads, num_kv_heads))
     helper = LayerHelper("grouped_attention")
     d = x.shape[-1]
-    inputs = _params(helper, prefix, x.dtype, dict({
-        "NormScale": ("norm", (d,)),
-        "WQ": ("wq", (d, num_heads * head_dim)),
-        "WK": ("wk", (d, num_kv_heads * head_dim)),
-        "WV": ("wv", (d, num_kv_heads * head_dim)),
-        "QNormScale": ("q_norm", (head_dim,)),
-        "KNormScale": ("k_norm", (head_dim,)),
-        "WG": ("wg", (d, num_heads * head_dim)),
-        "WO": ("wo", (num_heads * head_dim, d))}, **_post_norm(post_norm, d)))
+    shapes = {"NormScale": ("norm", (d,)),
+              "WQ": ("wq", (d, num_heads * head_dim)),
+              "WK": ("wk", (d, num_kv_heads * head_dim)),
+              "WV": ("wv", (d, num_kv_heads * head_dim))}
+    if qk_norm:
+        shapes.update(QNormScale=("q_norm", (head_dim,)),
+                      KNormScale=("k_norm", (head_dim,)))
+    if gate:
+        shapes["WG"] = ("wg", (d, num_heads * head_dim))
+    shapes["WO"] = ("wo", (num_heads * head_dim, d))
+    inputs = _params(helper, prefix, x.dtype,
+                     dict(shapes, **_post_norm(post_norm, d)))
     inputs["X"] = [x]
     out = _out_like(helper, x)
     helper.append_op(
@@ -153,7 +158,8 @@ def grouped_attention(x, num_heads, num_kv_heads, head_dim, window=None,
         attrs={"heads": num_heads, "kv_heads": num_kv_heads,
                "head_dim": head_dim, "window": int(window or 0),
                "rotary": bool(rotary), "theta": float(theta),
-               "epsilon": epsilon})
+               "epsilon": epsilon, "qk_norm": bool(qk_norm),
+               "gate": bool(gate)})
     return out
 
 
@@ -176,12 +182,20 @@ def gated_ffn(x, size, epsilon=1e-6, post_norm=False, prefix=None):
 
 def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
             experts_held=None, scaling=1.0, epsilon=1e-6, post_norm=False,
+            score="sigmoid", activation="silu", router_input=None,
             prefix=None):
     """x + shared(h) + the held experts' part of the routed sum, h =
     rms_norm(x). The router scores ALL ``num_experts`` with a sigmoid and
     picks the ``top_k`` largest of score + bias (the bias is a persistable
-    variable with no gradient); the weights are the picked scores without
-    the bias, normalised over the picks, times ``scaling``.
+    variable with no gradient), or (``score="softmax"``) with a softmax
+    over them and picks the ``top_k`` largest scores (no bias is made); the
+    weights are the picked scores without the bias, normalised over the
+    picks, times ``scaling``. ``router_input = (y, norm_scale)``: the
+    router reads rms_norm(y) under that existing scale parameter instead
+    of h (a router placed before attention: the block's input and the
+    attention norm's scale, which then gets the gradient of both readers).
+    An expert is (act(h W_gate) * (h W_up)) W_down, ``activation`` "silu"
+    or "relu"; ``shared_size`` 0 makes no shared expert.
 
     ``experts_held = (first, count)`` says which experts THIS program
     holds (None: all): their weights are three stacked parameters
@@ -198,30 +212,42 @@ def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
     add (``<prefix>.post_norm``); the norm of a share's partial sum is no
     part of the whole layer's norm, so shares that are to add up leave it
     off. Parameters ``<prefix>.{norm,router,router_bias,expert_gate,
-    expert_up,expert_down,shared_gate,shared_up,shared_down}``."""
+    expert_up,expert_down,shared_gate,shared_up,shared_down}``
+    (``router_bias`` only for a sigmoid router, ``shared_*`` only with a
+    ``shared_size``)."""
     first, count = experts_held or (0, num_experts)
     if not (0 <= first and count >= 1 and first + count <= num_experts):
         raise ValueError("experts_held %r lies outside the %d experts"
                          % (experts_held, num_experts))
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError("no router score %r" % (score,))
+    if activation not in ("silu", "relu"):
+        raise ValueError("no expert activation %r" % (activation,))
     helper = LayerHelper("moe_ffn")
     d = x.shape[-1]
-    inputs = _params(helper, prefix, x.dtype, dict({
-        "NormScale": ("norm", (d,)),
-        "WRouter": ("router", (d, num_experts)),
-        "ExpertGate": ("expert_gate", (count, d, expert_size)),
-        "ExpertUp": ("expert_up", (count, d, expert_size)),
-        "ExpertDown": ("expert_down", (count, expert_size, d)),
-        "SharedGate": ("shared_gate", (d, shared_size)),
-        "SharedUp": ("shared_up", (d, shared_size)),
-        "SharedDown": ("shared_down", (shared_size, d))},
-        **_post_norm(post_norm, d)))
-    bias_attr = _named(prefix, "router_bias")
-    bias_attr.trainable = False
-    bias = helper.create_parameter(
-        bias_attr, shape=[num_experts], dtype=x.dtype,
-        default_initializer=ConstantInitializer(0.0))
-    bias.stop_gradient = True
-    inputs.update(X=[x], RouterBias=[bias])
+    shapes = {"NormScale": ("norm", (d,)),
+              "WRouter": ("router", (d, num_experts)),
+              "ExpertGate": ("expert_gate", (count, d, expert_size)),
+              "ExpertUp": ("expert_up", (count, d, expert_size)),
+              "ExpertDown": ("expert_down", (count, expert_size, d))}
+    if shared_size:
+        shapes.update(SharedGate=("shared_gate", (d, shared_size)),
+                      SharedUp=("shared_up", (d, shared_size)),
+                      SharedDown=("shared_down", (shared_size, d)))
+    inputs = _params(helper, prefix, x.dtype,
+                     dict(shapes, **_post_norm(post_norm, d)))
+    inputs["X"] = [x]
+    if router_input is not None:
+        inputs.update(RouterX=[router_input[0]],
+                      RouterNormScale=[router_input[1]])
+    if score == "sigmoid":
+        bias_attr = _named(prefix, "router_bias")
+        bias_attr.trainable = False
+        bias = helper.create_parameter(
+            bias_attr, shape=[num_experts], dtype=x.dtype,
+            default_initializer=ConstantInitializer(0.0))
+        bias.stop_gradient = True
+        inputs["RouterBias"] = [bias]
     out = _out_like(helper, x)
     load = helper.create_variable_for_type_inference("int32", True)
     load.shape = (num_experts,)
@@ -231,7 +257,8 @@ def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
         type="moe_ffn", inputs=inputs,
         outputs={"Out": [out], "Load": [load], "RowsHeld": [rows]},
         attrs={"top_k": top_k, "first_expert": first,
-               "scaling": float(scaling), "epsilon": epsilon})
+               "scaling": float(scaling), "epsilon": epsilon,
+               "score": score, "activation": activation})
     return out, load, rows
 
 

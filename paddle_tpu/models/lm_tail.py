@@ -1,5 +1,5 @@
 """What the configuration-driven decoders (``latent_moe_lm``,
-``window_moe_lm``) share around their layers: the two keys of one chip's
+``window_moe_lm``, ``prerouted_moe_lm``) share around their layers: the two keys of one chip's
 share, the embedding of the held vocabulary rows, the final norm, the head
 over those rows and the loss.
 

@@ -1,7 +1,10 @@
 """Ops of a modern decoder block: ``rms_norm``, ``rotary_embedding``, and
 the half-layers ``latent_attention`` (the DeepSeek-V3 block's, arXiv:
-2412.19437 section 2.1), ``grouped_attention`` (grouped-query heads with
-QK norms, a sliding window, an output gate), ``gated_ffn`` and ``moe_ffn``.
+2412.19437 section 2.1), ``grouped_attention`` (grouped-query heads, a
+sliding window, QK norms and an output gate where asked for), ``gated_ffn``
+and ``moe_ffn`` (a sigmoid router with a selection bias or a softmax one,
+SiLU- or ReLU-gated experts, shared experts or none, a router that reads
+its own half-layer's input or another block input's norm).
 
 No 2018 reference equivalent. Each half-layer (pre-norm, the products, the
 residual add, and where its ``PostNormScale`` is given a norm of the
@@ -22,8 +25,8 @@ second level (``forward/latent_attention/attn``).
 Precision: under AMP the matrix products take bf16 operands and accumulate
 in f32; under pure AMP the residual stream and what a half-layer hands on
 is bf16. RMS statistics, rotary angles, the softmax (inside the attention
-kernel), the router's product, its sigmoid and the pick weights are f32
-always.
+kernel), the router's product, its sigmoid or softmax and the pick weights
+are f32 always.
 """
 from __future__ import annotations
 
@@ -84,6 +87,9 @@ def rotary(x, theta, layout="interleaved"):
     else:
         raise ValueError("no rotary layout %r" % (layout,))
     return out.astype(x.dtype)
+
+
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def gated(h, w_gate, w_up, w_down, operand):
@@ -177,18 +183,17 @@ def _residual(ctx, x, y, stream):
 def grouped_attention_op(ctx):
     """Out = X + N_post(W_o (o * sigmoid(h W_g))), h = RMSNorm(X): q = h
     W_q as ``heads`` heads, k = h W_k and v = h W_v as ``kv_heads`` heads
-    (q head j reads k/v head j // (heads / kv_heads)), q and k each
-    RMS-normed over the head with a learned scale; with ``rotary`` their
-    whole heads turned by their positions (pairs (i, i + head_dim / 2));
-    causal softmax(q k^T head_dim^-1/2) v, with ``window`` > 0 over the
-    last ``window`` keys only (itself included). The attention kernels run
-    under the part scope ``attn_window`` (a window) or ``attn_full``."""
+    (q head j reads k/v head j // (heads / kv_heads)); with ``qk_norm`` q
+    and k each RMS-normed over the head with a learned scale; with
+    ``rotary`` their whole heads turned by their positions (pairs (i, i +
+    head_dim / 2)); causal softmax(q k^T head_dim^-1/2) v, with ``window``
+    > 0 over the last ``window`` keys only (itself included); the gate only
+    with ``gate``. The attention kernels run under the part scope
+    ``attn_window`` (a window) or ``attn_full``."""
     x = raw_data(ctx.input("X"))
     operand, stream = _dtypes(ctx, x)
-    w_in, wq, wk, wv, w_qn, w_kn, wg, wo = (
-        raw_data(ctx.input(s)) for s in
-        ("NormScale", "WQ", "WK", "WV", "QNormScale", "KNormScale", "WG",
-         "WO"))
+    w_in, wq, wk, wv, wo = (raw_data(ctx.input(s)) for s in
+                            ("NormScale", "WQ", "WK", "WV", "WO"))
     H, Hkv, D = (int(ctx.attr(a)) for a in ("heads", "kv_heads", "head_dim"))
     window = int(ctx.attr("window", 0)) or None
     eps = ctx.attr("epsilon")
@@ -196,9 +201,13 @@ def grouped_attention_op(ctx):
     x = x.astype(stream)
     with part_scope("proj"):
         h = rms_norm(x, w_in, eps)
-        normed = lambda w, n, scale: rms_norm(
-            _mm(h, w, operand).reshape(B, S, n, D), scale, eps, stream)
-        q, k = normed(wq, H, w_qn), normed(wk, Hkv, w_kn)
+
+        def heads(w, n, scale):
+            y = _mm(h, raw_data(ctx.input(w)), operand).reshape(B, S, n, D)
+            if not ctx.attr("qk_norm", True):
+                return y.astype(stream)
+            return rms_norm(y, raw_data(ctx.input(scale)), eps, stream)
+        q, k = heads("WQ", H, "QNormScale"), heads("WK", Hkv, "KNormScale")
         v = _mm(h, wv, operand).astype(stream).reshape(B, S, Hkv, D)
     if ctx.attr("rotary", True):
         with part_scope("rope"):
@@ -206,9 +215,11 @@ def grouped_attention_op(ctx):
             q, k = rotary(q, theta, "half"), rotary(k, theta, "half")
     with part_scope("attn_window" if window else "attn_full"):
         o = attention(q, k, v, causal=True, scale=D ** -0.5, window=window)
-    with part_scope("gate"):
-        gate = jax.nn.sigmoid(_mm(h, wg, operand))
-        o = (o.reshape(B, S, H * D).astype(jnp.float32) * gate).astype(stream)
+    o = o.reshape(B, S, H * D)
+    if ctx.attr("gate", True):
+        with part_scope("gate"):
+            gate = jax.nn.sigmoid(_mm(h, raw_data(ctx.input("WG")), operand))
+            o = (o.astype(jnp.float32) * gate).astype(stream)
     with part_scope("proj"):
         a = _mm(o, wo, operand)
     ctx.set_output("Out", _residual(ctx, x, a, stream))
@@ -227,15 +238,22 @@ def gated_ffn_op(ctx):
     ctx.set_output("Out", _residual(ctx, x, y, stream))
 
 
-def route(h, w_router, bias, top_k, scaling):
-    """Sigmoid scores over ALL experts, f32. The picks are the ``top_k``
-    largest of score + bias (ties: the lower index); their weights are the
-    scores WITHOUT the bias, normalised over the picks and scaled.
+def route(h, w_router, bias, top_k, scaling, score="sigmoid"):
+    """Scores over ALL experts, f32: a sigmoid of each, or a softmax over
+    them (``score``). The picks are the ``top_k`` largest of score + bias
+    (``bias`` None: of the scores; ties: the lower index); their weights
+    are the scores WITHOUT the bias, normalised over the picks and scaled.
     Returns (idx [T, k] int32, g [T, k] f32)."""
-    s = jax.nn.sigmoid(jnp.matmul(
-        h.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _top, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], top_k)
+    logits = jnp.matmul(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError("no router score %r" % (score,))
+    chosen_by = s if bias is None else s + bias.astype(jnp.float32)[None, :]
+    _top, idx = jax.lax.top_k(chosen_by, top_k)
     g = jnp.take_along_axis(s, idx, axis=1)
     g = g / (jnp.sum(g, axis=1, keepdims=True) + 1e-20) * scaling
     return idx, g
@@ -254,10 +272,11 @@ def held_rungs(pairs, count, n_experts):
     return tuple(sorted(set(rungs)))
 
 
-def _held_rung(rows, k, operand, stream):
+def _held_rung(rows, k, operand, stream, activation):
     """The held experts' part of the routed sum over the first ``rows`` of
-    the sorted (token, pick) pairs: ``fn(h, g, eg, eu, ed, order, inv,
-    sizes) -> [T, d] f32``. Right whenever ``sum(sizes) <= rows``. Its two
+    the sorted (token, pick) pairs, experts gated by ``activation``
+    (``ACTIVATIONS``): ``fn(h, g, eg, eu, ed, order, inv, sizes) -> [T, d]
+    f32``. Right whenever ``sum(sizes) <= rows``. Its two
     row movements are gathers in both directions (``inv`` is the inverse
     of the permutation ``order``, which autodiff cannot know: it would
     scatter-add): a token's picks read row ``inv`` of the ``rows``
@@ -321,7 +340,7 @@ def _held_rung(rows, k, operand, stream):
             rd = lambda a, w: jnp.where(live, jax.lax.ragged_dot(
                 a.astype(operand), w.astype(operand), sizes,
                 preferred_element_type=jnp.float32), 0.0)
-            ys = rd(jax.nn.silu(rd(xs, eg)) * rd(xs, eu), ed)
+            ys = rd(ACTIVATIONS[activation](rd(xs, eg)) * rd(xs, eu), ed)
         with part_scope("route"):
             ys = (ys * g.reshape(-1)[o][:, None]).astype(stream)
             return combine(ys, o, inv)
@@ -330,10 +349,11 @@ def _held_rung(rows, k, operand, stream):
 
 
 @functools.lru_cache(maxsize=None)
-def _held_part(rungs, k, operand, stream, epsilon):
+def _held_part(rungs, k, operand, stream, epsilon, activation="silu"):
     """``fn(moved, order, inv, sizes)`` -> the layer's result [B, S, d],
     where ``moved = (h, g, eg, eu, ed, x, shared[, post norm scale])`` are
-    the operands a gradient flows to: ``x + N(shared + routed)`` with
+    the operands a gradient flows to (``shared`` None: the layer has no
+    shared expert): ``x + N(shared + routed)`` with
     ``routed`` [T, d] f32 ``_held_rung`` at the smallest of ``rungs``
     (ascending, the last one all the pairs) that holds the step's
     ``sum(sizes)``, chosen on the device.
@@ -357,13 +377,15 @@ def _held_part(rungs, k, operand, stream, epsilon):
     every layer's and both phases' instructions keep their own scope."""
 
     def whole(rows):
-        rung = _held_rung(rows, k, operand, stream)
+        rung = _held_rung(rows, k, operand, stream, activation)
 
         def fn(moved, order, inv, sizes):
             x, shared = moved[5:7]
-            routed = rung(*moved[:5], order, inv, sizes)
-            return _add_normed(x, (shared + routed).reshape(x.shape), stream,
-                               epsilon, *moved[7:])
+            y = rung(*moved[:5], order, inv, sizes)
+            if shared is not None:
+                y = shared + y
+            return _add_normed(x, y.reshape(x.shape), stream, epsilon,
+                               *moved[7:])
         return jax.jit(fn, inline=True)
     fns = [whole(rows) for rows in rungs]
     if len(fns) == 1:
@@ -397,8 +419,14 @@ def _held_part(rungs, k, operand, stream, epsilon):
 @register_op("moe_ffn", infer_shape=_infer_out_like_x)
 def moe_ffn_op(ctx):
     """Out = X + shared(h) + sum over the picks that fall on a HELD expert
-    of g_k E_k(h), h = RMSNorm(X). The router scores all ``n_experts``;
-    this op holds the experts ``[first, first + count)`` (stacked weights
+    of g_k E_k(h), h = RMSNorm(X), E_k gated by ``activation`` (silu or
+    relu). The router scores all ``n_experts`` (``score``: sigmoid, picked
+    by score + ``RouterBias``; softmax, no bias) from h or, where the op is
+    given ``RouterX`` and ``RouterNormScale``, from RMSNorm(RouterX) under
+    that scale (a router placed before the attention half-layer reads that
+    half-layer's input norm; its own product, norm, top-k and sort all sit
+    in the part scope ``route``). Without ``SharedGate`` there is no shared
+    expert. This op holds the experts ``[first, first + count)`` (stacked weights
     ``[count, ...]``) and leaves out what the others would add: g stays
     normalised over all the picks; no capacity is set, nothing is dropped;
     the held part runs over the smallest rung that holds the step's
@@ -418,21 +446,29 @@ def moe_ffn_op(ctx):
     int32[1]: pairs that fell on held experts."""
     x = raw_data(ctx.input("X"))
     operand, stream = _dtypes(ctx, x)
-    w_post, w_router, bias, eg, eu, ed, sg, su, sd = (
+    w_post, w_router, eg, eu, ed = (
         raw_data(ctx.input(s)) for s in
-        ("NormScale", "WRouter", "RouterBias", "ExpertGate", "ExpertUp",
-         "ExpertDown", "SharedGate", "SharedUp", "SharedDown"))
+        ("NormScale", "WRouter", "ExpertGate", "ExpertUp", "ExpertDown"))
     k, first = int(ctx.attr("top_k")), int(ctx.attr("first_expert"))
     count, n_experts = eg.shape[0], w_router.shape[1]
+    eps = ctx.attr("epsilon")
     B, S, d = x.shape
     T = B * S
     x = x.astype(stream)
-    h = rms_norm(x, w_post, ctx.attr("epsilon")).reshape(T, d)
+    h = rms_norm(x, w_post, eps).reshape(T, d)
     from .. import tune
     rungs = held_rungs(T * k, count, n_experts)
     tune.count_moe_rungs(T * k, rungs)
     with part_scope("route"):
-        idx, g = route(h, w_router, bias, k, ctx.attr("scaling"))
+        hr = h
+        if ctx.has_input("RouterX"):
+            hr = rms_norm(raw_data(ctx.input("RouterX")).astype(stream),
+                          raw_data(ctx.input("RouterNormScale")),
+                          eps).reshape(T, d)
+        bias = raw_data(ctx.input("RouterBias")) \
+            if ctx.has_input("RouterBias") else None
+        idx, g = route(hr, w_router, bias, k, ctx.attr("scaling"),
+                       ctx.attr("score", "sigmoid"))
         flat = idx.reshape(T * k)
         load = jnp.sum(flat[:, None] == jnp.arange(n_experts)[None, :],
                        axis=0, dtype=jnp.int32)
@@ -442,12 +478,17 @@ def moe_ffn_op(ctx):
                             stable=True)
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(T * k, dtype=order.dtype))
-    with part_scope("shared"):
-        shared = gated(h, sg, su, sd, operand)
+    shared = None
+    if ctx.has_input("SharedGate"):
+        with part_scope("shared"):
+            shared = gated(h, *(raw_data(ctx.input(s)) for s in
+                                ("SharedGate", "SharedUp", "SharedDown")),
+                           operand)
     post = (raw_data(ctx.input("PostNormScale")),) \
         if ctx.has_input("PostNormScale") else ()
     ctx.set_output("Out", _held_part(
-        rungs, k, operand, stream, float(ctx.attr("epsilon")))(
+        rungs, k, operand, stream, float(eps),
+        ctx.attr("activation", "silu"))(
         (h, g, eg, eu, ed, x, shared) + post, order, inv, sizes))
     ctx.set_output("Load", load)
     ctx.set_output("RowsHeld", jnp.sum(sizes).reshape(1))

@@ -199,6 +199,62 @@ def test_flash_attention_vmem_reckoning_refuses_before_mosaic(one_chip,
             _compile(fn, one_chip, *shapes)
 
 
+def _long_row_programs(fa, window):
+    """(fn, shapes) of the forward and of both backward kernels at the
+    published heads of 28 q / 4 k/v heads of 128, 16,384 positions, bf16,
+    causal, under ``window``."""
+    h, hkv, s, d = 28, 4, 16384, 128
+
+    def fwd(q, k, v):
+        return fa._fa_forward(q, k, v, True, d ** -0.5, s, interpret=False,
+                              window=window)
+
+    def bwd(q, k, v, do, lse, delta):
+        return fa._fa_backward(q, k, v, do, lse, delta, True, d ** -0.5, s,
+                               interpret=False, window=window)
+
+    q, kv = ((h, s, d), jnp.bfloat16), ((hkv, s, d), jnp.bfloat16)
+    row = ((h, s), jnp.float32)
+    return (fwd, (q, kv, kv)), (bwd, (q, kv, kv, q, row, row))
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["w4096", "full"])
+def test_flash_attention_rows_of_16384_compile(one_chip, window):
+    """The three kernels at the prerouted cell's shape, 28 q heads on 4 k/v
+    heads of 128 and 16,384 positions, under its 4,096-key window and under
+    none: the rule's blocks are 512 x 512, which no scoped VMEM of Mosaic's
+    default holds, and each launch asks for its reckoning."""
+    fa = _kernel_module("flash_attention")
+    for kernel in fa.KERNELS:
+        assert fa.default_blocks(kernel, 16384, 16384, 128, 128,
+                                 jnp.bfloat16, True, 7, window) == (512, 512)
+        assert fa.vmem_limit(kernel, 512, 512, 16384, 16384, 128, 128, 2,
+                             7) > fa.VMEM_LIMIT
+    (fwd, f_shapes), (bwd, b_shapes) = _long_row_programs(fa, window)
+    _compile(fwd, one_chip, *f_shapes)
+    assert _compile(bwd, one_chip, *b_shapes).count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("less", [None, 4 * 2 ** 20],
+                         ids=["default", "reckoning_less_4MiB"])
+def test_flash_attention_rows_of_16384_need_what_is_reckoned(
+        one_chip, monkeypatch, less):
+    """The same launches at 512 x 512 under Mosaic's default scoped VMEM,
+    and with 4 MiB less than ``vmem_bytes`` reckons: Mosaic refuses every
+    one (the reckoning errs high by 1-3 MiB at this shape)."""
+    fa = _kernel_module("flash_attention")
+    real = fa.vmem_limit
+    monkeypatch.setattr(fa, "vmem_limit", lambda *a: None if less is None
+                        else real(*a) - less)
+    fa._forward_launch.cache_clear()
+    try:
+        for fn, shapes in _long_row_programs(fa, 4096):
+            with pytest.raises(Exception, match="vmem"):
+                _compile(fn, one_chip, *shapes)
+    finally:
+        fa._forward_launch.cache_clear()
+
+
 @pytest.mark.parametrize("kernel", ["lstm", "gru"])
 def test_fused_rnn_compiles(one_chip, kernel):
     """The stacked-LSTM cell shape: T100, N64, D512, f32."""

@@ -418,9 +418,13 @@ def test_vmem_reckoning_refuses_what_the_chip_refused_and_falls_back():
     for kernel, pick in picks.items():
         assert fa.vmem_bytes(kernel, 512, 512, *long, 2) > fa.VMEM_LIMIT
         assert fa.vmem_bytes(kernel, *pick, *long, 2) <= fa.VMEM_LIMIT
-    # nothing fits 16,384 positions: the narrowest tile is what is left
-    assert fa.default_blocks("dkv", 16384, 16384, 64, 64, BF16, True) == (
-        128, 128)
+    # nothing fits 16,384 positions under Mosaic's default: the walk runs
+    # under VMEM_MOST, and the launch asks for what its blocks need
+    long = (16384, 16384, 64, 64)
+    assert fa.vmem_bytes("dkv", 128, 128, *long, 2) > fa.VMEM_LIMIT
+    assert fa.default_blocks("dkv", *long, BF16, True) == (512, 512)
+    assert fa.vmem_limit("dkv", 512, 512, *long, 2) >= fa.vmem_bytes(
+        "dkv", 512, 512, *long, 2)
 
 
 def _traced_blocks(S, D, Dv, config=None, dtype=BF16):
@@ -580,6 +584,42 @@ def test_the_cells_banded_launch_visits_70_of_256_tiles():
                                         "square": 256}
         assert fa.tile_counts(kernel, 512, 512, 8192, 8192, 8192, True) == {
             "visited": 136, "masked": 16, "square": 256}
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["w4096", "full"])
+def test_rows_of_16384_take_the_widest_blocks_and_ask_for_their_vmem(window):
+    """The published shape of 28 q heads on 4 k/v heads of 128: at 16,384
+    positions k and v (q and dO) whole in VMEM are 16 MiB twice over, so no
+    blocks fit Mosaic's default; the rule then walks under VMEM_MOST, picks
+    512 x 512 for all three kernels and each launch asks for its reckoning
+    rounded up to a MiB. At 8,192 positions (the window / full cell's
+    shape) nothing is raised: those launches are built as before."""
+    for kernel in fa.KERNELS:
+        args = (16384, 16384, 128, 128, 2, 7)
+        assert fa.vmem_budget(kernel, *args) == fa.VMEM_MOST
+        assert fa.default_blocks(kernel, *args[:4], BF16, True, 7,
+                                 window) == (512, 512)
+        need = fa.vmem_bytes(kernel, 512, 512, *args)
+        assert fa.VMEM_LIMIT < need <= fa.vmem_limit(
+            kernel, 512, 512, *args) < need + 2 ** 20
+        cell = (8192, 8192, 128, 128, 2, 8)
+        assert fa.vmem_budget(kernel, *cell) == fa.VMEM_LIMIT
+        assert fa.vmem_limit(kernel, 512, 512, *cell) is None
+
+
+def test_the_16384_row_launches_visit_only_the_band():
+    """16,384 positions, a 4,096-key window, 512 x 512: a q block visits
+    the 8 tiles behind its own and that one (fewer at the row's start),
+    252 of the square's 1,024 tiles, masking its own (the diagonal) and the
+    oldest (the band's edge) where it has 8 behind; the triangle has 528
+    (32 masked). dK/dV's k blocks see the same tiles."""
+    for kernel in fa.KERNELS:
+        assert fa.tile_counts(kernel, 512, 512, 16384, 16384, 16384, True,
+                              4096) == {"visited": 252, "masked": 56,
+                                        "square": 1024}
+        assert fa.tile_counts(kernel, 512, 512, 16384, 16384, 16384,
+                              True) == {"visited": 528, "masked": 32,
+                                        "square": 1024}
 
 
 def test_a_window_narrower_than_its_tiles_narrows_them():
