@@ -914,7 +914,9 @@ class Executor(object):
         # launches whose backward reads the o and lse their forward kept
         # (flash_kept_bytes of them); moe_rungs the tally of the row
         # counts each expert layer's held part was traced at ({"49152 ->
-        # 12288/24576/49152": n}; ops/decoder_ops.py: held_rungs)
+        # 12288/24576/49152": n}; ops/decoder_ops.py: held_rungs) and
+        # gmm_blocks the tiling each grouped-matmul launch was traced at
+        # ({"gmm 256x2048x768 g16": n}; kernels/grouped_matmul.py)
         # comm_path says HOW the last compiled program's DP grads sync:
         # "explicit" = routed through the paddle_tpu.comm collectives
         # (comm_* stats measured from the traced plan), "model" = GSPMD
@@ -936,6 +938,7 @@ class Executor(object):
                       "tune_fallbacks": 0, "flash_blocks": {},
                       "flash_tiles": {}, "flash_kept": {},
                       "flash_kept_bytes": 0, "moe_rungs": {},
+                      "gmm_blocks": {},
                       "elastic_resizes": 0, "elastic_lost_ranks": 0,
                       "elastic_requeued_tasks": 0,
                       "elastic_resume_ms": 0.0,

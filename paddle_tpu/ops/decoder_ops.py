@@ -39,6 +39,7 @@ import numpy as np
 
 from ..core.executor import raw_data
 from ..core.registry import register_op
+from ..kernels.grouped_matmul import grouped_matmul
 from ..profiler import part_scope
 from .attention_ops import attention
 
@@ -276,7 +277,10 @@ def _held_rung(rows, k, operand, stream, activation):
     """The held experts' part of the routed sum over the first ``rows`` of
     the sorted (token, pick) pairs, experts gated by ``activation``
     (``ACTIVATIONS``): ``fn(h, g, eg, eu, ed, order, inv, sizes) -> [T, d]
-    f32``. Right whenever ``sum(sizes) <= rows``. Its two
+    f32``. Right whenever ``sum(sizes) <= rows``: the rows from
+    ``sum(sizes)`` on are pairs of no held expert, where the grouped
+    products (``kernels.grouped_matmul``) and their left operand's gradient
+    are exactly 0, so those pairs add nothing either way. Its two
     row movements are gathers in both directions (``inv`` is the inverse
     of the permutation ``order``, which autodiff cannot know: it would
     scatter-add): a token's picks read row ``inv`` of the ``rows``
@@ -296,16 +300,14 @@ def _held_rung(rows, k, operand, stream, activation):
         return got.astype(jnp.float32).sum(axis=1)
 
     @jax.custom_vjp
-    def dispatch(h, o, inv, live):          # [T, d] -> [rows, d], sorted
+    def dispatch(h, o, inv):                # [T, d] -> [rows, d], sorted
         return h[o // k]
 
-    def dispatch_fwd(h, o, inv, live):
-        return dispatch(h, o, inv, live), (inv, live)
+    def dispatch_fwd(h, o, inv):
+        return dispatch(h, o, inv), inv
 
-    def dispatch_bwd(res, ct):
-        inv, live = res
-        back = summed_picks(jnp.where(live, ct, 0), inv)
-        return back.astype(ct.dtype), None, None, None
+    def dispatch_bwd(inv, ct):
+        return summed_picks(ct, inv).astype(ct.dtype), None, None
 
     dispatch.defvjp(dispatch_fwd, dispatch_bwd)
 
@@ -326,20 +328,16 @@ def _held_rung(rows, k, operand, stream, activation):
 
     def fn(h, g, eg, eu, ed, order, inv, sizes):
         o = order[:rows]
-        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
         with part_scope("route"):
-            xs = dispatch(h, o, inv, live)
-        # (XLA:TPU makes ``ragged-dot-*`` kernels of the ragged dots under
-        # an ``op_name`` of its own: the scope table calls those unscoped)
+            xs = dispatch(h, o, inv)
         with part_scope("experts"):
-            # rows past the held pairs belong to no group and a grouped
-            # product leaves there whatever the buffer held (NaN on the
-            # chip): every result is SELECTED by ``live`` before anything
-            # multiplies it, so that neither the values nor their gradients
-            # meet the garbage
-            rd = lambda a, w: jnp.where(live, jax.lax.ragged_dot(
-                a.astype(operand), w.astype(operand), sizes,
-                preferred_element_type=jnp.float32), 0.0)
+            # rows past the held pairs belong to no group: the kernels'
+            # results and left-operand gradients are exactly 0 there, so
+            # neither the values nor their gradients meet what the buffer
+            # held in those rows. The weights enter as they are stored
+            # (f32 masters under AMP): the kernels cast them to the
+            # operand dtype in VMEM and write their gradients in f32
+            rd = lambda a, w: grouped_matmul(a.astype(operand), w, sizes)
             ys = rd(ACTIVATIONS[activation](rd(xs, eg)) * rd(xs, eu), ed)
         with part_scope("route"):
             ys = (ys * g.reshape(-1)[o][:, None]).astype(stream)
@@ -432,9 +430,9 @@ def moe_ffn_op(ctx):
     the held part runs over the smallest rung that holds the step's
     ``RowsHeld``. The (token, pick) pairs are sorted by expert, held
     experts first, so the held ones are a prefix of the sorted order, and
-    only a prefix moves: the gathers, the grouped products
-    (``jax.lax.ragged_dot``) and the weighted sum back run over the first
-    ``C`` sorted pairs, ``C`` the smallest of ``held_rungs`` (static sizes,
+    only a prefix moves: the gathers, the grouped products (Pallas
+    kernels, ``kernels.grouped_matmul``) and the weighted sum back run over
+    the first ``C`` sorted pairs, ``C`` the smallest of ``held_rungs`` (static sizes,
     the last one every pair) that holds this step's ``RowsHeld``, picked on
     the device by a ``lax.switch`` (``_held_part``, which every expert
     layer of one shape shares: a rung's Python is traced once, not once a

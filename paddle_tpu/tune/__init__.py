@@ -56,6 +56,7 @@ __all__ = [
     "parity_ok", "parity_report",
     "lookup", "record_fallback", "counters", "reset_counters",
     "count_flash_blocks", "count_flash_kept", "count_moe_rungs",
+    "count_gmm_blocks",
 ]
 
 # -- dispatch counters --------------------------------------------------------
@@ -85,6 +86,11 @@ _flash_kept = {}
 # compiled at (ops/decoder_ops.py: held_rungs); one rung: the layer holds
 # every expert and has no conditional
 _moe_rungs = {}
+# {"gmm 256x2048x768 g16": launches traced}: the tiling (rows x contracted
+# width x result width) each grouped-matmul launch was built with, by kernel
+# (gmm, gmm_t, tgmm; kernels/grouped_matmul.py: default_tiling), and its
+# number of groups
+_gmm_blocks = {}
 
 
 def _bump(name):
@@ -133,6 +139,14 @@ def count_moe_rungs(pairs, rungs):
         _moe_rungs[name] = _moe_rungs.get(name, 0) + 1
 
 
+def count_gmm_blocks(kernel, tm, tk, tn, groups):
+    """One grouped-matmul launch (``kernel``: gmm / gmm_t / tgmm) traced at
+    tiling (tm, tk, tn) over ``groups`` groups."""
+    name = "%s %dx%dx%d g%d" % (kernel, tm, tk, tn, groups)
+    with _counters_lock:
+        _gmm_blocks[name] = _gmm_blocks.get(name, 0) + 1
+
+
 def counters():
     """Snapshot of the process-level dispatch counters; ``flash_blocks``
     is the {"<kernel> <block_q>x<block_k>[ g<group>][ w<window>]":
@@ -142,13 +156,15 @@ def counters():
     launch's name, whose backward reads the o and lse that launch kept
     (``flash_kept_bytes`` of them in all), and ``moe_rungs`` the
     {"<pairs> -> <rung>/<rung>/...": launches} tally of the expert layers
-    traced."""
+    traced, ``gmm_blocks`` the {"<kernel> <tm>x<tk>x<tn> g<groups>":
+    launches} tally of the grouped-matmul launches."""
     with _counters_lock:
         return dict(_counters, flash_blocks=dict(_flash_blocks),
                     flash_tiles={k: dict(v)
                                  for k, v in _flash_tiles.items()},
                     flash_kept=dict(_flash_kept),
-                    moe_rungs=dict(_moe_rungs))
+                    moe_rungs=dict(_moe_rungs),
+                    gmm_blocks=dict(_gmm_blocks))
 
 
 def reset_counters():
@@ -160,6 +176,7 @@ def reset_counters():
         _flash_tiles.clear()
         _flash_kept.clear()
         _moe_rungs.clear()
+        _gmm_blocks.clear()
     profiler.reset_tune_counters()
 
 

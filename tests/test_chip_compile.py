@@ -1,4 +1,4 @@
-"""The six Pallas kernels, compiled by the TPU's own compiler for a
+"""The seven Pallas kernels, compiled by the TPU's own compiler for a
 *described* v5e (no chip attached), at the real widths the chip smoke and
 the benchmark cells use. A compile that passes here is not a chip run: it
 proves Mosaic accepts the kernel (block shapes, dot forms, VMEM budget),
@@ -255,6 +255,40 @@ def test_flash_attention_rows_of_16384_need_what_is_reckoned(
         fa._forward_launch.cache_clear()
 
 
+# the expert layers of the language-model cells: (d, f, held experts) and
+# their first and top rungs
+GMM_CELLS = {"K": (2048, 768, 16, (12288, 49152)),
+             "T": (2048, 1024, 16, (16384, 65536)),
+             "P": (2560, 768, 8, (24576, 98304))}
+
+
+@pytest.mark.parametrize("cell,rung", [(c, i) for c in GMM_CELLS
+                                       for i in (0, 1)],
+                         ids=["%s-%s" % (c, r) for c in GMM_CELLS
+                              for r in ("first", "top")])
+def test_grouped_matmul_kernels_compile(one_chip, monkeypatch, cell, rung):
+    """The three grouped-matmul kernels (product, left operand's gradient,
+    weight gradient) of an expert layer's gate and down products, bf16 rows
+    and f32 master weights (cast in VMEM), at the cell's first and top
+    rung, at the tilings the rule picks: one differentiated program, six
+    launches."""
+    gm = _kernel_module("grouped_matmul")
+    monkeypatch.setattr(gm, "on_tpu", lambda: True)
+    d, f, groups, rungs = GMM_CELLS[cell]
+    rows = rungs[rung]
+
+    def step(x, w_in, w_out, sizes):
+        def loss(x, w_in, w_out):
+            h = gm.grouped_matmul(x, w_in, sizes).astype(jnp.bfloat16)
+            return jnp.sum(gm.grouped_matmul(h, w_out, sizes))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w_in, w_out)
+
+    text = _compile(step, one_chip, ((rows, d), jnp.bfloat16),
+                    ((groups, d, f), jnp.float32),
+                    ((groups, f, d), jnp.float32), ((groups,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6
+
+
 @pytest.mark.parametrize("kernel", ["lstm", "gru"])
 def test_fused_rnn_compiles(one_chip, kernel):
     """The stacked-LSTM cell shape: T100, N64, D512, f32."""
@@ -385,3 +419,61 @@ def test_a_recomputed_layer_shares_the_forward_op_s_flash_launch(
     finally:
         pt.amp.force(prev)
     assert len(_forward_kernels(text)) == (3 if keep else 6)
+
+
+def test_the_grouped_products_carry_the_experts_scope_in_both_phases(
+        one_chip, monkeypatch):
+    """A training step of one ``moe_ffn`` that holds 4 of 32 experts (three
+    rungs behind the switch), recomputed, under pure AMP: every
+    grouped-matmul launch after XLA is a custom call whose scope is
+    ``forward/moe_ffn/experts`` or ``backward/moe_ffn/experts``, and each
+    branch of each phase has its rung's launches (forward: 3 products;
+    backward: the 3 replayed, 3 left-operand and 3 weight gradients)."""
+    import collections
+    import paddle_tpu as pt
+    from paddle_tpu import layers as L, profiler
+    from paddle_tpu.core.executor import RngSource, trace_ops
+    gm = _kernel_module("grouped_matmul")
+    monkeypatch.setattr(gm, "on_tpu", lambda: True)
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        x = L.data("x", shape=[384, 64], dtype="float32")
+        x.stop_gradient = False
+        out, _load, _held = L.moe_ffn(x, 32, 4, 32, 64, experts_held=(6, 4),
+                                      scaling=2.448, prefix="m")
+        pt.amp.enable(main, pure=True)
+        pt.optimizer.SGDOptimizer(learning_rate=0.0).minimize(
+            L.mean(out * out))
+        pt.memory_optimize(main, remat_types=("moe_ffn",))
+    exe = pt.Executor(pt.CPUPlace())
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        state = {n: jax.ShapeDtypeStruct(
+            scope.find_var(n).shape, scope.find_var(n).dtype,
+            sharding=one_chip) for n in exe._state_inputs(main, scope,
+                                                          {"x"})}
+    feed = {"x": jax.ShapeDtypeStruct((2, 384, 64), jnp.float32,
+                                      sharding=one_chip)}
+
+    def step(state, feed):
+        env = dict(feed, **state)
+        trace_ops(main.global_block(), env, RngSource(jax.random.PRNGKey(0)))
+        return {n: env[n] for n in state}
+
+    prev = pt.amp.force(True)
+    try:
+        text = jax.jit(step).lower(state, feed).compile().as_text()
+    finally:
+        pt.amp.force(prev)
+    _module, table = profiler.scopes_of_module(text)
+    launches = collections.Counter()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?(grouped_(\w+?))\.\d+ = ", line)
+        if m and 'custom_call_target="tpu_custom_call"' in line:
+            name = line.split("=", 1)[0].strip().lstrip("%")
+            launches[(table[name], m.group(2))] += 1
+    rungs = 3
+    assert launches == {("forward/moe_ffn/experts", "gmm"): 3 * rungs,
+                        ("backward/moe_ffn/experts", "gmm"): 3 * rungs,
+                        ("backward/moe_ffn/experts", "gmm_t"): 3 * rungs,
+                        ("backward/moe_ffn/experts", "tgmm"): 3 * rungs}

@@ -424,48 +424,47 @@ def test_a_compiler_made_kernel_stays_unscoped_beside_the_part_scopes():
 @pytest.mark.parametrize("where", ["model", "rung"])
 def test_rows_of_no_group_never_reach_the_result_or_the_gradients(
         monkeypatch, where):
-    """On the chip a grouped product leaves whatever the buffer held (NaN,
-    seen by ``chip_smoke.py``) in the rows past the held pairs, forward and
-    in the gradient of its left operand. With NaN planted there the loss
-    and every gradient are what they are without: in the small model (one
-    rung: all the pairs) and in a held share's rungs, whose rows past
-    ``RowsHeld`` are such rows too."""
+    """The rows past the held pairs belong to no group, and what a buffer
+    holds there is no number (NaN on the chip, seen by ``chip_smoke.py``
+    when a grouped product left it there). With NaN planted in those rows
+    of every grouped product's left operand, forward, and of the gradient
+    of its result, backward, the loss and every gradient are what they
+    are without: ``grouped_matmul`` gives exactly 0 in those rows of its
+    result and of its left operand's gradient, and leaves them out of the
+    weight gradient. In the small model (one rung: all the pairs) and in a
+    held share's rungs, whose rows past ``RowsHeld`` are such rows too."""
     config = tiny(True)
     batch = batches(config, 17, 1)[0]
-    real = jax.lax.ragged_dot
+    real = decoder_ops.grouped_matmul
 
-    def dead_rows_nan(out, sizes):
-        rows = jnp.arange(out.shape[0])[:, None]
-        return jnp.where(rows < jnp.sum(sizes), out, jnp.nan)
+    def dead_rows_nan(a, sizes):
+        rows = jnp.arange(a.shape[0])[:, None]
+        return jnp.where(rows < jnp.sum(sizes), a, jnp.nan)
 
     @jax.custom_vjp
-    def planted(lhs, rhs, sizes):
-        return dead_rows_nan(real(lhs, rhs, sizes,
-                                  preferred_element_type=jnp.float32), sizes)
+    def operand(a, sizes):          # NaN in the operand, forward
+        return dead_rows_nan(a, sizes)
 
-    def fwd(lhs, rhs, sizes):
-        return planted(lhs, rhs, sizes), (lhs, rhs, sizes)
+    operand.defvjp(lambda a, sizes: (operand(a, sizes), None),
+                   lambda _res, g: (g, None))
 
-    def bwd(res, g):
-        lhs, rhs, sizes = res
-        _out, vjp = jax.vjp(lambda a, b: real(
-            a, b, sizes, preferred_element_type=jnp.float32), lhs, rhs)
-        live = (jnp.arange(g.shape[0]) < jnp.sum(sizes))[:, None]
-        d_lhs, d_rhs = vjp(jnp.where(live, g, 0.0))
-        return dead_rows_nan(d_lhs, sizes), d_rhs, None
+    @jax.custom_vjp
+    def result(y, sizes):           # NaN in the result's gradient
+        return y
 
-    planted.defvjp(fwd, bwd)
+    result.defvjp(lambda y, sizes: (y, sizes),
+                  lambda sizes, g: (dead_rows_nan(g, sizes), None))
     got, traced = [], []
 
-    def plant_it(a, b, s, **_kw):
-        traced.append(a.shape)
-        return planted(a, b, s)
+    def plant_it(lhs, rhs, sizes, **kw):
+        traced.append(lhs.shape)
+        return result(real(operand(lhs, sizes), rhs, sizes, **kw), sizes)
     for plant in (False, True):
         # the held part keeps its traced rungs (``_held_part``): what was
         # traced without the plant must not answer for the run with it
         decoder_ops._held_part.cache_clear()
         if plant:
-            monkeypatch.setattr(jax.lax, "ragged_dot", plant_it)
+            monkeypatch.setattr(decoder_ops, "grouped_matmul", plant_it)
         if where == "rung":
             # 0, ~1.5x and ~3x the expected pairs held: the first rung
             # twice and the second, each with dead rows inside it
@@ -574,12 +573,20 @@ def held_layer(remat, one_rung=False, cached=True):
     return layer
 
 
+def _switches(text):
+    """The ``conditional`` instructions of a compiled module's text that
+    the program made: not those inside a grouped-matmul kernel, which the
+    Pallas interpreter makes of the kernel's ``pl.when`` on the CPU (on the
+    chip the kernel is one custom call)."""
+    return [line for line in text.splitlines() if " conditional(" in line
+            and not re.search(r"/grouped_(gmm|gmm_t|tgmm)/", line)]
+
+
 def _branch_computations(text):
     out = []
-    for line in text.splitlines():
-        if " conditional(" in line:
-            out += re.search(r"branch_computations=\{([^}]*)\}",
-                             line).group(1).replace("%", "").split(", ")
+    for line in _switches(text):
+        out += re.search(r"branch_computations=\{([^}]*)\}",
+                         line).group(1).replace("%", "").split(", ")
     return out
 
 
@@ -689,7 +696,7 @@ def test_a_layer_that_holds_every_expert_has_no_conditional():
         exe.run(main, feed={"x": _held_x()}, fetch_list=[out])
     step = list(executor.compiled_steps())[-1]
     text = step.fn.lower(*step._avals).compile().as_text()
-    assert "moe_ffn" in text and " conditional(" not in text
+    assert "moe_ffn" in text and not _switches(text)
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
@@ -700,7 +707,7 @@ def test_no_conditional_of_the_gradient_returns_a_rungs_arrays(remat):
     one backward (the recomputed forward's is dead), and what they return
     is the layer's result and the operands' gradients."""
     layer = held_layer(remat)
-    conds = [l for l in layer["text"].splitlines() if " conditional(" in l]
+    conds = _switches(layer["text"])
     assert len(conds) == 2, conds
     for line in conds:
         result = line.split(" conditional(")[0].split("=", 1)[1]
@@ -725,6 +732,43 @@ def test_the_rungs_are_counted_where_the_flash_blocks_are():
                                                           H_PAIRS): 2}
     tune.reset_counters()
     assert tune.counters()["moe_rungs"] == {}
+
+
+def test_every_held_product_is_counted_on_the_grouped_matmul_kernels(
+        capsys):
+    """Each rung of the held part makes its three grouped products (gate
+    and up: [rows, 64] x [4, 64, 32]; down: [rows, 32] x [4, 32, 64]) on
+    the kernels, traced in the step's forward, the checkpoint's recomputed
+    forward and the backward's ``jax.vjp``, whose gradients are a ``gmm_t``
+    and a ``tgmm`` each: ``tune.counters()["gmm_blocks"]``, mirrored in
+    ``Executor.stats`` and printed in the scope table's header."""
+    import importlib.util
+    import os
+    from paddle_tpu import tune
+    tune.reset_counters()
+    # a rung traced before answers from jax's cache and launches nothing
+    decoder_ops._held_part.cache_clear()
+    layer = held_layer(True, cached=False)
+    decoder_ops._held_part.cache_clear()
+    rungs = len(layer["rungs"])
+    counted = tune.counters()["gmm_blocks"]
+    assert counted == {"gmm 256x64x32 g4": 3 * 2 * rungs,
+                       "gmm 256x32x64 g4": 3 * rungs,
+                       "gmm_t 256x32x64 g4": 2 * rungs,
+                       "tgmm 256x64x32 g4": 2 * rungs,
+                       "gmm_t 256x64x32 g4": rungs,
+                       "tgmm 256x32x64 g4": rungs}
+    assert layer["exe"].stats["gmm_blocks"] == counted
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))), "tools", "scope_table.py")
+    spec = importlib.util.spec_from_file_location("scope_table", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    tool.report("step", 1, {}, tune.counters())
+    assert "gmm_blocks: gmm 256x32x64 g4 x%d, gmm 256x64x32 g4 x%d" % (
+        3 * rungs, 6 * rungs) in capsys.readouterr().out
+    tune.reset_counters()
+    assert tune.counters()["gmm_blocks"] == {}
 
 
 def _step_of_expert_layers(monkeypatch, n_layers, shared):

@@ -12,7 +12,8 @@ instructions, per instruction its scope, result type, fusion kind and time.
 what a ``fusion.N`` reads and writes. The cell's result (every per-layer
 metric of a ``--trace 1`` run) goes into the ``--out`` file beside the table,
 with ``tune.counters()`` (the header's lines: dispatch gauges, the blocks
-each flash-attention launch was traced at, the differentiated launches
+each flash-attention launch was traced at, the tiling of each
+grouped-matmul launch of the held experts, the differentiated launches
 whose o and lse the backward reads kept, the row counts each expert
 layer's held part was compiled at) and, where the cell's program has expert
 layers whose ``Load`` / ``RowsHeld`` its Trainer fetches, the rung every
@@ -237,6 +238,9 @@ def report(module, steps, rows, counters=None, rungs=None):
                  counters["tune_fallbacks"],
                  ", ".join("%s x%d" % kv for kv in sorted(blocks.items()))
                  or "none"))
+        print("gmm_blocks: %s" % (", ".join(
+            "%s x%d" % kv for kv in sorted(counters.get(
+                "gmm_blocks", {}).items())) or "none"))
         for name, t in sorted(counters.get("flash_tiles", {}).items()):
             print("flash_tiles %s: %d visited, %d masked, %d in the square"
                   % (name, t["visited"], t["masked"], t["square"]))
