@@ -916,7 +916,9 @@ class Executor(object):
         # counts each expert layer's held part was traced at ({"49152 ->
         # 12288/24576/49152": n}; ops/decoder_ops.py: held_rungs) and
         # gmm_blocks the tiling each grouped-matmul launch was traced at
-        # ({"gmm 256x2048x768 g16": n}; kernels/grouped_matmul.py)
+        # ({"gmm 256x2048x768 g16": n}; kernels/grouped_matmul.py) and
+        # ssm_scans the shape of each state-space scan traced
+        # ({"Q128 x64 h64 g8 n128 p64 xla": n}; kernels/ssd_scan.py)
         # comm_path says HOW the last compiled program's DP grads sync:
         # "explicit" = routed through the paddle_tpu.comm collectives
         # (comm_* stats measured from the traced plan), "model" = GSPMD
@@ -938,7 +940,7 @@ class Executor(object):
                       "tune_fallbacks": 0, "flash_blocks": {},
                       "flash_tiles": {}, "flash_kept": {},
                       "flash_kept_bytes": 0, "moe_rungs": {},
-                      "gmm_blocks": {},
+                      "gmm_blocks": {}, "ssm_scans": {},
                       "elastic_resizes": 0, "elastic_lost_ranks": 0,
                       "elastic_requeued_tasks": 0,
                       "elastic_resume_ms": 0.0,

@@ -100,29 +100,52 @@ def vmem_limit(kernel, tm, tk, tn, itemsize, out_itemsize, rhs_itemsize=None):
     return None if need <= VMEM_LIMIT else -(-need // 2 ** 20) * 2 ** 20
 
 
+def _lane_splits(width):
+    """Block widths narrower than ``width`` that tile it in whole lanes,
+    widest first (none where ``width`` is no multiple of 128)."""
+    if width % LANE:
+        return []
+    lanes = width // LANE
+    return [LANE * d for d in range(lanes - 1, 0, -1) if lanes % d == 0]
+
+
 def default_tiling(kernel, m, k, n, itemsize, out_itemsize=4,
                    rhs_itemsize=None):
-    """(tm, k, n): the row tile of ``kernel`` for a call of ``m`` rows,
+    """(tm, tk, tn): the row tile of ``kernel`` for a call of ``m`` rows,
     contracted width ``k`` (``tgmm``: the rows) and result width ``n``
-    (``tgmm``: the result is [groups, k, n]); blocks are as wide as their
-    operands, so each group's right operand enters VMEM once and nothing
-    is summed across steps but ``tgmm``'s rows. A pure function of the
-    call's shape, read off the sweep in PERF.md §6.
+    (``tgmm``: the result is [groups, k, n]), and the widths of its blocks.
+    A pure function of the call's shape, read off the sweep in PERF.md §6.
 
     Rows: the widest of 256 / 128 that divides ``m``, for all three kernels
     (128 and 256 timed alike, within 5%, in every cell; 512 was 5-10%
     slower: each tile a group boundary crosses is made once a group in it).
     A call of fewer rows than 128 is one tile; other row counts are padded
-    to a multiple of 128 (``_run``). Raises where ``vmem_bytes`` of the
-    whole widths passes VMEM_MOST (no cell's launch comes near it: T's
-    ``tgmm``, the largest, asks for ~41 MiB)."""
+    to a multiple of 128 (``_run``). Blocks are as wide as their operands,
+    so each group's right operand enters VMEM once and nothing is summed
+    across steps but ``tgmm``'s rows, wherever ``vmem_bytes`` of the whole
+    widths fits VMEM_MOST (every launch of K, T and P: T's ``tgmm``, the
+    largest, asks for ~41 MiB). Where it does not: first a narrower row
+    tile at whole widths; then the widest result block, in whole lanes,
+    that fits (``gmm`` / ``gmm_t``: the result width ``n``; ``tgmm``: ``n``,
+    or where ``n`` is no multiple of 128 the result's rows ``k``), each
+    such block a walk of its own over the rows. Raises where nothing
+    fits."""
     tm = next((t for t in ROW_TILES if m % t == 0), m if m < LANE else LANE)
-    need = vmem_bytes(kernel, tm, k, n, itemsize, out_itemsize, rhs_itemsize)
-    if need > VMEM_MOST:
-        raise ValueError(
-            "grouped %s of widths %d x %d needs %d B of VMEM, past the %d a "
-            "launch may ask for" % (kernel, k, n, need, VMEM_MOST))
-    return tm, k, n
+    need = functools.partial(vmem_bytes, kernel, itemsize=itemsize,
+                             out_itemsize=out_itemsize,
+                             rhs_itemsize=rhs_itemsize)
+    for rows in [tm] + [t for t in ROW_TILES if t < tm and m % t == 0]:
+        if need(rows, k, n) <= VMEM_MOST:
+            return rows, k, n
+    splits = [(k, tn) for tn in _lane_splits(n)]
+    if kernel == "tgmm" and not splits:
+        splits = [(tk, n) for tk in _lane_splits(k)]
+    for tk, tn in splits:
+        if need(tm, tk, tn) <= VMEM_MOST:
+            return tm, tk, tn
+    raise ValueError(
+        "grouped %s of widths %d x %d needs %d B of VMEM, past the %d a "
+        "launch may ask for" % (kernel, k, n, need(tm, k, n), VMEM_MOST))
 
 
 def _walk(sizes, m, tm, visit_empty):
@@ -207,11 +230,13 @@ def _before(s, ref):
 
 
 def _gmm_kernel(offsets, group, _tile_in, tile_out, n_live, lhs_ref, rhs_ref,
-                out_ref, *cast_ref, tm, dims):
+                out_ref, *cast_ref, tm, dims, axis):
     """``gmm`` / ``gmm_t`` (``dims``); ``cast_ref``: where the right
-    operand's dtype is not the left's, its block in the left's dtype."""
+    operand's dtype is not the left's, its block in the left's dtype. The
+    walk's step is grid axis ``axis`` (1 where the result's width is cut
+    into blocks: axis 0 walks them)."""
     from jax.experimental import pallas as pl
-    s = pl.program_id(0)
+    s = pl.program_id(axis)
     live = lax.lt(s, n_live[0])
     g = group[s]
     start, end = offsets[g], offsets[lax.add(g, _I32(1))]
@@ -258,11 +283,11 @@ def _gmm_kernel(offsets, group, _tile_in, tile_out, n_live, lhs_ref, rhs_ref,
 
 
 def _tgmm_kernel(offsets, group, tile_in, _tile_out, n_live, lhs_ref, rhs_ref,
-                 out_ref, acc_ref, *, tm, steps):
-    """``tgmm``: one group's [k, n] result, summed in f32 over the group's
-    row tiles."""
+                 out_ref, acc_ref, *, tm, steps, axis):
+    """``tgmm``: one group's [k, n] result (a block of it), summed in f32
+    over the group's row tiles; the walk's step is grid axis ``axis``."""
     from jax.experimental import pallas as pl
-    s = pl.program_id(0)
+    s = pl.program_id(axis)
     n = n_live[0]
     live = lax.lt(s, n)
     g = group[s]
@@ -302,47 +327,61 @@ def _tgmm_kernel(offsets, group, tile_in, _tile_out, n_live, lhs_ref, rhs_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _launch(kernel, m, k, n, groups, dtype, rhs_dtype, out_dtype, tm,
+def _launch(kernel, m, k, n, groups, dtype, rhs_dtype, out_dtype, tiling,
             interpret):
-    """``kernel``'s launch at these shapes, dtypes and row tile, traced
-    ONCE: ``fn(lhs, rhs, sizes)``. ``gmm``: lhs [m, k], rhs [groups, k, n];
-    ``gmm_t``: rhs [groups, n, k]; both -> [m, n]. ``tgmm``: lhs [m, k],
-    rhs [m, n] -> [groups, k, n]. The grid's steps are the walk's
-    (``_walk``), counted on the device."""
+    """``kernel``'s launch at these shapes, dtypes and ``tiling`` (tm, tk,
+    tn), traced ONCE: ``fn(lhs, rhs, sizes)``. ``gmm``: lhs [m, k], rhs
+    [groups, k, n]; ``gmm_t``: rhs [groups, n, k]; both -> [m, n]. ``tgmm``:
+    lhs [m, k], rhs [m, n] -> [groups, k, n]. The grid's steps are the
+    walk's (``_walk``), counted on the device; where the result's width is
+    cut into blocks (tk < k or tn < n) a first grid axis walks the blocks,
+    each a whole walk over the rows."""
     import jax.extend
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    tm, tk, tn = tiling
     steps = m // tm + groups - 1
+    cut_n = n // tn
+    blocks = (k // tk) * cut_n
     itemsize, rhs_itemsize, out_itemsize = (
         jnp.dtype(t).itemsize for t in (dtype, rhs_dtype, out_dtype))
-    lhs_spec = pl.BlockSpec((tm, k), lambda s, o, g, ti, to, nl: (ti[s], 0))
-    if kernel == "tgmm":
-        body = functools.partial(_tgmm_kernel, tm=tm, steps=steps)
-        in_specs = [lhs_spec, pl.BlockSpec(
-            (tm, n), lambda s, o, g, ti, to, nl: (ti[s], 0))]
-        out_specs = pl.BlockSpec((None, k, n), lambda s, o, g, ti, to, nl:
-                                 (g[s], 0, 0))
-        out_shape = (groups, k, n)
-        scratch = [pltpu.VMEM((k, n), jnp.float32)]
-        nbytes = m * k * itemsize + m * n * rhs_itemsize \
-            + groups * k * n * out_itemsize
+    if blocks == 1:
+        at = lambda f: (lambda s, o, g, ti, to, nl: f(s, 0, g, ti, to))
     else:
-        rhs_block = (n, k) if kernel == "gmm_t" else (k, n)
-        body = functools.partial(_gmm_kernel, tm=tm,
+        at = lambda f: (lambda j, s, o, g, ti, to, nl: f(s, j, g, ti, to))
+    axis = int(blocks > 1)
+    if kernel == "tgmm":
+        body = functools.partial(_tgmm_kernel, tm=tm, steps=steps, axis=axis)
+        in_specs = [pl.BlockSpec((tm, tk), at(lambda s, j, g, ti, to:
+                                              (ti[s], j // cut_n))),
+                    pl.BlockSpec((tm, tn), at(lambda s, j, g, ti, to:
+                                              (ti[s], j % cut_n)))]
+        out_specs = pl.BlockSpec((None, tk, tn), at(
+            lambda s, j, g, ti, to: (g[s], j // cut_n, j % cut_n)))
+        out_shape = (groups, k, n)
+        scratch = [pltpu.VMEM((tk, tn), jnp.float32)]
+        nbytes = (m * k * itemsize * cut_n + m * n * rhs_itemsize
+                  * (k // tk) + groups * k * n * out_itemsize)
+    else:
+        rhs_block = (tn, k) if kernel == "gmm_t" else (k, tn)
+        body = functools.partial(_gmm_kernel, tm=tm, axis=axis,
                                  dims=_NT if kernel == "gmm_t" else _NN)
-        in_specs = [lhs_spec, pl.BlockSpec(
-            (None,) + rhs_block, lambda s, o, g, ti, to, nl: (g[s], 0, 0))]
-        out_specs = pl.BlockSpec((tm, n), lambda s, o, g, ti, to, nl:
-                                 (to[s], 0))
+        in_specs = [pl.BlockSpec((tm, k), at(lambda s, j, g, ti, to:
+                                             (ti[s], 0))),
+                    pl.BlockSpec((None,) + rhs_block, at(
+                        lambda s, j, g, ti, to: (g[s], j, 0)
+                        if kernel == "gmm_t" else (g[s], 0, j)))]
+        out_specs = pl.BlockSpec((tm, tn), at(lambda s, j, g, ti, to:
+                                              (to[s], j)))
         out_shape = (m, n)
         scratch = ([pltpu.VMEM(rhs_block, dtype)]
                    if jnp.dtype(rhs_dtype) != jnp.dtype(dtype) else [])
-        nbytes = m * k * itemsize + groups * k * n * rhs_itemsize \
+        nbytes = m * k * itemsize * blocks + groups * k * n * rhs_itemsize \
             + m * n * out_itemsize
     params = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",),
-        vmem_limit_bytes=vmem_limit(kernel, tm, k, n, itemsize, out_itemsize,
-                                    rhs_itemsize))
+        dimension_semantics=("arbitrary",) * (1 + axis),
+        vmem_limit_bytes=vmem_limit(kernel, tm, tk, tn, itemsize,
+                                    out_itemsize, rhs_itemsize))
 
     def fn(lhs, rhs, sizes):
         walk, taken = _walk_call(sizes, m, tm, kernel == "tgmm")
@@ -350,8 +389,10 @@ def _launch(kernel, m, k, n, groups, dtype, rhs_dtype, out_dtype, tm,
             body,
             out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5, grid=(taken,), in_specs=in_specs,
-                out_specs=out_specs, scratch_shapes=scratch),
+                num_scalar_prefetch=5,
+                grid=(taken,) if blocks == 1 else (blocks, taken),
+                in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=scratch),
             compiler_params=params,
             cost_estimate=pl.CostEstimate(flops=2 * m * k * n,
                                           transcendentals=0,
@@ -386,7 +427,7 @@ def _run(kernel, lhs, rhs, sizes, out_dtype):
         if kernel == "tgmm":
             rhs = jnp.pad(rhs, ((0, pad), (0, 0)))
     launch = _launch(kernel, m + pad, k, n, groups, jnp.dtype(lhs.dtype),
-                     jnp.dtype(rhs.dtype), jnp.dtype(out_dtype), tiling[0],
+                     jnp.dtype(rhs.dtype), jnp.dtype(out_dtype), tiling,
                      not on_tpu())
     out = launch(lhs, rhs, sizes.astype(jnp.int32))[0]
     return out[:m] if pad and kernel != "tgmm" else out

@@ -1,7 +1,7 @@
 """Layers of a modern decoder block: ``flash_attention``, ``rms_norm``,
 ``rotary_embedding`` and the half-layers ``latent_attention``,
-``grouped_attention``, ``gated_ffn`` and ``moe_ffn`` (ops/decoder_ops.py;
-doc/decoder_layers.md).
+``grouped_attention``, ``ssm_mixer``, ``gated_ffn`` and ``moe_ffn``
+(ops/decoder_ops.py; doc/decoder_layers.md).
 
 The half-layers take the residual stream ``x`` [batch, seq, hidden] and
 return ``x + f(rms_norm(x))`` (with ``post_norm`` ``x + rms_norm(f(
@@ -13,13 +13,14 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..initializer import ConstantInitializer
+from ..initializer import (ConstantInitializer, NumpyArrayInitializer,
+                           UniformInitializer)
 from ..param_attr import ParamAttr
 from .layer_helper import LayerHelper
 
 __all__ = ["flash_attention", "rms_norm", "rotary_embedding",
-           "latent_attention", "grouped_attention", "gated_ffn", "moe_ffn",
-           "moe_load_stats", "moe_rows_moved"]
+           "latent_attention", "grouped_attention", "ssm_mixer", "gated_ffn",
+           "moe_ffn", "moe_load_stats", "moe_rows_moved"]
 
 
 def _named(prefix, name):
@@ -163,6 +164,56 @@ def grouped_attention(x, num_heads, num_kv_heads, head_dim, window=None,
     return out
 
 
+def ssm_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
+              chunk_size=128, epsilon=1e-5, prefix=None):
+    """x + W_out N_g(y * silu(z)) of h = rms_norm(x), a Mamba-2 mixer:
+    [z | xBC | dt] = h W_in (z ``num_heads`` x ``head_dim`` wide, xBC that
+    and 2 x ``n_groups`` x ``state_size``, dt ``num_heads``); xBC through a
+    causal depthwise conv of ``conv_kernel`` taps with a bias and a SiLU,
+    split into x (heads of ``head_dim``), B and C (``n_groups`` groups of
+    ``state_size``; head j reads group j // (num_heads / n_groups)); y the
+    state-space scan of x with the step softplus(dt + dt_bias) and the decay
+    -exp(a_log) a head, plus d x (``kernels/ssd_scan.py``, computed in
+    chunks of ``chunk_size``); N_g an RMS norm over each of ``n_groups``
+    groups of channels with one learned scale. Parameters
+    ``<prefix>.{norm,w_in,conv_w,conv_b,dt_bias,a_log,d,gate_norm,w_out}``
+    (``conv_w`` [conv_kernel, channels]: tap j reads the position
+    conv_kernel - 1 - j back)."""
+    if num_heads % n_groups:
+        raise ValueError("%d heads cannot share %d groups"
+                         % (num_heads, n_groups))
+    helper = LayerHelper("ssm_mixer")
+    d = x.shape[-1]
+    inner = num_heads * head_dim
+    channels = inner + 2 * n_groups * state_size
+    named = lambda name, shape, init=None: [helper.create_parameter(
+        _named(prefix, name), shape=list(shape), dtype=x.dtype,
+        default_initializer=init)]
+    inputs = {
+        "NormScale": named("norm", (d,), ConstantInitializer(1.0)),
+        "WIn": named("w_in", (d, inner + channels + num_heads)),
+        "ConvW": named("conv_w", (conv_kernel, channels),
+                       UniformInitializer(-0.5, 0.5)),
+        "ConvB": named("conv_b", (channels,), UniformInitializer(-0.5, 0.5)),
+        # a step of 0.01 at the start, decays 1..num_heads
+        "DtBias": named("dt_bias", (num_heads,), ConstantInitializer(
+            float(np.log(np.expm1(0.01))))),
+        "ALog": named("a_log", (num_heads,), NumpyArrayInitializer(
+            np.log(np.arange(1, num_heads + 1, dtype=np.float32)))),
+        "D": named("d", (num_heads,), ConstantInitializer(1.0)),
+        "GateNormScale": named("gate_norm", (inner,),
+                               ConstantInitializer(1.0)),
+        "WOut": named("w_out", (inner, d)),
+        "X": [x]}
+    out = _out_like(helper, x)
+    helper.append_op(
+        type="ssm_mixer", inputs=inputs, outputs={"Out": [out]},
+        attrs={"heads": num_heads, "head_dim": head_dim,
+               "groups": n_groups, "state": state_size,
+               "chunk": chunk_size, "epsilon": epsilon})
+    return out
+
+
 def gated_ffn(x, size, epsilon=1e-6, post_norm=False, prefix=None):
     """x + (silu(h W_gate) * (h W_up)) W_down, h = rms_norm(x), ``size``
     wide; with ``post_norm`` the products' result is normed before the
@@ -195,7 +246,10 @@ def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
     of h (a router placed before attention: the block's input and the
     attention norm's scale, which then gets the gradient of both readers).
     An expert is (act(h W_gate) * (h W_up)) W_down, ``activation`` "silu"
-    or "relu"; ``shared_size`` 0 makes no shared expert.
+    or "relu", or with ``activation="relu2"`` the non-gated relu(h W_up)^2
+    W_down (no ``expert_gate`` / ``shared_gate`` is made); the shared
+    expert is of the experts' kind; ``shared_size`` 0 makes no shared
+    expert.
 
     ``experts_held = (first, count)`` says which experts THIS program
     holds (None: all): their weights are three stacked parameters
@@ -214,25 +268,28 @@ def moe_ffn(x, num_experts, top_k, expert_size, shared_size,
     off. Parameters ``<prefix>.{norm,router,router_bias,expert_gate,
     expert_up,expert_down,shared_gate,shared_up,shared_down}``
     (``router_bias`` only for a sigmoid router, ``shared_*`` only with a
-    ``shared_size``)."""
+    ``shared_size``, the two ``*_gate`` only for a gated ``activation``)."""
     first, count = experts_held or (0, num_experts)
     if not (0 <= first and count >= 1 and first + count <= num_experts):
         raise ValueError("experts_held %r lies outside the %d experts"
                          % (experts_held, num_experts))
     if score not in ("sigmoid", "softmax"):
         raise ValueError("no router score %r" % (score,))
-    if activation not in ("silu", "relu"):
+    if activation not in ("silu", "relu", "relu2"):
         raise ValueError("no expert activation %r" % (activation,))
+    gated = activation != "relu2"
     helper = LayerHelper("moe_ffn")
     d = x.shape[-1]
     shapes = {"NormScale": ("norm", (d,)),
-              "WRouter": ("router", (d, num_experts)),
-              "ExpertGate": ("expert_gate", (count, d, expert_size)),
-              "ExpertUp": ("expert_up", (count, d, expert_size)),
-              "ExpertDown": ("expert_down", (count, expert_size, d))}
+              "WRouter": ("router", (d, num_experts))}
+    if gated:
+        shapes["ExpertGate"] = ("expert_gate", (count, d, expert_size))
+    shapes.update(ExpertUp=("expert_up", (count, d, expert_size)),
+                  ExpertDown=("expert_down", (count, expert_size, d)))
     if shared_size:
-        shapes.update(SharedGate=("shared_gate", (d, shared_size)),
-                      SharedUp=("shared_up", (d, shared_size)),
+        if gated:
+            shapes["SharedGate"] = ("shared_gate", (d, shared_size))
+        shapes.update(SharedUp=("shared_up", (d, shared_size)),
                       SharedDown=("shared_down", (shared_size, d)))
     inputs = _params(helper, prefix, x.dtype,
                      dict(shapes, **_post_norm(post_norm, d)))

@@ -85,7 +85,8 @@ DEFAULT_REMAT_TYPES = frozenset((
     "dynamic_gru", "sequence_conv", "flash_attention", "mdlstm",
     # whole half-layers of a decoder block (ops/decoder_ops.py): kept as
     # their [tokens, hidden] input, recomputed in the backward pass
-    "latent_attention", "grouped_attention", "gated_ffn", "moe_ffn"))
+    "latent_attention", "grouped_attention", "ssm_mixer", "gated_ffn",
+    "moe_ffn"))
 
 
 @profiler.setup_span("build")

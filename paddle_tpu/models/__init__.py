@@ -19,3 +19,4 @@ from .ctr import wide_deep, deepfm, synthetic_click_batch  # noqa: F401
 from .latent_moe_lm import latent_moe_lm  # noqa: F401
 from .window_moe_lm import window_moe_lm  # noqa: F401
 from .prerouted_moe_lm import prerouted_moe_lm  # noqa: F401
+from .hybrid_ssm_moe_lm import hybrid_ssm_moe_lm  # noqa: F401

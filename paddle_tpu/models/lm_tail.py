@@ -1,7 +1,9 @@
 """What the configuration-driven decoders (``latent_moe_lm``,
-``window_moe_lm``, ``prerouted_moe_lm``) share around their layers: the two keys of one chip's
-share, the embedding of the held vocabulary rows, the final norm, the head
-over those rows and the loss.
+``window_moe_lm``, ``prerouted_moe_lm``, ``hybrid_ssm_moe_lm``) share around
+their layers: the two keys of one chip's share, the embedding of the held
+vocabulary rows, the final norm, the head over those rows and the loss; and
+``pattern_blocks``, the layer loop of a model whose layers are of several
+kinds placed by a published pattern.
 
 ``experts_held = [first, count]`` and ``vocab_held = [first, count]`` say
 which experts and which vocabulary rows THIS program holds, as one chip of
@@ -67,3 +69,33 @@ def decoder_lm(tokens, labels, config, blocks, embed_scale=None):
         out["loss"] = L.mean(L.softmax_with_cross_entropy(
             flat, L.reshape(local_ids(labels), shape=[-1, 1])))
     return out
+
+
+def pattern_blocks(model, pattern, parts):
+    """``blocks(x)`` for ``decoder_lm`` whose layer i is the part that
+    ``pattern[i]`` names. ``parts``: {letter: (prefix, build)}; the n-th
+    layer of a letter gets the parameter prefix ``prefix % n`` (each kind
+    counts its own layers, so the kinds never share a name), and
+    ``build(x, prefix)`` returns the stream, or (stream, load, rows_held)
+    for an expert layer (``layers.moe_ffn``), whose two counters the model
+    returns in layer order. A letter with no part raises."""
+    for i, letter in enumerate(pattern):
+        if letter not in parts:
+            raise NotImplementedError(
+                "%s builds the layer kinds %s, the pattern's layer %d is %r"
+                % (model, "".join(sorted(parts)), i, letter))
+
+    def blocks(x):
+        loads, rows_held, seen = [], [], dict.fromkeys(parts, 0)
+        for letter in pattern:
+            prefix, build = parts[letter]
+            out = build(x, prefix % seen[letter])
+            seen[letter] += 1
+            if isinstance(out, tuple):
+                x, load, rows = out
+                loads.append(load)
+                rows_held.append(rows)
+            else:
+                x = out
+        return x, loads, rows_held
+    return blocks

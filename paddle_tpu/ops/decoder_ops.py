@@ -1,15 +1,17 @@
 """Ops of a modern decoder block: ``rms_norm``, ``rotary_embedding``, and
 the half-layers ``latent_attention`` (the DeepSeek-V3 block's, arXiv:
 2412.19437 section 2.1), ``grouped_attention`` (grouped-query heads, a
-sliding window, QK norms and an output gate where asked for), ``gated_ffn``
-and ``moe_ffn`` (a sigmoid router with a selection bias or a softmax one,
-SiLU- or ReLU-gated experts, shared experts or none, a router that reads
-its own half-layer's input or another block input's norm).
+sliding window, QK norms and an output gate where asked for),
+``ssm_mixer`` (a Mamba-2 mixer: conv, chunked state-space scan, gated
+grouped norm), ``gated_ffn`` and ``moe_ffn`` (a sigmoid router with a
+selection bias or a softmax one, SiLU- or ReLU-gated or squared-ReLU
+experts, shared experts or none, a router that reads its own half-layer's
+input or another block input's norm).
 
 No 2018 reference equivalent. Each half-layer (pre-norm, the products, the
 residual add, and where its ``PostNormScale`` is given a norm of the
 products' result BEFORE the add: the sandwich norm) is ONE op whose lowering
-is a pure jax function, and all four are in ``memory_optimize``'s default
+is a pure jax function, and all five are in ``memory_optimize``'s default
 ``remat_types``: ``generic_grad`` then recomputes the half-layer under
 ``jax.checkpoint`` and a layer keeps its two ``[tokens, hidden]`` inputs for
 the backward pass instead of every product's operands; an attention
@@ -18,15 +20,17 @@ tokens, v head]`` and ``[rows x heads, tokens]`` f32), which the backward
 kernels read, so that its backward re-runs the norms, products, rotary
 positions and gate but not the attention kernel. Inside a lowering
 ``profiler.part_scope`` names the parts (``proj``, ``rope``, ``attn``,
-``attn_window``, ``attn_full``, ``gate``, ``route``, ``experts``,
-``shared``, ``post_norm``), and ``profiler.device_scopes()`` keeps that
-second level (``forward/latent_attention/attn``).
+``attn_window``, ``attn_full``, ``gate``, ``conv``, ``scan``, ``norm``,
+``route``, ``experts``, ``shared``, ``post_norm``), and
+``profiler.device_scopes()`` keeps that second level
+(``forward/latent_attention/attn``).
 
 Precision: under AMP the matrix products take bf16 operands and accumulate
 in f32; under pure AMP the residual stream and what a half-layer hands on
 is bf16. RMS statistics, rotary angles, the softmax (inside the attention
 kernel), the router's product, its sigmoid or softmax and the pick weights
-are f32 always.
+are f32 always; in ``ssm_mixer`` also the conv, the step's softplus, the
+cumulative decays, the state carried between chunks and the grouped norm.
 """
 from __future__ import annotations
 
@@ -40,6 +44,7 @@ import numpy as np
 from ..core.executor import raw_data
 from ..core.registry import register_op
 from ..kernels.grouped_matmul import grouped_matmul
+from ..kernels.ssd_scan import mixer_scan
 from ..profiler import part_scope
 from .attention_ops import attention
 
@@ -90,13 +95,19 @@ def rotary(x, theta, layout="interleaved"):
     return out.astype(x.dtype)
 
 
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda v: jnp.square(jax.nn.relu(v))}
 
 
 def gated(h, w_gate, w_up, w_down, operand):
     """(silu(h W_gate) * (h W_up)) W_down, f32 out."""
     act = jax.nn.silu(_mm(h, w_gate, operand)) * _mm(h, w_up, operand)
     return _mm(act, w_down, operand)
+
+
+def squared_relu(h, w_up, w_down, operand):
+    """relu(h W_up)^2 W_down (non-gated), f32 out."""
+    return _mm(ACTIVATIONS["relu2"](_mm(h, w_up, operand)), w_down, operand)
 
 
 def _infer_out_like_x(op, block):
@@ -226,6 +237,63 @@ def grouped_attention_op(ctx):
     ctx.set_output("Out", _residual(ctx, x, a, stream))
 
 
+def causal_conv(u, w, b):
+    """u [B, S, C] -> b + sum_j w[j] u[t - K + 1 + j] over the K taps of w
+    [K, C], zeros before position 0; f32."""
+    K, S = w.shape[0], u.shape[1]
+    up = jnp.pad(u.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    acc = b.astype(jnp.float32)
+    for j in range(K):
+        acc = acc + w[j].astype(jnp.float32) * up[:, j:j + S]
+    return acc
+
+
+@register_op("ssm_mixer", infer_shape=_infer_out_like_x)
+def ssm_mixer_op(ctx):
+    """Out = X + W_out N_g(y * silu(z)), h = RMSNorm(X), a Mamba-2 mixer:
+    [z | xBC | dt] = h W_in; xBC = silu(causal conv of ``ConvW`` / ``ConvB``
+    over xBC) split into x [heads, head_dim], B and C [groups, state]; y =
+    the chunked state-space scan (``kernels/ssd_scan.py: mixer_scan``: step
+    softplus(dt + ``DtBias``), decay -exp(``ALog``), chunks of ``chunk``)
+    plus ``D`` x; N_g an RMS norm over each group's heads x head_dim
+    channels under ``GateNormScale``. Part scopes: ``proj`` (both products
+    and the pre-norm), ``conv``, ``scan`` (step, scan, skip) and ``norm``
+    (the gate and the grouped norm)."""
+    x = raw_data(ctx.input("X"))
+    operand, stream = _dtypes(ctx, x)
+    H, P, G, N = (int(ctx.attr(a)) for a in
+                  ("heads", "head_dim", "groups", "state"))
+    eps = ctx.attr("epsilon")
+    B, S, d = x.shape
+    inner = H * P
+    x = x.astype(stream)
+    with part_scope("proj"):
+        h = rms_norm(x, raw_data(ctx.input("NormScale")), eps)
+        zxbcdt = _mm(h, raw_data(ctx.input("WIn")), operand)
+        z = zxbcdt[..., :inner].astype(stream)
+        xbc = zxbcdt[..., inner:2 * inner + 2 * G * N].astype(stream)
+        dt = zxbcdt[..., 2 * inner + 2 * G * N:]
+    with part_scope("conv"):
+        xbc = jax.nn.silu(causal_conv(xbc, raw_data(ctx.input("ConvW")),
+                                      raw_data(ctx.input("ConvB"))))
+        xbc = xbc.astype(stream)
+        xs = xbc[..., :inner].reshape(B, S, H, P)
+        bs = xbc[..., inner:inner + G * N].reshape(B, S, G, N)
+        cs = xbc[..., inner + G * N:].reshape(B, S, G, N)
+    with part_scope("scan"):
+        y = mixer_scan(xs, dt, bs, cs, raw_data(ctx.input("DtBias")),
+                       raw_data(ctx.input("ALog")), raw_data(ctx.input("D")),
+                       int(ctx.attr("chunk")), operand)
+    with part_scope("norm"):
+        u = y.reshape(B, S, inner) * jax.nn.silu(z.astype(jnp.float32))
+        u = rms_norm(u.reshape(B, S, G, inner // G),
+                     raw_data(ctx.input("GateNormScale")).reshape(
+                         G, inner // G), eps, stream)
+    with part_scope("proj"):
+        a = _mm(u.reshape(B, S, inner), raw_data(ctx.input("WOut")), operand)
+    ctx.set_output("Out", (x.astype(jnp.float32) + a).astype(stream))
+
+
 @register_op("gated_ffn", infer_shape=_infer_out_like_x)
 def gated_ffn_op(ctx):
     """Out = X + (silu(h W_gate) * (h W_up)) W_down, h = RMSNorm(X); with
@@ -276,7 +344,8 @@ def held_rungs(pairs, count, n_experts):
 def _held_rung(rows, k, operand, stream, activation):
     """The held experts' part of the routed sum over the first ``rows`` of
     the sorted (token, pick) pairs, experts gated by ``activation``
-    (``ACTIVATIONS``): ``fn(h, g, eg, eu, ed, order, inv, sizes) -> [T, d]
+    (``ACTIVATIONS``; ``eg`` None for the non-gated "relu2": two products,
+    not three): ``fn(h, g, eg, eu, ed, order, inv, sizes) -> [T, d]
     f32``. Right whenever ``sum(sizes) <= rows``: the rows from
     ``sum(sizes)`` on are pairs of no held expert, where the grouped
     products (``kernels.grouped_matmul``) and their left operand's gradient
@@ -338,7 +407,10 @@ def _held_rung(rows, k, operand, stream, activation):
             # (f32 masters under AMP): the kernels cast them to the
             # operand dtype in VMEM and write their gradients in f32
             rd = lambda a, w: grouped_matmul(a.astype(operand), w, sizes)
-            ys = rd(ACTIVATIONS[activation](rd(xs, eg)) * rd(xs, eu), ed)
+            if eg is None:
+                ys = rd(ACTIVATIONS[activation](rd(xs, eu)), ed)
+            else:
+                ys = rd(ACTIVATIONS[activation](rd(xs, eg)) * rd(xs, eu), ed)
         with part_scope("route"):
             ys = (ys * g.reshape(-1)[o][:, None]).astype(stream)
             return combine(ys, o, inv)
@@ -418,12 +490,14 @@ def _held_part(rungs, k, operand, stream, epsilon, activation="silu"):
 def moe_ffn_op(ctx):
     """Out = X + shared(h) + sum over the picks that fall on a HELD expert
     of g_k E_k(h), h = RMSNorm(X), E_k gated by ``activation`` (silu or
-    relu). The router scores all ``n_experts`` (``score``: sigmoid, picked
+    relu), or with "relu2" relu(h W_up)^2 W_down, no gate (no
+    ``ExpertGate``; the shared expert likewise, no ``SharedGate``). The
+    router scores all ``n_experts`` (``score``: sigmoid, picked
     by score + ``RouterBias``; softmax, no bias) from h or, where the op is
     given ``RouterX`` and ``RouterNormScale``, from RMSNorm(RouterX) under
     that scale (a router placed before the attention half-layer reads that
     half-layer's input norm; its own product, norm, top-k and sort all sit
-    in the part scope ``route``). Without ``SharedGate`` there is no shared
+    in the part scope ``route``). Without ``SharedUp`` there is no shared
     expert. This op holds the experts ``[first, first + count)`` (stacked weights
     ``[count, ...]``) and leaves out what the others would add: g stays
     normalised over all the picks; no capacity is set, nothing is dropped;
@@ -444,11 +518,13 @@ def moe_ffn_op(ctx):
     int32[1]: pairs that fell on held experts."""
     x = raw_data(ctx.input("X"))
     operand, stream = _dtypes(ctx, x)
-    w_post, w_router, eg, eu, ed = (
+    w_post, w_router, eu, ed = (
         raw_data(ctx.input(s)) for s in
-        ("NormScale", "WRouter", "ExpertGate", "ExpertUp", "ExpertDown"))
+        ("NormScale", "WRouter", "ExpertUp", "ExpertDown"))
+    eg = raw_data(ctx.input("ExpertGate")) \
+        if ctx.has_input("ExpertGate") else None
     k, first = int(ctx.attr("top_k")), int(ctx.attr("first_expert"))
-    count, n_experts = eg.shape[0], w_router.shape[1]
+    count, n_experts = eu.shape[0], w_router.shape[1]
     eps = ctx.attr("epsilon")
     B, S, d = x.shape
     T = B * S
@@ -482,6 +558,10 @@ def moe_ffn_op(ctx):
             shared = gated(h, *(raw_data(ctx.input(s)) for s in
                                 ("SharedGate", "SharedUp", "SharedDown")),
                            operand)
+    elif ctx.has_input("SharedUp"):
+        with part_scope("shared"):
+            shared = squared_relu(h, raw_data(ctx.input("SharedUp")),
+                                  raw_data(ctx.input("SharedDown")), operand)
     post = (raw_data(ctx.input("PostNormScale")),) \
         if ctx.has_input("PostNormScale") else ()
     ctx.set_output("Out", _held_part(

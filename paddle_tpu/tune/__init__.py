@@ -56,7 +56,7 @@ __all__ = [
     "parity_ok", "parity_report",
     "lookup", "record_fallback", "counters", "reset_counters",
     "count_flash_blocks", "count_flash_kept", "count_moe_rungs",
-    "count_gmm_blocks",
+    "count_gmm_blocks", "count_ssm_scan",
 ]
 
 # -- dispatch counters --------------------------------------------------------
@@ -91,6 +91,9 @@ _moe_rungs = {}
 # (gmm, gmm_t, tgmm; kernels/grouped_matmul.py: default_tiling), and its
 # number of groups
 _gmm_blocks = {}
+# {"Q128 x64 h64 g8 n128 p64 xla": scans traced}: the shape of each
+# state-space scan (kernels/ssd_scan.py: mixer_scan) and what runs it
+_ssm_scans = {}
 
 
 def _bump(name):
@@ -147,6 +150,17 @@ def count_gmm_blocks(kernel, tm, tk, tn, groups):
         _gmm_blocks[name] = _gmm_blocks.get(name, 0) + 1
 
 
+def count_ssm_scan(chunk, chunks, heads, groups, state, head_dim,
+                   implementation):
+    """One state-space scan traced (``kernels/ssd_scan.py: mixer_scan``):
+    chunks of ``chunk`` positions, ``heads`` heads of ``head_dim`` reading
+    ``groups`` B/C groups of ``state``, run by ``implementation``."""
+    name = "Q%d x%d h%d g%d n%d p%d %s" % (chunk, chunks, heads, groups,
+                                            state, head_dim, implementation)
+    with _counters_lock:
+        _ssm_scans[name] = _ssm_scans.get(name, 0) + 1
+
+
 def counters():
     """Snapshot of the process-level dispatch counters; ``flash_blocks``
     is the {"<kernel> <block_q>x<block_k>[ g<group>][ w<window>]":
@@ -157,14 +171,17 @@ def counters():
     (``flash_kept_bytes`` of them in all), and ``moe_rungs`` the
     {"<pairs> -> <rung>/<rung>/...": launches} tally of the expert layers
     traced, ``gmm_blocks`` the {"<kernel> <tm>x<tk>x<tn> g<groups>":
-    launches} tally of the grouped-matmul launches."""
+    launches} tally of the grouped-matmul launches, ``ssm_scans`` the
+    {"Q<chunk> x<chunks> h<heads> g<groups> n<state> p<head width>
+    <pallas|xla>": scans} tally of the state-space scans."""
     with _counters_lock:
         return dict(_counters, flash_blocks=dict(_flash_blocks),
                     flash_tiles={k: dict(v)
                                  for k, v in _flash_tiles.items()},
                     flash_kept=dict(_flash_kept),
                     moe_rungs=dict(_moe_rungs),
-                    gmm_blocks=dict(_gmm_blocks))
+                    gmm_blocks=dict(_gmm_blocks),
+                    ssm_scans=dict(_ssm_scans))
 
 
 def reset_counters():
@@ -177,6 +194,7 @@ def reset_counters():
         _flash_kept.clear()
         _moe_rungs.clear()
         _gmm_blocks.clear()
+        _ssm_scans.clear()
     profiler.reset_tune_counters()
 
 

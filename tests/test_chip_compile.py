@@ -179,6 +179,35 @@ def test_flash_attention_grouped_heads_and_window_compile(one_chip, kernels,
         assert "f32[%d,%d,%d]" % (h, s, d) in text      # per q head, f32
 
 
+@pytest.mark.parametrize("kernels", ["fwd", "bwd"])
+def test_flash_attention_groups_of_16_compile(one_chip, kernels):
+    """The three kernels at the hybrid cell's attention layer: 32 query
+    heads on 2 key/value heads of 128 (groups of 16), 8,192 positions,
+    bf16, causal, no window, at the blocks the rule picks and the scoped
+    VMEM it reckons."""
+    fa = _kernel_module("flash_attention")
+    h, hkv, s, d = 32, 2, 8192, 128
+    blocks = {kernel: fa.default_blocks(kernel, s, s, d, d, jnp.bfloat16,
+                                        True, h // hkv)
+              for kernel in fa.KERNELS}
+    assert blocks["fwd"] == (512, 512)
+
+    def fwd(q, k, v):
+        return fa._fa_forward(q, k, v, True, d ** -0.5, s, interpret=False)
+
+    def bwd(q, k, v, do, lse, delta):
+        return fa._fa_backward(q, k, v, do, lse, delta, True, d ** -0.5, s,
+                               interpret=False)
+
+    q, kv = ((h, s, d), jnp.bfloat16), ((hkv, s, d), jnp.bfloat16)
+    row = ((h, s), jnp.float32)
+    if kernels == "fwd":
+        _compile(fwd, one_chip, q, kv, kv)
+    else:
+        assert _compile(bwd, one_chip, q, kv, kv, q, row, row).count(
+            "tpu_custom_call") >= 2
+
+
 def test_flash_attention_vmem_reckoning_refuses_before_mosaic(one_chip,
                                                               monkeypatch):
     """8,192 positions of 192 / 128-wide heads at 512 x 512: Mosaic
@@ -259,7 +288,8 @@ def test_flash_attention_rows_of_16384_need_what_is_reckoned(
 # their first and top rungs
 GMM_CELLS = {"K": (2048, 768, 16, (12288, 49152)),
              "T": (2048, 1024, 16, (16384, 65536)),
-             "P": (2560, 768, 8, (24576, 98304))}
+             "P": (2560, 768, 8, (24576, 98304)),
+             "N": (2688, 1856, 8, (6144, 49152))}
 
 
 @pytest.mark.parametrize("cell,rung", [(c, i) for c in GMM_CELLS

@@ -139,3 +139,38 @@ def test_groups_of_7_past_8192_positions_match_dense(S, window, bq, bk):
     for name, a, b in zip("qkv", g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
                                    atol=2e-4, err_msg="d" + name)
+
+
+@pytest.mark.parametrize("S,bq,bk", [(1024, 512, 512), (2048, 512, 256),
+                                     (2048, 256, 512)])
+def test_groups_of_16_without_positions_match_dense(S, bq, bk):
+    """The hybrid cell's group (32 q heads on 2 k/v heads: 16 q heads on
+    one here, 16 wide), causal, no window, nothing turned by positions
+    before the kernels: o, lse, dq, dk, dv against the dense
+    composition."""
+    rng = np.random.RandomState(S + bq + 2 * bk)
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
+    q, k, v = mk(1, S, 16, 16), mk(1, S, 1, 16), mk(1, S, 1, 16)
+    co, cl = mk(1, S, 16, 16), mk(1, 16, S)
+
+    def both(f):
+        def loss(q, k, v):
+            o, lse = f(q, k, v)
+            return jnp.sum(o * co) + jnp.sum(lse * cl), (o, lse)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    cfg = {"block_q": bq, "block_k": bk}
+    tune.reset_counters()
+    (_, got), g1 = both(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal=True, config=cfg))(q, k, v)
+    (_, want), g2 = both(lambda q, k, v: _dense_grouped(
+        q, k, v, None))(q, k, v)
+    for name, a, b in zip(("o", "lse"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5, err_msg=name)
+    for name, a, b in zip("qkv", g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=2e-4, err_msg="d" + name)
+    assert set(tune.counters()["flash_blocks"]) == {
+        "%s %dx%d g16" % (kernel, bq, bk) for kernel in fa.KERNELS}

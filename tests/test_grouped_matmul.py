@@ -14,7 +14,7 @@ from paddle_tpu.kernels import grouped_matmul as gm
 # the expert layers of the language-model cells: (d, f, held experts,
 # first rung's rows)
 CELLS = {"K": (2048, 768, 16, 12288), "T": (2048, 1024, 16, 16384),
-         "P": (2560, 768, 8, 24576)}
+         "P": (2560, 768, 8, 24576), "N": (2688, 1856, 8, 6144)}
 
 
 def _tilings(m, k, n):
@@ -143,12 +143,36 @@ def test_grouped_matmul_matches_the_one_hot_reference(case):
 
 
 def test_a_launch_wider_than_a_launch_may_ask_for_is_refused():
-    """Blocks are as wide as their operands: where those widths need more
-    VMEM than ``VMEM_MOST`` the rule raises instead of tiling the widths
-    (the cells' widest launch, T's weight gradient, needs ~41 MiB)."""
+    """Blocks are as wide as their operands wherever those widths fit
+    ``VMEM_MOST`` (the widest such launch of K, T and P, T's weight
+    gradient, needs ~41 MiB); where they do not, a narrower row tile, then
+    result blocks of whole lanes; where none of those fits either, the rule
+    raises."""
     need = gm.vmem_bytes("tgmm", 256, 2048, 1024, 2, 4, 2)
     assert 32 * 2 ** 20 < need <= gm.VMEM_MOST
     assert gm.default_tiling("tgmm", 16384, 2048, 1024, 2, 4, 2) == (
         256, 2048, 1024)
-    with pytest.raises(ValueError, match="tgmm of widths 4096 x 2048"):
-        gm.default_tiling("tgmm", 16384, 4096, 2048, 2, 4, 2)
+    assert gm.default_tiling("tgmm", 16384, 4096, 2048, 2, 4, 2) == (
+        256, 4096, 512)
+    with pytest.raises(ValueError, match="tgmm of widths 4000 x 3000"):
+        gm.default_tiling("tgmm", 16384, 4000, 3000, 2, 4, 2)
+
+
+@pytest.mark.parametrize("kernel,k,n,want", [
+    ("gmm", 2688, 1856, (256, 2688, 1856)),
+    ("gmm", 1856, 2688, (128, 1856, 2688)),
+    ("gmm_t", 1856, 2688, (256, 1856, 2688)),
+    ("gmm_t", 2688, 1856, (256, 2688, 1856)),
+    ("tgmm", 2688, 1856, (256, 896, 1856)),
+    ("tgmm", 1856, 2688, (256, 1856, 896))])
+def test_expert_widths_past_the_vmem_of_whole_blocks_are_cut(kernel, k, n,
+                                                              want):
+    """The published 2,688 x 1,856 experts (1,856 = 14.5 lanes): the rows'
+    product and both gradients of the up and down products, bf16 rows and
+    f32 master weights, at a rung of 6,144 rows. Whole widths where they
+    fit, a row tile of 128 where 256 does not, and the weight gradients in
+    result blocks of whole lanes along whichever width has them."""
+    out, rhs = {"gmm": (4, 4), "gmm_t": (2, 4), "tgmm": (4, 2)}[kernel]
+    got = gm.default_tiling(kernel, 6144, k, n, 2, out, rhs)
+    assert got == want
+    assert gm.vmem_bytes(kernel, *got, 2, out, rhs) <= gm.VMEM_MOST

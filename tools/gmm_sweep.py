@@ -114,17 +114,19 @@ def main(argv=None):
         def fwd(a, _w, s, _ct, ct, w):
             return gm._launch("gmm", a.shape[0], a.shape[1], w.shape[2],
                               w.shape[0], a.dtype, w.dtype, jnp.dtype(f32),
-                              tm, False)(a, w, s)[0]
+                              (tm, a.shape[1], w.shape[2]), False)(a, w, s)[0]
 
         def dlhs(a, _w, s, _ct, ct, w):
             return gm._launch("gmm_t", a.shape[0], ct.shape[1], a.shape[1],
                               w.shape[0], a.dtype, w.dtype, jnp.dtype(bf16),
-                              tm, False)(ct, w, s)[0]
+                              (tm, ct.shape[1], a.shape[1]), False)(
+                                  ct, w, s)[0]
 
         def drhs(a, w, s, _ct, ct, _w32):
             return gm._launch("tgmm", a.shape[0], a.shape[1], ct.shape[1],
                               w.shape[0], a.dtype, ct.dtype, jnp.dtype(f32),
-                              tm, False)(a, ct, s)[0]
+                              (tm, a.shape[1], ct.shape[1]), False)(
+                                  a, ct, s)[0]
         return {"fwd": fwd, "dlhs": dlhs, "drhs": drhs}[kind]
 
     def mega(kind, tiling):

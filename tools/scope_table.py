@@ -15,8 +15,9 @@ with ``tune.counters()`` (the header's lines: dispatch gauges, the blocks
 each flash-attention launch was traced at, the tiling of each
 grouped-matmul launch of the held experts, the differentiated launches
 whose o and lse the backward reads kept, the row counts each expert
-layer's held part was compiled at) and, where the cell's program has expert
-layers whose ``Load`` / ``RowsHeld`` its Trainer fetches, the rung every
+layer's held part was compiled at, the shape of each state-space scan)
+and, where the cell's program has expert layers whose ``Load`` /
+``RowsHeld`` its Trainer fetches, the rung every
 (layer, step) of the window ran at (``layers.moe_rows_moved`` over the
 fetched ``RowsHeld``: a listener of this tool's own on ``EndIteration``).
 A run also prints the device's idle time a step by the innermost program
@@ -112,7 +113,7 @@ def listen_to_expert_layers(heard):
         at = {v.name: i for i, v in enumerate(self.fetch_list[1:])}
         heard["layers"] = [
             (at[op.output("Load")[0]], at[op.output("RowsHeld")[0]],
-             block._find_var_recursive(op.input("ExpertGate")[0]).shape[0],
+             block._find_var_recursive(op.input("ExpertUp")[0]).shape[0],
              block._find_var_recursive(op.input("WRouter")[0]).shape[1])
             for op in block.ops if op.type == "moe_ffn"
             and op.output("Load")[0] in at and op.output("RowsHeld")[0] in at]
@@ -251,6 +252,9 @@ def report(module, steps, rows, counters=None, rungs=None):
         if counters.get("moe_rungs"):
             print("moe_rungs: %s" % ", ".join(
                 "%s x%d" % kv for kv in sorted(counters["moe_rungs"].items())))
+        if counters.get("ssm_scans"):
+            print("ssm_scans: %s" % ", ".join(
+                "%s x%d" % kv for kv in sorted(counters["ssm_scans"].items())))
     if rungs:
         tally = collections.Counter()
         for i, layer in enumerate(rungs):
