@@ -918,7 +918,7 @@ class Executor(object):
         # gmm_blocks the tiling each grouped-matmul launch was traced at
         # ({"gmm 256x2048x768 g16": n}; kernels/grouped_matmul.py) and
         # ssm_scans the shape of each state-space scan traced
-        # ({"Q128 x64 h64 g8 n128 p64 xla": n}; kernels/ssd_scan.py)
+        # ({"Q128 x64 h64 g8 n128 p64 pallas": n}; kernels/ssd_scan.py)
         # comm_path says HOW the last compiled program's DP grads sync:
         # "explicit" = routed through the paddle_tpu.comm collectives
         # (comm_* stats measured from the traced plan), "model" = GSPMD

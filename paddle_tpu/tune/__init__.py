@@ -91,7 +91,7 @@ _moe_rungs = {}
 # (gmm, gmm_t, tgmm; kernels/grouped_matmul.py: default_tiling), and its
 # number of groups
 _gmm_blocks = {}
-# {"Q128 x64 h64 g8 n128 p64 xla": scans traced}: the shape of each
+# {"Q128 x64 h64 g8 n128 p64 pallas": scans traced}: the shape of each
 # state-space scan (kernels/ssd_scan.py: mixer_scan) and what runs it
 _ssm_scans = {}
 
@@ -173,7 +173,7 @@ def counters():
     traced, ``gmm_blocks`` the {"<kernel> <tm>x<tk>x<tn> g<groups>":
     launches} tally of the grouped-matmul launches, ``ssm_scans`` the
     {"Q<chunk> x<chunks> h<heads> g<groups> n<state> p<head width>
-    <pallas|xla>": scans} tally of the state-space scans."""
+    <implementation>": scans} tally of the state-space scans."""
     with _counters_lock:
         return dict(_counters, flash_blocks=dict(_flash_blocks),
                     flash_tiles={k: dict(v)

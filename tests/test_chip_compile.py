@@ -1,4 +1,4 @@
-"""The seven Pallas kernels, compiled by the TPU's own compiler for a
+"""The eight Pallas kernels, compiled by the TPU's own compiler for a
 *described* v5e (no chip attached), at the real widths the chip smoke and
 the benchmark cells use. A compile that passes here is not a chip run: it
 proves Mosaic accepts the kernel (block shapes, dot forms, VMEM budget),
@@ -507,3 +507,121 @@ def test_the_grouped_products_carry_the_experts_scope_in_both_phases(
                         ("backward/moe_ffn/experts", "gmm"): 3 * rungs,
                         ("backward/moe_ffn/experts", "gmm_t"): 3 * rungs,
                         ("backward/moe_ffn/experts", "tgmm"): 3 * rungs}
+
+
+# the mixers' scan of the hybrid cell: 8,192 positions in chunks of 128, 64
+# heads of 64 over 8 B/C groups of a 128-wide state, bf16 under AMP
+SCAN_N = dict(seq=8192, chunk=128, heads=64, head_dim=64, groups=8,
+              state=128)
+
+
+def _scan_programs(ss, seq, chunk, heads, head_dim, groups, state):
+    """(fn, shapes) of the primal launch alone and of the forward rule's
+    launch with the backward's."""
+    bf16 = jnp.bfloat16
+    scan = lambda *v: ss.chunked_scan(*v, chunk, bf16)
+
+    def vjp(x, delta, a, b, c):
+        y, back = jax.vjp(scan, x, delta, a, b, c)
+        return back(y)
+
+    shapes = (((1, seq, heads, head_dim), bf16),
+              ((1, seq, heads), jnp.float32), ((heads,), jnp.float32),
+              ((1, seq, groups, state), bf16),
+              ((1, seq, groups, state), bf16))
+    return (scan, shapes), (vjp, shapes)
+
+
+@pytest.mark.parametrize("program", ["primal", "vjp"])
+def test_ssd_scan_kernels_compile(one_chip, monkeypatch, program):
+    """The scan's forward kernel alone (the primal launch, y only), and the
+    forward rule's launch (y and the entering states) with the backward
+    kernel, at the hybrid cell's widths."""
+    ss = _kernel_module("ssd_scan")
+    monkeypatch.setattr(ss, "on_tpu", lambda: True)
+    primal, vjp = _scan_programs(ss, **SCAN_N)
+    fn, shapes = primal if program == "primal" else vjp
+    text = _compile(fn, one_chip, *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if program == "primal" else 2)
+
+
+def test_ssd_scan_vmem_reckoning_stays_under_mosaic_s_default(one_chip,
+                                                              monkeypatch):
+    """At the hybrid cell's widths the kernels reckon 2.15 and 3.16 MiB
+    (the blocks twice, the carried state, chunk x chunk blocks and a
+    head's blocks), under the 16 MiB Mosaic grants by default, so a launch asks for
+    nothing and compiles under that default; a launch told to ask for its
+    reckoning, rounded up to a MiB, compiles too."""
+    ss = _kernel_module("ssd_scan")
+    monkeypatch.setattr(ss, "on_tpu", lambda: True)
+    widths = (SCAN_N["chunk"], SCAN_N["heads"] // SCAN_N["groups"],
+              SCAN_N["head_dim"], SCAN_N["state"], 2)
+    for kernel in ss.KERNELS:
+        need = ss.vmem_bytes(kernel, *widths)
+        assert 2 * 2 ** 20 < need < ss.VMEM_LIMIT
+        assert ss.vmem_limit(kernel, *widths) is None
+    real = ss.vmem_limit
+    monkeypatch.setattr(ss, "vmem_limit", lambda *a: -(
+        -ss.vmem_bytes(*a) // 2 ** 20) * 2 ** 20)
+    ss._launch.cache_clear()
+    try:
+        for fn, shapes in _scan_programs(ss, **SCAN_N):
+            _compile(fn, one_chip, *shapes)
+    finally:
+        monkeypatch.setattr(ss, "vmem_limit", real)
+        ss._launch.cache_clear()
+
+
+def test_the_scan_kernels_carry_the_scan_scope_in_both_phases(one_chip,
+                                                              monkeypatch):
+    """A training step of one ``ssm_mixer`` (8 heads of 64 over 2 groups
+    of a 128-wide state, 512 positions in chunks of 128), recomputed, under
+    pure AMP: the forward op makes the primal launch under
+    ``forward/ssm_mixer/scan``; its gradient the forward rule's launch and
+    the backward kernel under ``backward/ssm_mixer/scan``."""
+    import collections
+    import paddle_tpu as pt
+    from paddle_tpu import layers as L, profiler
+    from paddle_tpu.core.executor import RngSource, trace_ops
+    ss = _kernel_module("ssd_scan")
+    monkeypatch.setattr(ss, "on_tpu", lambda: True)
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        x = L.data("x", shape=[512, 256], dtype="float32")
+        x.stop_gradient = False
+        out = L.ssm_mixer(x, 8, 64, 2, 128, chunk_size=128, prefix="m")
+        pt.amp.enable(main, pure=True)
+        pt.optimizer.SGDOptimizer(learning_rate=0.0).minimize(
+            L.mean(out * out))
+        pt.memory_optimize(main, remat_types=("ssm_mixer",))
+    exe = pt.Executor(pt.CPUPlace())
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        state = {n: jax.ShapeDtypeStruct(
+            scope.find_var(n).shape, scope.find_var(n).dtype,
+            sharding=one_chip) for n in exe._state_inputs(main, scope,
+                                                          {"x"})}
+    feed = {"x": jax.ShapeDtypeStruct((1, 512, 256), jnp.float32,
+                                      sharding=one_chip)}
+
+    def step(state, feed):
+        env = dict(feed, **state)
+        trace_ops(main.global_block(), env, RngSource(jax.random.PRNGKey(0)))
+        return {n: env[n] for n in state}
+
+    prev = pt.amp.force(True)
+    try:
+        text = jax.jit(step).lower(state, feed).compile().as_text()
+    finally:
+        pt.amp.force(prev)
+    _module, table = profiler.scopes_of_module(text)
+    launches = collections.Counter()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?(ssd_scan_(\w+?))\.\d+ = ", line)
+        if m and 'custom_call_target="tpu_custom_call"' in line:
+            name = line.split("=", 1)[0].strip().lstrip("%")
+            launches[(table[name], m.group(2))] += 1
+    assert launches == {("forward/ssm_mixer/scan", "fwd"): 1,
+                        ("backward/ssm_mixer/scan", "fwd"): 1,
+                        ("backward/ssm_mixer/scan", "bwd"): 1}

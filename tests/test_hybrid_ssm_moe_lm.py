@@ -274,7 +274,7 @@ def test_a_compiled_step_has_the_part_scopes_and_counts_its_scans():
         for phase in ("forward", "backward"):
             assert "%s/%s" % (phase, want) in scopes, (want, sorted(scopes))
     scans = tune.counters()["ssm_scans"]
-    assert set(scans) == {"Q8 x4 h8 g2 n8 p8 xla"}
+    assert set(scans) == {"Q8 x4 h8 g2 n8 p8 pallas"}
     assert exe.stats["ssm_scans"] == scans
 
 
