@@ -918,7 +918,9 @@ class Executor(object):
         # gmm_blocks the tiling each grouped-matmul launch was traced at
         # ({"gmm 256x2048x768 g16": n}; kernels/grouped_matmul.py) and
         # ssm_scans the shape of each state-space scan traced
-        # ({"Q128 x64 h64 g8 n128 p64 pallas": n}; kernels/ssd_scan.py)
+        # ({"Q128 x64 h64 g8 n128 p64 pallas": n}; kernels/ssd_scan.py) and
+        # pick_sums the shape of each held-pick sum traced
+        # ({"8192x6x2048 rows12288 tile256": n}; kernels/pick_sum.py)
         # comm_path says HOW the last compiled program's DP grads sync:
         # "explicit" = routed through the paddle_tpu.comm collectives
         # (comm_* stats measured from the traced plan), "model" = GSPMD
@@ -940,7 +942,7 @@ class Executor(object):
                       "tune_fallbacks": 0, "flash_blocks": {},
                       "flash_tiles": {}, "flash_kept": {},
                       "flash_kept_bytes": 0, "moe_rungs": {},
-                      "gmm_blocks": {}, "ssm_scans": {},
+                      "gmm_blocks": {}, "ssm_scans": {}, "pick_sums": {},
                       "elastic_resizes": 0, "elastic_lost_ranks": 0,
                       "elastic_requeued_tasks": 0,
                       "elastic_resume_ms": 0.0,

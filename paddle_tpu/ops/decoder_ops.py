@@ -44,6 +44,7 @@ import numpy as np
 from ..core.executor import raw_data
 from ..core.registry import register_op
 from ..kernels.grouped_matmul import grouped_matmul
+from ..kernels.pick_sum import pick_sum
 from ..kernels.ssd_scan import mixer_scan
 from ..profiler import part_scope
 from .attention_ops import attention
@@ -352,53 +353,48 @@ def _held_rung(rows, k, operand, stream, activation):
     are exactly 0, so those pairs add nothing either way. Its two
     row movements are gathers in both directions (``inv`` is the inverse
     of the permutation ``order``, which autodiff cannot know: it would
-    scatter-add): a token's picks read row ``inv`` of the ``rows``
-    results, and a pick whose row lies past them (it is not held) reads
-    the zero it added when every pair moved. (A ``rows``-row scatter-add
-    into [T, d] was timed beside it, ``PERF.md`` section 6, PR 35: 1.5 ms
-    a layer ahead at the first rung, behind at the last, and XLA:TPU adds
-    its f32 rows at bf16's precision, which is another sum.)"""
-
-    def summed_picks(a, inv):    # sorted [rows, d] -> [T, d] f32
-        # (indexed [T, k] at once: a [T * k, d] result reshaped to
-        # [T, k, d] is a copy on the chip where k is no multiple of 8)
-        inv = inv.reshape(-1, k)
-        got = a[jnp.minimum(inv, rows - 1)]
-        if rows < inv.size:
-            got = jnp.where((inv < rows)[..., None], got, 0)
-        return got.astype(jnp.float32).sum(axis=1)
+    scatter-add): the rows handed to the experts are a ``rows``-row gather
+    of the tokens, and a token's held picks are summed back from the
+    sorted rows by ``kernels.pick_sum`` (the forward's results and the
+    backward's cotangent of the handed rows alike), which reads only the
+    held picks' rows, ``sum(sizes)`` of them, whatever the rung. (A
+    ``rows``-row scatter-add into [T, d] was timed beside the gathers: 1.5
+    ms a layer ahead at the first rung, behind at the last, and XLA:TPU
+    adds its f32 rows at bf16's precision, which is another sum.)"""
 
     @jax.custom_vjp
-    def dispatch(h, o, inv):                # [T, d] -> [rows, d], sorted
+    def dispatch(h, o, inv, sizes):         # [T, d] -> [rows, d], sorted
         return h[o // k]
 
-    def dispatch_fwd(h, o, inv):
-        return dispatch(h, o, inv), inv
+    def dispatch_fwd(h, o, inv, sizes):
+        return dispatch(h, o, inv, sizes), (inv, sizes)
 
-    def dispatch_bwd(inv, ct):
-        return summed_picks(ct, inv).astype(ct.dtype), None, None
+    def dispatch_bwd(res, ct):
+        inv, sizes = res
+        return (pick_sum(ct, inv.reshape(-1, k), sizes, ct.dtype), None,
+                None, None)
 
     dispatch.defvjp(dispatch_fwd, dispatch_bwd)
 
     @jax.custom_vjp
-    def combine(ys, o, inv):                # sorted [rows, d] -> [T, d] f32
-        return summed_picks(ys, inv)
+    def combine(ys, o, inv, sizes):         # sorted [rows, d] -> [T, d] f32
+        return pick_sum(ys, inv.reshape(-1, k), sizes)
 
-    def combine_fwd(ys, o, inv):
-        return combine(ys, o, inv), o
+    def combine_fwd(ys, o, inv, sizes):
+        return combine(ys, o, inv, sizes), o
 
     def combine_bwd(o, ct):
         # row j of the sorted pairs is pick o[j] % k of token o[j] // k, and
         # every pick of a token has the token's cotangent; ``ys`` is of the
         # stream's dtype
-        return ct[o // k].astype(stream), None, None
+        return ct[o // k].astype(stream), None, None, None
 
     combine.defvjp(combine_fwd, combine_bwd)
 
     def fn(h, g, eg, eu, ed, order, inv, sizes):
         o = order[:rows]
         with part_scope("route"):
-            xs = dispatch(h, o, inv)
+            xs = dispatch(h, o, inv, sizes)
         with part_scope("experts"):
             # rows past the held pairs belong to no group: the kernels'
             # results and left-operand gradients are exactly 0 there, so
@@ -413,7 +409,7 @@ def _held_rung(rows, k, operand, stream, activation):
                 ys = rd(ACTIVATIONS[activation](rd(xs, eg)) * rd(xs, eu), ed)
         with part_scope("route"):
             ys = (ys * g.reshape(-1)[o][:, None]).astype(stream)
-            return combine(ys, o, inv)
+            return combine(ys, o, inv, sizes)
 
     return fn
 
@@ -504,13 +500,14 @@ def moe_ffn_op(ctx):
     the held part runs over the smallest rung that holds the step's
     ``RowsHeld``. The (token, pick) pairs are sorted by expert, held
     experts first, so the held ones are a prefix of the sorted order, and
-    only a prefix moves: the gathers, the grouped products (Pallas
-    kernels, ``kernels.grouped_matmul``) and the weighted sum back run over
-    the first ``C`` sorted pairs, ``C`` the smallest of ``held_rungs`` (static sizes,
-    the last one every pair) that holds this step's ``RowsHeld``, picked on
-    the device by a ``lax.switch`` (``_held_part``, which every expert
-    layer of one shape shares: a rung's Python is traced once, not once a
-    layer and pass). A layer that holds every expert has one rung and no
+    only a prefix moves: the gathers and the grouped products (Pallas
+    kernels, ``kernels.grouped_matmul``) run over the first ``C`` sorted
+    pairs, ``C`` the smallest of ``held_rungs`` (static sizes, the last one
+    every pair) that holds this step's ``RowsHeld``, and the weighted sum
+    back reads the ``RowsHeld`` rows alone (``kernels.pick_sum``); ``C`` is
+    picked on the device by a ``lax.switch`` (``_held_part``, which every
+    expert layer of one shape shares: a rung's Python is traced once, not
+    once a layer and pass). A layer that holds every expert has one rung and no
     switch. With ``PostNormScale`` the sum shared + held part is normed
     before the add: the norm of a partial sum is no part of the whole
     layer's, so shares that are to add up leave it off and norm their sum.

@@ -56,7 +56,7 @@ __all__ = [
     "parity_ok", "parity_report",
     "lookup", "record_fallback", "counters", "reset_counters",
     "count_flash_blocks", "count_flash_kept", "count_moe_rungs",
-    "count_gmm_blocks", "count_ssm_scan",
+    "count_gmm_blocks", "count_ssm_scan", "count_pick_sum",
 ]
 
 # -- dispatch counters --------------------------------------------------------
@@ -94,6 +94,10 @@ _gmm_blocks = {}
 # {"Q128 x64 h64 g8 n128 p64 pallas": scans traced}: the shape of each
 # state-space scan (kernels/ssd_scan.py: mixer_scan) and what runs it
 _ssm_scans = {}
+# {"16384x6x2560 rows98304 tile128": launches traced}: the shape of each
+# held-pick sum (kernels/pick_sum.py: pick_sum), tokens x picks x width,
+# the sorted rows it reads from and its token tile
+_pick_sums = {}
 
 
 def _bump(name):
@@ -161,6 +165,14 @@ def count_ssm_scan(chunk, chunks, heads, groups, state, head_dim,
         _ssm_scans[name] = _ssm_scans.get(name, 0) + 1
 
 
+def count_pick_sum(T, k, d, rows, tile):
+    """One held-pick sum traced over ``T`` tokens of ``k`` picks, rows of
+    ``d``, from ``rows`` sorted rows, in token tiles of ``tile``."""
+    name = "%dx%dx%d rows%d tile%d" % (T, k, d, rows, tile)
+    with _counters_lock:
+        _pick_sums[name] = _pick_sums.get(name, 0) + 1
+
+
 def counters():
     """Snapshot of the process-level dispatch counters; ``flash_blocks``
     is the {"<kernel> <block_q>x<block_k>[ g<group>][ w<window>]":
@@ -173,7 +185,9 @@ def counters():
     traced, ``gmm_blocks`` the {"<kernel> <tm>x<tk>x<tn> g<groups>":
     launches} tally of the grouped-matmul launches, ``ssm_scans`` the
     {"Q<chunk> x<chunks> h<heads> g<groups> n<state> p<head width>
-    <implementation>": scans} tally of the state-space scans."""
+    <implementation>": scans} tally of the state-space scans, ``pick_sums``
+    the {"<T>x<k>x<d> rows<rows> tile<tile>": launches} tally of the
+    held-pick sums."""
     with _counters_lock:
         return dict(_counters, flash_blocks=dict(_flash_blocks),
                     flash_tiles={k: dict(v)
@@ -181,7 +195,8 @@ def counters():
                     flash_kept=dict(_flash_kept),
                     moe_rungs=dict(_moe_rungs),
                     gmm_blocks=dict(_gmm_blocks),
-                    ssm_scans=dict(_ssm_scans))
+                    ssm_scans=dict(_ssm_scans),
+                    pick_sums=dict(_pick_sums))
 
 
 def reset_counters():
@@ -195,6 +210,7 @@ def reset_counters():
         _moe_rungs.clear()
         _gmm_blocks.clear()
         _ssm_scans.clear()
+        _pick_sums.clear()
     profiler.reset_tune_counters()
 
 

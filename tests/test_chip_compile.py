@@ -1,4 +1,4 @@
-"""The eight Pallas kernels, compiled by the TPU's own compiler for a
+"""The nine Pallas kernels, compiled by the TPU's own compiler for a
 *described* v5e (no chip attached), at the real widths the chip smoke and
 the benchmark cells use. A compile that passes here is not a chip run: it
 proves Mosaic accepts the kernel (block shapes, dot forms, VMEM budget),
@@ -507,6 +507,97 @@ def test_the_grouped_products_carry_the_experts_scope_in_both_phases(
                         ("backward/moe_ffn/experts", "gmm"): 3 * rungs,
                         ("backward/moe_ffn/experts", "gmm_t"): 3 * rungs,
                         ("backward/moe_ffn/experts", "tgmm"): 3 * rungs}
+
+
+# an expert layer of each language-model cell: tokens, picks, width, held
+# experts, its rungs (first, top)
+PICK_CELLS = {"K": (8192, 6, 2048, 16, (12288, 49152)),
+              "T": (8192, 8, 2048, 16, (16384, 65536)),
+              "P": (16384, 6, 2560, 8, (24576, 98304)),
+              "N": (8192, 6, 2688, 8, (6144, 49152))}
+
+
+@pytest.mark.parametrize("cell,rung", [(c, i) for c in PICK_CELLS
+                                       for i in (0, 1)],
+                         ids=["%s-%s" % (c, r) for c in PICK_CELLS
+                              for r in ("first", "top")])
+def test_pick_sum_kernel_compiles(one_chip, monkeypatch, cell, rung):
+    """The held-pick sums of an expert layer, bf16 rows, at the cell's
+    first and top rung and the token tile the rule picks: the combine's
+    (an f32 result) and the handed rows' gradient's (bf16, added up in an
+    f32 scratch), each the runs found before the launch and the launch (a
+    raised scoped VMEM where ``vmem_bytes`` passes Mosaic's default)."""
+    ps = _kernel_module("pick_sum")
+    monkeypatch.setattr(ps, "on_tpu", lambda: True)
+    T, k, d, groups, rungs = PICK_CELLS[cell]
+
+    def both(a, inv, sizes):
+        return (ps.pick_sum(a, inv, sizes),
+                ps.pick_sum(a, inv, sizes, jnp.bfloat16))
+
+    text = _compile(both, one_chip, ((rungs[rung], d), jnp.bfloat16),
+                    ((T, k), jnp.int32), ((groups,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_the_pick_sums_carry_the_route_scope_and_leave_no_pick_copy(
+        one_chip, monkeypatch):
+    """A training step of one ``moe_ffn`` that holds 4 of 32 experts (three
+    rungs behind the switch), recomputed, under pure AMP: each rung's sum
+    of the held picks is a ``pick_sum`` launch in scope
+    ``forward/moe_ffn/route`` (the combine) and ``backward/moe_ffn/route``
+    (the dispatch's gradient; the replayed combine is dead there), and no
+    instruction of the step makes a [T, k, d] array (768 tokens x 4 picks
+    x 64)."""
+    import collections
+    import paddle_tpu as pt
+    from paddle_tpu import layers as L, profiler
+    from paddle_tpu.core.executor import RngSource, trace_ops
+    from paddle_tpu.ops import decoder_ops
+    for name in ("grouped_matmul", "pick_sum"):
+        monkeypatch.setattr(_kernel_module(name), "on_tpu", lambda: True)
+    decoder_ops._held_part.cache_clear()
+    main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
+    with pt.program_guard(main, startup):
+        x = L.data("x", shape=[384, 64], dtype="float32")
+        x.stop_gradient = False
+        out, _load, _held = L.moe_ffn(x, 32, 4, 32, 64, experts_held=(6, 4),
+                                      scaling=2.448, prefix="m")
+        pt.amp.enable(main, pure=True)
+        pt.optimizer.SGDOptimizer(learning_rate=0.0).minimize(
+            L.mean(out * out))
+        pt.memory_optimize(main, remat_types=("moe_ffn",))
+    exe = pt.Executor(pt.CPUPlace())
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        state = {n: jax.ShapeDtypeStruct(
+            scope.find_var(n).shape, scope.find_var(n).dtype,
+            sharding=one_chip) for n in exe._state_inputs(main, scope,
+                                                          {"x"})}
+    feed = {"x": jax.ShapeDtypeStruct((2, 384, 64), jnp.float32,
+                                      sharding=one_chip)}
+
+    def step(state, feed):
+        env = dict(feed, **state)
+        trace_ops(main.global_block(), env, RngSource(jax.random.PRNGKey(0)))
+        return {n: env[n] for n in state}
+
+    prev = pt.amp.force(True)
+    try:
+        text = jax.jit(step).lower(state, feed).compile().as_text()
+    finally:
+        pt.amp.force(prev)
+        decoder_ops._held_part.cache_clear()
+    _module, table = profiler.scopes_of_module(text)
+    launches = collections.Counter()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?(pick_sum)\.\d+ = ", line)
+        if m and 'custom_call_target="tpu_custom_call"' in line:
+            launches[table[line.split("=", 1)[0].strip().lstrip("%")]] += 1
+    rungs = 3
+    assert launches == {"forward/moe_ffn/route": rungs,
+                        "backward/moe_ffn/route": rungs}
+    assert not re.search(r"\[768,4,64\]", text)
 
 
 # the mixers' scan of the hybrid cell: 8,192 positions in chunks of 128, 64

@@ -529,7 +529,11 @@ def held_layer(remat, one_rung=False, cached=True):
     key = (remat, one_rung)
     if cached and key in _held_layers:
         return _held_layers[key]
+    from paddle_tpu import tune
     from paddle_tpu.core import executor
+    # every rung traced anew, so that its launches are counted here
+    decoder_ops._held_part.cache_clear()
+    sums = dict(tune.counters()["pick_sums"])
     main, startup, scope = pt.Program(), pt.Program(), pt.Scope()
     with pt.program_guard(main, startup):
         x = L.data("x", shape=[H_SEQ, H_D], dtype="float32")
@@ -566,6 +570,10 @@ def held_layer(remat, one_rung=False, cached=True):
 
     layer = {"run": run, "names": names, "rungs": rungs, "exe": exe}
     run(0.0)                    # traced and compiled here, under this key
+    decoder_ops._held_part.cache_clear()
+    layer["pick_sums"] = {name: n - sums.get(name, 0) for name, n in
+                          tune.counters()["pick_sums"].items()
+                          if n > sums.get(name, 0)}
     step = list(executor.compiled_steps())[-1]
     layer["text"] = step.fn.lower(*step._avals).compile().as_text()
     if cached:
@@ -575,11 +583,12 @@ def held_layer(remat, one_rung=False, cached=True):
 
 def _switches(text):
     """The ``conditional`` instructions of a compiled module's text that
-    the program made: not those inside a grouped-matmul kernel, which the
-    Pallas interpreter makes of the kernel's ``pl.when`` on the CPU (on the
-    chip the kernel is one custom call)."""
+    the program made: not those inside a grouped-matmul or pick-sum
+    kernel, which the Pallas interpreter makes of the kernel's ``pl.when``
+    on the CPU (on the chip a kernel is one custom call)."""
     return [line for line in text.splitlines() if " conditional(" in line
-            and not re.search(r"/grouped_(gmm|gmm_t|tgmm)/", line)]
+            and not re.search(r"/(grouped_(gmm|gmm_t|tgmm)|pick_sum)/",
+                              line)]
 
 
 def _branch_computations(text):
@@ -623,6 +632,13 @@ def test_every_rung_gives_the_loss_and_gradients_of_the_one_rung_lowering(
                   0.3: (2.7, 3.6, 1536), 10.0: (8, 8, 3072)}[bias]
     assert lo * expected <= held <= hi * expected, held
     assert rung == at
+    # each rung sums its picks on the kernel, and so does the one rung
+    tokens = H_ROWS * H_SEQ
+    assert set(layer["pick_sums"]) == {
+        "%dx%dx%d rows%d tile256" % (tokens, H_K, H_D, r)
+        for r in layer["rungs"]}
+    assert set(plain["pick_sums"]) == {
+        "%dx%dx%d rows%d tile256" % (tokens, H_K, H_D, H_PAIRS)}
     for name, a, b in zip(["loss", "rows_held", "x"] + layer["names"], got,
                           want):
         scale = float(np.abs(b).max())
@@ -740,8 +756,10 @@ def test_every_held_product_is_counted_on_the_grouped_matmul_kernels(
     and up: [rows, 64] x [4, 64, 32]; down: [rows, 32] x [4, 32, 64]) on
     the kernels, traced in the step's forward, the checkpoint's recomputed
     forward and the backward's ``jax.vjp``, whose gradients are a ``gmm_t``
-    and a ``tgmm`` each: ``tune.counters()["gmm_blocks"]``, mirrored in
-    ``Executor.stats`` and printed in the scope table's header."""
+    and a ``tgmm`` each: ``tune.counters()["gmm_blocks"]``; and sums its
+    held picks on the pick-sum kernel, ``tune.counters()["pick_sums"]``;
+    both mirrored in ``Executor.stats`` and printed in the scope table's
+    header."""
     import importlib.util
     import os
     from paddle_tpu import tune
@@ -759,16 +777,27 @@ def test_every_held_product_is_counted_on_the_grouped_matmul_kernels(
                        "gmm_t 256x64x32 g4": rungs,
                        "tgmm 256x32x64 g4": rungs}
     assert layer["exe"].stats["gmm_blocks"] == counted
+    # and each rung's sums of the held picks on the pick-sum kernel: the
+    # combine in the forward, the recomputed forward and the backward's
+    # ``jax.vjp``, and the gradient of the rows handed to the experts
+    sums = tune.counters()["pick_sums"]
+    assert sums == {"%dx%dx%d rows%d tile256" % (H_ROWS * H_SEQ, H_K, H_D, r):
+                    4 for r in layer["rungs"]}
+    assert layer["exe"].stats["pick_sums"] == sums
     path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
         __file__))), "tools", "scope_table.py")
     spec = importlib.util.spec_from_file_location("scope_table", path)
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
     tool.report("step", 1, {}, tune.counters())
+    printed = capsys.readouterr().out
     assert "gmm_blocks: gmm 256x32x64 g4 x%d, gmm 256x64x32 g4 x%d" % (
-        3 * rungs, 6 * rungs) in capsys.readouterr().out
+        3 * rungs, 6 * rungs) in printed
+    assert "pick_sums: %s" % ", ".join(
+        "%s x4" % name for name in sorted(sums)) in printed
     tune.reset_counters()
     assert tune.counters()["gmm_blocks"] == {}
+    assert tune.counters()["pick_sums"] == {}
 
 
 def _step_of_expert_layers(monkeypatch, n_layers, shared):

@@ -15,7 +15,8 @@ with ``tune.counters()`` (the header's lines: dispatch gauges, the blocks
 each flash-attention launch was traced at, the tiling of each
 grouped-matmul launch of the held experts, the differentiated launches
 whose o and lse the backward reads kept, the row counts each expert
-layer's held part was compiled at, the shape of each state-space scan)
+layer's held part was compiled at, the shape of each state-space scan and
+of each held-pick sum)
 and, where the cell's program has expert layers whose ``Load`` /
 ``RowsHeld`` its Trainer fetches, the rung every
 (layer, step) of the window ran at (``layers.moe_rows_moved`` over the
@@ -255,6 +256,9 @@ def report(module, steps, rows, counters=None, rungs=None):
         if counters.get("ssm_scans"):
             print("ssm_scans: %s" % ", ".join(
                 "%s x%d" % kv for kv in sorted(counters["ssm_scans"].items())))
+        if counters.get("pick_sums"):
+            print("pick_sums: %s" % ", ".join(
+                "%s x%d" % kv for kv in sorted(counters["pick_sums"].items())))
     if rungs:
         tally = collections.Counter()
         for i, layer in enumerate(rungs):
